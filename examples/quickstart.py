@@ -167,10 +167,22 @@ print(f"mesh: {mesh.shape}")
 Bg = jax.random.normal(jax.random.PRNGKey(5), (1024, 4))
 out_d = dist_spmm(G, Bg, mesh=mesh, axis="shards", schedule="tune",
                   cache=cache)
-np.testing.assert_allclose(np.asarray(out_d),
-                           np.asarray(spmm(G, Bg, impl="ref")),
-                           rtol=1e-4, atol=1e-4)
 res_d = tune_dist_spmm(G, 4, mesh=mesh, axis="shards", cache=cache)
+# the joint search may pick narrow value storage (§13) when it measures
+# faster; the oracle reads the same storage
+from repro.core.dtypes import operand_dtype, storage_dtype  # noqa: E402
+
+vd = res_d.schedule.value_dtype
+Gt = G if vd is None else G.astype(storage_dtype(vd))
+Bt = Bg.astype(operand_dtype(vd)).astype(jnp.float32)
+np.testing.assert_allclose(np.asarray(out_d, np.float32),
+                           np.asarray(spmm(Gt, Bt, impl="ref")),
+                           rtol=1e-4, atol=1e-4)
+# ... and the f32 oracle within the tuner's parity budget (with slack)
+want_d = np.asarray(spmm(G, Bg, impl="ref"))
+rel_d = (np.linalg.norm(np.asarray(out_d, np.float32) - want_d)
+         / np.linalg.norm(want_d))
+assert rel_d <= 0.10, (vd, rel_d)
 print("distributed spmm matches oracle: OK | tuned collective:",
       res_d.schedule.collective, "| cached replay:", res_d.from_cache)
 

@@ -14,9 +14,8 @@ the quantized value path (per-row scales, ``sparse.formats.quantize_csr``)
 with a ``bfloat16`` dense operand.
 
 ``float8_e4m3fn`` degrades to ``bfloat16`` with a :class:`Fp8Fallback`
-warning when the running jax has no fp8 type (older pins) or when
-``REPRO_DISABLE_FP8`` is set — schedules stay valid and replayable
-across heterogeneous fleets; only the realized storage width changes.
+warning when ``REPRO_DISABLE_FP8`` is set — schedules stay valid and
+replayable; only the realized storage width changes.
 """
 from __future__ import annotations
 
@@ -72,26 +71,19 @@ def canonical_value_dtype(value_dtype):
 
 
 def fp8_supported() -> bool:
-    """True when this process can store ``float8_e4m3fn`` values.
-
-    ``REPRO_DISABLE_FP8`` (any value but ``""``/``"0"``) forces False —
-    the CI fallback leg uses it to exercise the degraded path on a jax
-    that does have the type.
-    """
-    if os.environ.get("REPRO_DISABLE_FP8", "") not in ("", "0"):
-        return False
-    import jax.numpy as jnp
-
-    return hasattr(jnp, "float8_e4m3fn")
+    """True unless ``REPRO_DISABLE_FP8`` (any value but ``""``/``"0"``)
+    turns ``float8_e4m3fn`` storage off — the switch the fallback tests
+    use to exercise the bf16 degradation path."""
+    return os.environ.get("REPRO_DISABLE_FP8", "") in ("", "0")
 
 
 def storage_dtype(value_dtype):
     """Resolve a canonical value-dtype name to the jnp storage dtype.
 
     ``None``/``"float32"`` -> f32; ``"int8"`` -> int8 (the quantized
-    value stream); fp8 -> ``jnp.float8_e4m3fn`` when available, else
-    ``jnp.bfloat16`` with a :class:`Fp8Fallback` warning (never an
-    error: an old jax pin must degrade, not crash).
+    value stream); fp8 -> ``jnp.float8_e4m3fn``, or ``jnp.bfloat16``
+    with a :class:`Fp8Fallback` warning when :func:`fp8_supported` is
+    False (degrade, never crash).
     """
     import jax.numpy as jnp
 
@@ -100,8 +92,7 @@ def storage_dtype(value_dtype):
         return jnp.float32
     if name == "float8_e4m3fn" and not fp8_supported():
         warnings.warn(
-            "float8_e4m3fn storage unavailable on this jax "
-            "(missing jnp.float8_e4m3fn or REPRO_DISABLE_FP8 set); "
+            "float8_e4m3fn storage disabled (REPRO_DISABLE_FP8 set); "
             "degrading value storage to bfloat16",
             Fp8Fallback, stacklevel=2)
         return jnp.bfloat16

@@ -95,7 +95,7 @@ def _epilogue_operands(launch: Launch, params):
     return bias, residual
 
 
-def _run_launch(launch: Launch, cur, params, interpret: bool):
+def _run_launch(launch: Launch, cur, params):
     a = launch.anchor
     p = params[launch.anchor_idx] or {}
     ep = launch.epilogue
@@ -107,22 +107,19 @@ def _run_launch(launch: Launch, cur, params, interpret: bool):
         x = cur if p.get("w") is None else cur @ p["w"]
         return spmm(p["a"], x, schedule=a.schedule or "auto",
                     bias=bias, residual=residual,
-                    epilogue=None if ep.is_noop else ep,
-                    interpret=interpret)
+                    epilogue=None if ep.is_noop else ep)
     if a.kind == "grouped_matmul":
         from ..kernels.ops import grouped_matmul
 
         return grouped_matmul(
             cur, p["tile_experts"], p["weights"], bias=bias, epilogue=ep,
             token_tile=p.get("token_tile", 128),
-            f_tile=p.get("f_tile", 128), d_tile=p.get("d_tile", 128),
-            interpret=interpret)
+            f_tile=p.get("f_tile", 128), d_tile=p.get("d_tile", 128))
     if a.kind == "segment_reduce":
         from ..sparse import segment_reduce
 
         return segment_reduce(p["seg_ids"], cur, p["num_segments"],
-                              schedule=a.schedule, op=a.op,
-                              interpret=interpret)
+                              schedule=a.schedule, op=a.op)
     if a.kind == "combine":
         return moe_combine(cur, p["topi"], p["topv"], p["num_tokens"],
                            op=a.op)
@@ -131,13 +128,13 @@ def _run_launch(launch: Launch, cur, params, interpret: bool):
                     residual=residual)
 
 
-def run_plan(plan: FusePlan, x, params, *, interpret: bool = True):
+def run_plan(plan: FusePlan, x, params):
     """Execute a plan: ``params`` is the per-chain-node operand list
     (``len(params) == len(plan.chain)``)."""
     assert len(params) == len(plan.chain), (len(params), len(plan.chain))
     cur = x
     for launch in plan.launches:
-        cur = _run_launch(launch, cur, params, interpret)
+        cur = _run_launch(launch, cur, params)
     return cur
 
 

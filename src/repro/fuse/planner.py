@@ -110,7 +110,7 @@ def plan_key(chain, x, params) -> str:
 def tune_plan(chain, x, params, *, cache=None,
               measure: Optional[Callable[[FusePlan], float]] = None,
               warmup: Optional[int] = None, iters: Optional[int] = None,
-              backend: Optional[str] = None, interpret: bool = True,
+              backend: Optional[str] = None,
               hill_steps: Optional[int] = None):
     """Measure fuse decisions for this chain on this workload and return
     a :class:`~repro.tune.TuneResult` whose ``.schedule`` is the winning
@@ -147,7 +147,7 @@ def tune_plan(chain, x, params, *, cache=None,
 
         def measure(p: FusePlan) -> float:
             return time_fn(
-                lambda xx: run_plan(p, xx, params, interpret=interpret),
+                lambda xx: run_plan(p, xx, params),
                 x, warmup=warmup, iters=iters)
 
     if hill_steps is None:

@@ -10,21 +10,18 @@ the whole tile and combining the result (correct, not tuned).
 Every realization is written against the strategy's reduction *monoid*
 (``repro.core.Monoid``): the combine op, its identity, and the derived
 reducers.  Sum is the ``add`` instance; ``op="max"``/``"min"`` run the
-same machinery (graph pooling, the fused-attention row max).  The only
-monoid-conditional code is the MXU fast path: the one-hot matmul reduce
-is *algebraically* a masked sum, so it is used exactly when
-``monoid.matmul_ok`` — any other monoid takes the masked-``where``
-reduce.
+same machinery (graph pooling, the fused-attention row max) — a masked
+lane becomes the monoid's identity, so no realization branches on it.
 
 The built-in 'segment' realization is the TPU form of the paper's segment
 group (DESIGN.md §2): within each width-G group it
 
-1. finds segment runs (boundary cumsum — replaces the GPU's runtime
-   writeback-thread election),
-2. reduces the run partials with a (G × G) one-hot matmul (add monoid;
-   masked reduce otherwise) — the MXU analogue of the warp shuffle tree,
-3. writes each live run back with a read-modify-write into the output
-   block — the analogue of the paper's multiple writeback threads; the
+1. finds segment runs by walking the group's row ids on the scalar unit
+   (they sit in SMEM) — the GPU's runtime writeback-thread election,
+2. reduces each run's lanes with one masked reduce over the group's
+   (G, C) partials,
+3. writes each run back with a read-modify-write into the output block
+   — the analogue of the paper's multiple writeback threads; the
    sequential TPU grid makes the RMW race-free ("atomic" for free).
 
 Strategy variants:
@@ -33,6 +30,11 @@ Strategy variants:
                 within-group reduce + single writeback (one writeback
                 thread);
   'accumulate'  per-lane RMW (the atomicAdd baseline).
+
+Every Pallas launch of the package goes through :func:`pallas_call`, the
+one place that decides compiled (TPU) vs interpreted (elsewhere) and
+asks the compiler for the VMEM the kernel's blocks need
+(:func:`vmem_bytes`).
 
 ``apply_epilogue`` is the shared last-grid-step epilogue applier
 (``core.Epilogue``): bias / activation / residual / dtype cast fused
@@ -78,6 +80,69 @@ def upcast_f32(*xs):
     return out[0] if len(out) == 1 else out
 
 
+#: VMEM of one v5e TensorCore: 128 MiB physical.  A kernel that asks
+#: for nothing gets the compiler's 16 MiB scoped default; ``pallas_call``
+#: below asks for what the kernel's blocks need.
+VMEM_CAPACITY = 128 * 1024 * 1024
+#: VMEM the compiler keeps for its own scratch (semaphores, relayout
+#: buffers, spills) on top of the blocks a kernel declares.
+VMEM_HEADROOM = 4 * 1024 * 1024
+
+
+def vmem_bytes(shape, dtype, buffers: int = 1) -> int:
+    """VMEM one block occupies: Mosaic tiles the last two dims by
+    (sublanes, 128 lanes), with 8 sublanes of 32-bit words and packing
+    narrower dtypes (16 rows of bf16, 32 of int8/fp8) — so a (K, 16) f32
+    block takes as much VMEM as a (K, 128) one.  A one-row block, such
+    as a ``(1, T)`` lane block, is tiled one row high.  ``buffers``
+    counts the pipeline's copies (:func:`block_buffers`)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, r, c = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sub = 1 if r == 1 else 8 * max(1, 4 // itemsize)
+    n = 1
+    for d in lead:
+        n *= d
+    return buffers * n * (-(-r // sub) * sub) * (-(-c // 128) * 128) * itemsize
+
+
+def block_buffers(n_blocks: int) -> int:
+    """Pipeline copies Mosaic allocates for a block that takes
+    ``n_blocks`` distinct positions over the grid: one for a block that
+    never moves (resident for the whole launch), two (double-buffered)
+    otherwise."""
+    return 1 if n_blocks == 1 else 2
+
+
+def pallas_call(kernel, *, vmem_need: int | None = None, interpret=None,
+                **kw):
+    """``pl.pallas_call`` with the one compiled-vs-interpreted decision
+    of the kernels package: ``interpret=None`` follows the backend
+    (``launch.backend.pallas_interpret_default``: compiled on a TPU,
+    interpreted elsewhere).  Asking for the interpreter on a TPU raises —
+    a kernel that cannot lower fails there, it never falls back.
+
+    Compiled with ``vmem_need`` given (the padded, buffered bytes of the
+    kernel's blocks and scratch — see :func:`vmem_bytes`), the kernel's
+    scoped VMEM limit is that plus :data:`VMEM_HEADROOM`, capped at the
+    chip's :data:`VMEM_CAPACITY`; a kernel whose blocks exceed the cap
+    is refused by the compiler.  Without it the compiler's default
+    scoped limit holds."""
+    from ..launch.backend import pallas_interpret_default
+
+    default = pallas_interpret_default()
+    if interpret is None:
+        interpret = default
+    elif interpret and not default:
+        raise ValueError("Pallas kernels run compiled on a TPU; the "
+                         "interpreter is for backends without one")
+    if not interpret and vmem_need is not None:
+        from jax.experimental.pallas import tpu as pltpu
+
+        kw["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=min(vmem_need + VMEM_HEADROOM, VMEM_CAPACITY))
+    return pl.pallas_call(kernel, interpret=interpret, **kw)
+
+
 def _rmw_row(out_ref, row, delta, combine):
     """out_ref[row, :] = combine(out_ref[row, :], delta); delta (1, C),
     dynamic row index."""
@@ -86,82 +151,78 @@ def _rmw_row(out_ref, row, delta, combine):
 
 
 # ---------------------------------------------------------------------------
-# Built-in in-kernel realizations.  Registry contract:
-#     pallas_fn(rows (T,), partial (T, C), out_ref (R, C), group_size,
-#               monoid=<Monoid>)
-# (the monoid keyword is passed iff the signature accepts it, so 4-arg
-# user realizations keep working — see core.schedule.call_pallas_fn).
+# Built-in in-kernel realizations.  They read their operands through refs:
+#     pallas_fn(rows_ref (1, T) SMEM, partial_ref (T, C) VMEM,
+#               out_ref (R, C), group_size, monoid=<Monoid>)
+# Writeback rows are scalars, and Mosaic reads scalars only from SMEM;
+# per-lane and per-run slices of the partials are dynamic row windows of
+# a VMEM ref.  A user realization keeps the value contract
+# ``(rows (T,), partial (T, C), out_ref, group_size)`` (see
+# ``group_reduce_scatter``).
 # ---------------------------------------------------------------------------
 
 
-def _pallas_accumulate(rows, partial, out_ref, group_size: int, *,
+def _pallas_accumulate(rows_ref, partial_ref, out_ref, group_size: int, *,
                        monoid: Monoid = _ADD):
-    T, _ = partial.shape
     del group_size
 
-    def lane_body(t, _):
-        _rmw_row(out_ref, rows[t], partial[t][None, :], monoid.combine)
-        return 0
-
-    jax.lax.fori_loop(0, T, lane_body, 0)
-
-
-def _pallas_parallel(rows, partial, out_ref, group_size: int, *,
-                     monoid: Monoid = _ADD):
-    T, C = partial.shape
-    G = group_size
-
-    def par_body(n, _):
-        p = jax.lax.dynamic_slice(partial, (n * G, 0), (G, C))
-        _rmw_row(out_ref, rows[n * G], monoid.reduce(p, 0)[None, :],
+    def lane_body(t, c):
+        _rmw_row(out_ref, rows_ref[0, t], partial_ref[pl.ds(t, 1), :],
                  monoid.combine)
-        return 0
+        return c
 
-    jax.lax.fori_loop(0, T // G, par_body, 0)
+    jax.lax.fori_loop(0, partial_ref.shape[0], lane_body, 0)
 
 
-def _pallas_segment(rows, partial, out_ref, group_size: int, *,
-                    monoid: Monoid = _ADD):
-    T, C = partial.shape
+def _pallas_parallel(rows_ref, partial_ref, out_ref, group_size: int, *,
+                     monoid: Monoid = _ADD):
     G = group_size
 
-    def group_body(n, _):
-        r = jax.lax.dynamic_slice(rows, (n * G,), (G,))
-        p = jax.lax.dynamic_slice(partial, (n * G, 0), (G, C))
-        # run boundaries -> local segment slots in [0, G)
-        prev = jnp.concatenate([jnp.full((1,), -1, r.dtype), r[:-1]])
-        local = jnp.cumsum((r != prev).astype(jnp.int32)) - 1  # (G,)
-        onehot = (
-            local[:, None]
-            == jax.lax.broadcasted_iota(jnp.int32, (G, G), 1)
-        )  # (G lanes, G slots) bool
-        if monoid.matmul_ok:
-            seg_tot = jnp.dot(onehot.astype(p.dtype).T, p,
-                              preferred_element_type=jnp.float32)  # MXU
-        else:
-            # masked reduce over lanes per slot (identity off-mask)
-            expanded = jnp.where(onehot.T[:, :, None], p[None, :, :],
-                                 monoid.identity)  # (slots, lanes, C)
-            seg_tot = monoid.reduce(expanded, 1)  # (G slots, C)
-        # slot -> global row (slots past the last run get -1 = dead)
-        seg_rows = jnp.max(
-            jnp.where(onehot, r[:, None], -1), axis=0
-        )  # (G,)
+    def group_body(n, c):
+        base = pl.multiple_of(n * G, G)
+        p = partial_ref[pl.ds(base, G), :]
+        _rmw_row(out_ref, rows_ref[0, base], monoid.reduce(p, 0)[None, :],
+                 monoid.combine)
+        return c
 
-        def slot_body(s, _):
-            row = seg_rows[s]
+    jax.lax.fori_loop(0, partial_ref.shape[0] // G, group_body, 0)
 
-            @pl.when(row >= 0)
+
+def _pallas_segment(rows_ref, partial_ref, out_ref, group_size: int, *,
+                    monoid: Monoid = _ADD):
+    G = group_size
+    lane = jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0)
+
+    def group_body(n, c):
+        base = pl.multiple_of(n * G, G)
+        p = partial_ref[pl.ds(base, G), :]
+
+        def flush(lo, hi):
+            # one run = lanes [lo, hi) of the group: one masked reduce,
+            # one writeback (the paper's writeback thread of that run)
+            run = jnp.where((lane >= lo) & (lane < hi), p, monoid.identity)
+            _rmw_row(out_ref, rows_ref[0, base + lo],
+                     monoid.reduce(run, 0)[None, :], monoid.combine)
+
+        def lane_body(t, start):
+            # a row change closes the open run (runtime writeback
+            # election, on the scalar unit)
+            brk = rows_ref[0, base + t] != rows_ref[0, base + t - 1]
+
+            @pl.when(brk)
             def _():
-                _rmw_row(out_ref, row,
-                         jax.lax.dynamic_slice(seg_tot, (s, 0), (1, C)),
-                         monoid.combine)
-            return 0
+                flush(start, t)
 
-        jax.lax.fori_loop(0, G, slot_body, 0)
-        return 0
+            return jnp.where(brk, t, start)
 
-    jax.lax.fori_loop(0, T // G, group_body, 0)
+        flush(jax.lax.fori_loop(1, G, lane_body, jnp.int32(0)), G)
+        return c
+
+    jax.lax.fori_loop(0, partial_ref.shape[0] // G, group_body, 0)
+
+
+_REF_REALIZATIONS = frozenset(
+    (_pallas_accumulate, _pallas_parallel, _pallas_segment))
 
 
 def spec_fallback_pallas(entry):
@@ -180,21 +241,48 @@ def spec_fallback_pallas(entry):
     return pallas_fn
 
 
-def group_reduce_scatter(rows, partial, out_ref, group_size: int,
+def group_reduce_scatter(rows_ref, partial_ref, out_ref, group_size: int,
                          strategy: str = "segment", op=None):
-    """Reduce ``partial`` (T, C) by ``rows`` (T,) into ``out_ref`` (R, C)
-    with the registered strategy named ``strategy`` under the reduction
-    monoid ``op`` names ('add' default / 'max' / 'min' / a Monoid).
+    """Reduce the partials ``partial_ref`` (T, C) by the row indices
+    ``rows_ref`` (1, T, in SMEM) into ``out_ref`` (R, C) with the
+    registered strategy named ``strategy`` under the reduction monoid
+    ``op`` names ('add' default / 'max' / 'min' / a Monoid).
 
-    ``rows`` need not be globally sorted; sorted input minimizes writebacks
+    ``rows`` need not be sorted; sorted input minimizes writebacks
     (each unsorted transition opens a new run — correct, just more RMWs),
     which is exactly the paper's "writeback thread decided at runtime".
+    The built-in realizations read the refs; a user realization (or the
+    spec bridge) gets the loaded ``(rows (T,), partial (T, C))`` values,
+    which only the interpreter can load from SMEM as a vector.
     """
-    T, _ = partial.shape
+    T = partial_ref.shape[0]
     assert T % group_size == 0, (T, group_size)
     entry = get_strategy(strategy, op=op)
+    if entry.pallas_fn in _REF_REALIZATIONS:
+        call_pallas_fn(entry.pallas_fn, rows_ref, partial_ref, out_ref,
+                       group_size, entry.monoid)
+        return
     fn = entry.pallas_fn or spec_fallback_pallas(entry)
-    call_pallas_fn(fn, rows, partial, out_ref, group_size, entry.monoid)
+    call_pallas_fn(fn, rows_ref[0, :], partial_ref[...], out_ref,
+                   group_size, entry.monoid)
+
+
+def group_reduce_scatter_values(rows, partial, out_ref, group_size: int,
+                                strategy: str = "segment", op=None):
+    """:func:`group_reduce_scatter` for kernels that hold ``rows`` (T,)
+    and ``partial`` (T, C) as values: stages them into scoped SMEM/VMEM
+    refs first.  Storing a vector into SMEM does not lower on a TPU, so
+    its callers (the fused attention kernels) run interpreted only."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def body(rows_ref, partial_ref):
+        rows_ref[...] = rows[None, :]
+        partial_ref[...] = partial
+        group_reduce_scatter(rows_ref, partial_ref, out_ref, group_size,
+                             strategy, op=op)
+
+    pl.run_scoped(body, pltpu.SMEM((1,) + rows.shape, rows.dtype),
+                  pltpu.VMEM(partial.shape, partial.dtype))
 
 
 def split_epilogue_refs(refs, epilogue: Epilogue, narrowed: bool):

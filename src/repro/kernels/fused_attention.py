@@ -25,7 +25,7 @@ renormalization* per output row: a running row max ``m`` and denominator
 (H, nnz_tiles, dv_tiles) and every per-head operand is flattened to a
 2-D head-major buffer ((H·n_rows, d) queries, (H·n_rows, 1) row stats,
 …) whose BlockSpec selects head h's slab — so the in-kernel blocks stay
-2-D and ``group_reduce_scatter`` is reused unchanged.  The pattern
+2-D and the registry's scatter is reused unchanged.  The pattern
 (rows/cols/bias) is shared across heads.
 
 **Probability carry.**  The per-tile probabilities are computed once per
@@ -48,7 +48,8 @@ scattered, so the nnz grid is walked twice inside the same kernel —
                         score recompute), scatter dQ[r] += ds·K[c] and
                         the transpose dK[c] += ds·Q[r].
 
-All scatters run through ``group_reduce_scatter``; the dK/dV transpose
+All scatters run through ``group_reduce_scatter_values`` (the value-form
+front of ``group_reduce_scatter``); the dK/dV transpose
 scatters hand it the *cols* as segment ids — unsorted ids are correct by
 the strategy contract (each transition opens a new run), just more
 writebacks.
@@ -70,7 +71,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import NEG_INF, group_reduce_scatter, upcast_f32
+from .common import (
+    NEG_INF,
+    group_reduce_scatter_values,
+    pallas_call,
+    upcast_f32,
+)
 
 __all__ = [
     "NEG_INF",
@@ -188,8 +194,8 @@ def _fused_attn_fwd_kernel(*refs, nnz: int, nnz_tile: int, scale: float,
         s = jnp.where(valid, s, NEG_INF)
         m_old = m_ref[...]  # (R, 1)
         # running row max: the max-monoid scatter through the registry
-        group_reduce_scatter(rows, s[:, None], m_ref, group_size,
-                             strategy, op="max")
+        group_reduce_scatter_values(rows, s[:, None], m_ref, group_size,
+                                    strategy, op="max")
         m_new = m_ref[...]
         alpha = jnp.where(m_old <= NEG_INF / 2, 0.0,
                           jnp.exp(m_old - m_new))  # (R, 1)
@@ -201,16 +207,17 @@ def _fused_attn_fwd_kernel(*refs, nnz: int, nnz_tile: int, scale: float,
         # p instead of redoing the d-length dots above
         p_ref[...] = p[:, None]
         l_ref[...] = l_ref[...] * alpha
-        group_reduce_scatter(rows, p[:, None], l_ref, group_size,
-                             strategy)
+        group_reduce_scatter_values(rows, p[:, None], l_ref, group_size,
+                                    strategy)
 
     # SpMM back-end (every dv step): rescale the accumulator by this nnz
     # tile's α, then scatter-add the carried-probability-weighted values
     p = p_ref[...][:, 0]
     vj = upcast_f32(v_ref[...])  # (n_cols, dv_tile)
     out_ref[...] = out_ref[...] * a_ref[...]
-    group_reduce_scatter(rows, p[:, None] * jnp.take(vj, cols, axis=0),
-                         out_ref, group_size, strategy)
+    group_reduce_scatter_values(
+        rows, p[:, None] * jnp.take(vj, cols, axis=0), out_ref, group_size,
+        strategy)
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _normalize():
@@ -220,13 +227,12 @@ def _fused_attn_fwd_kernel(*refs, nnz: int, nnz_tile: int, scale: float,
 @functools.partial(
     jax.jit,
     static_argnames=("n_rows", "nnz", "nnz_tile", "dv_tile", "scale",
-                     "group_size", "strategy", "interpret"),
+                     "group_size", "strategy"),
 )
 def fused_sparse_attention(rows, cols, q, k, v, *, n_rows: int, nnz: int,
                            nnz_tile: int = 256, dv_tile: int = 128,
                            scale: float, group_size: int = 32,
-                           strategy: str = "segment", bias=None,
-                           interpret: bool = True):
+                           strategy: str = "segment", bias=None):
     """One-launch SDDMM→softmax→SpMM over all heads.
 
     Inputs pre-padded by the wrapper: rows/cols (and bias) (nnz_pad,)
@@ -267,7 +273,7 @@ def fused_sparse_attention(rows, cols, q, k, v, *, n_rows: int, nnz: int,
         pl.BlockSpec((n_kv, d), lambda h, i, j: (h, 0)),
         pl.BlockSpec((n_kv, dv_tile), lambda h, i, j: (h, j)),
     ]
-    out, m, l, _alpha, _p = pl.pallas_call(
+    out, m, l, _alpha, _p = pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -285,7 +291,6 @@ def fused_sparse_attention(rows, cols, q, k, v, *, n_rows: int, nnz: int,
             jax.ShapeDtypeStruct((n_heads * n_rows, 1), jnp.float32),
             jax.ShapeDtypeStruct((nnz_tile, 1), jnp.float32),
         ],
-        interpret=interpret,
     )(*operands, qf, kf, vf)
     return (out.reshape(n_heads, n_rows, dv),
             m.reshape(n_heads, n_rows), l.reshape(n_heads, n_rows))
@@ -346,11 +351,12 @@ def _fused_attn_bwd_kernel(*refs, nnz: int, nnz_tile: int, scale: float,
         w_ref[...] = w[:, None]
         dw_ref[...] = dw[:, None]
         # the softmax-backward row dot δ[r] = Σ w·dw — add-monoid scatter
-        group_reduce_scatter(rows, (w * dw)[:, None], delta_ref,
-                             group_size, strategy)
+        group_reduce_scatter_values(rows, (w * dw)[:, None], delta_ref,
+                                    group_size, strategy)
         # dV[c] += w · dout[r] — scatter-transpose (cols as segment ids)
-        group_reduce_scatter(cols, w[:, None] * jnp.take(do, rows, axis=0),
-                             dv_ref, group_size, strategy)
+        group_reduce_scatter_values(
+            cols, w[:, None] * jnp.take(do, rows, axis=0), dv_ref,
+            group_size, strategy)
 
     @pl.when(ph == 1)
     def _dq_and_dk():
@@ -358,23 +364,24 @@ def _fused_attn_bwd_kernel(*refs, nnz: int, nnz_tile: int, scale: float,
         w = w_ref[...][:, 0]
         dw = dw_ref[...][:, 0]
         ds = w * (dw - jnp.take(delta_ref[...][:, 0], rows)) * scale
-        group_reduce_scatter(rows, ds[:, None] * jnp.take(k, cols, axis=0),
-                             dq_ref, group_size, strategy)
+        group_reduce_scatter_values(
+            rows, ds[:, None] * jnp.take(k, cols, axis=0), dq_ref,
+            group_size, strategy)
         # dK[c] += ds · Q[r] — scatter-transpose
-        group_reduce_scatter(cols, ds[:, None] * jnp.take(q, rows, axis=0),
-                             dk_ref, group_size, strategy)
+        group_reduce_scatter_values(
+            cols, ds[:, None] * jnp.take(q, rows, axis=0), dk_ref,
+            group_size, strategy)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("n_rows", "nnz", "nnz_tile", "scale", "group_size",
-                     "strategy", "interpret"),
+                     "strategy"),
 )
 def fused_sparse_attention_bwd(rows, cols, q, k, v, dout, m, l, *,
                                n_rows: int, nnz: int, nnz_tile: int = 256,
                                scale: float, group_size: int = 32,
-                               strategy: str = "segment", bias=None,
-                               interpret: bool = True):
+                               strategy: str = "segment", bias=None):
     """One-launch fused backward: ``(dq, dk, dv)`` for all heads.
 
     Grid (H, 2, nnz_tiles) — the nnz grid is walked twice inside one
@@ -420,7 +427,7 @@ def fused_sparse_attention_bwd(rows, cols, q, k, v, dout, m, l, *,
         pl.BlockSpec((n_rows, dv), lambda h, p, i: (h, 0)),
         stat_spec, stat_spec,
     ]
-    dq, dk, dv_, _delta, _w, _dw = pl.pallas_call(
+    dq, dk, dv_, _delta, _w, _dw = pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -439,7 +446,6 @@ def fused_sparse_attention_bwd(rows, cols, q, k, v, dout, m, l, *,
             jax.ShapeDtypeStruct((nnz_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((nnz_pad, 1), jnp.float32),
         ],
-        interpret=interpret,
     )(*operands, qf, kf, vf, dof, mf, lf)
     return (dq.reshape(n_heads, n_rows, d),
             dk.reshape(n_heads, n_kv, d),

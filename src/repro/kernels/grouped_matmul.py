@@ -32,7 +32,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.schedule import Epilogue
-from .common import apply_epilogue, split_epilogue_refs, upcast_f32
+from .common import (
+    apply_epilogue,
+    pallas_call,
+    split_epilogue_refs,
+    upcast_f32,
+)
 
 _NOOP = Epilogue()
 
@@ -73,13 +78,11 @@ def _gmm_kernel(epilogue: Epilogue, narrowed: bool,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("token_tile", "f_tile", "d_tile", "interpret",
-                     "epilogue"),
+    static_argnames=("token_tile", "f_tile", "d_tile", "epilogue"),
 )
 def grouped_matmul(x, tile_experts, weights, *, bias=None,
                    epilogue: Epilogue = _NOOP, token_tile: int = 128,
-                   f_tile: int = 128, d_tile: int = 128,
-                   interpret: bool = True):
+                   f_tile: int = 128, d_tile: int = 128):
     """x: (T_pad, D) tokens sorted by expert, T_pad % token_tile == 0;
     tile_experts: (T_pad // token_tile,) int32 expert of each token tile;
     weights: (E, D, F); bias: (E, F) per-expert, required iff
@@ -121,9 +124,8 @@ def grouped_matmul(x, tile_experts, weights, *, bias=None,
             if narrowed else []
         ),
     )
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_gmm_kernel, epilogue, narrowed),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_pad, f), out_dtype),
-        interpret=interpret,
     )(*operands)

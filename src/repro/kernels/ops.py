@@ -9,7 +9,7 @@ import numpy as np
 
 import jax
 
-from ..core.dtypes import operand_dtype, operand_itemsize, storage_dtype
+from ..core.dtypes import operand_dtype, storage_dtype
 from ..core.schedule import ACTIVATIONS, Epilogue, Schedule
 from ..sparse.formats import (
     CSR,
@@ -20,14 +20,21 @@ from ..sparse.formats import (
     round_up,
 )
 from . import ref
+from .common import VMEM_CAPACITY, VMEM_HEADROOM
 from .grouped_matmul import grouped_matmul as _gmm_pallas
 from .sddmm import sddmm as _sddmm_kernel
 from .spmm_eb import spmm_eb as _spmm_eb
+from .spmm_eb import vmem_need_eb
+from .spmm_rb import rb_width_tile, vmem_need_rb
 from .spmm_rb import spmm_rb as _spmm_rb
 
 _NOOP_EP = Epilogue()
 
-_VMEM_BYTES = 16 * 1024 * 1024  # v5e per-core VMEM
+#: VMEM a schedule's blocks may claim: the chip's VMEM less the
+#: compiler's headroom (``common.pallas_call`` asks for exactly the
+#: footprint, so this is the feasibility bound, not the 16 MiB default
+#: scoped limit a kernel gets without asking).
+_VMEM_BUDGET = VMEM_CAPACITY - VMEM_HEADROOM
 
 
 def _pad_cols(b, col_tile):
@@ -38,45 +45,46 @@ def _pad_cols(b, col_tile):
     return b, n
 
 
-def vmem_footprint_eb(k, n_rows, sched: Schedule, itemsize=4) -> int:
-    """Working set the EB kernel claims per grid cell (see spmm_eb.py)."""
-    return itemsize * (
-        k * sched.col_tile            # B block
-        + sched.nnz_tile * sched.col_tile  # partials
-        + n_rows * sched.col_tile     # out block
-        + 3 * sched.nnz_tile          # triplets
-    )
+def _col_tile(sched: Schedule, n: int) -> int:
+    """The column tile ``spmm`` launches with for an N-wide dense operand:
+    the schedule's tile rounded up to whole 128-lane vregs, shrunk to the
+    8-padded width.  Mosaic takes a block's last dim whole or in
+    multiples of 128, and a narrower tile fills the same lane-padded
+    VMEM, so a tile under 128 would only add grid steps."""
+    return min(round_up(sched.col_tile, 128), round_up(n, 8))
 
 
-def vmem_footprint_rb(k, width, sched: Schedule, itemsize=4,
-                      width_tile: int = 64) -> int:
-    """Working set the RB kernel claims per grid cell (see spmm_rb.py):
-    the whole-K B block plus the (row_tile × width_tile) ELL slabs, their
-    gathered expansion, and the output block."""
-    wt = min(max(width, 1), width_tile)
-    return itemsize * (
-        k * sched.col_tile                       # B block
-        + 2 * sched.row_tile * wt                # ecols + evals slabs
-        + sched.row_tile * wt * sched.col_tile   # gathered B rows
-        + sched.row_tile * sched.col_tile        # out block
-    )
+def vmem_footprint_eb(k, n_rows, sched: Schedule) -> int:
+    """VMEM the EB kernel claims for this schedule when the dense operand
+    spans more than one column tile: padded (sublane, 128) tiles and
+    pipeline buffers of the schedule's value storage and dense operand,
+    as ``spmm_eb.vmem_need_eb`` counts them."""
+    vd = sched.value_dtype
+    return vmem_need_eb(k, n_rows, nnz_tile=sched.nnz_tile,
+                        col_tile=sched.col_tile, b_dtype=operand_dtype(vd),
+                        vals_dtype=storage_dtype(vd), epilogue=sched.epilogue)
+
+
+def vmem_footprint_rb(k, width, sched: Schedule) -> int:
+    """VMEM the RB kernel claims for this schedule (see
+    ``spmm_rb.vmem_need_rb``): the whole-K B block plus the ELL value
+    slab, the gathered-slot scratch, and the output block."""
+    vd = sched.value_dtype
+    return vmem_need_rb(k, row_tile=sched.row_tile, col_tile=sched.col_tile,
+                        width_tile=rb_width_tile(max(width, 1)),
+                        b_dtype=operand_dtype(vd),
+                        vals_dtype=storage_dtype(vd), epilogue=sched.epilogue)
 
 
 def schedule_fits_vmem(sched: Schedule, *, n_rows: int, n_cols: int,
-                       row_max: int = 0, itemsize: int | None = None,
-                       budget: int = _VMEM_BYTES) -> bool:
+                       row_max: int = 0, budget: int = _VMEM_BUDGET) -> bool:
     """Whether a schedule's per-cell working set fits the VMEM budget —
     the feasibility predicate the autotuner prunes candidates with before
-    spending measurement time on them.  ``itemsize=None`` derives the
-    element width from the schedule's ``value_dtype`` (the B block and
-    its gathered expansion dominate the cell, so the operand width is
-    the honest bound)."""
-    if itemsize is None:
-        itemsize = operand_itemsize(sched.value_dtype)
+    spending measurement time on them."""
     if sched.kernel == "eb":
-        need = vmem_footprint_eb(n_cols, n_rows, sched, itemsize)
+        need = vmem_footprint_eb(n_cols, n_rows, sched)
     else:
-        need = vmem_footprint_rb(n_cols, max(row_max, 1), sched, itemsize)
+        need = vmem_footprint_rb(n_cols, max(row_max, 1), sched)
     return need <= budget
 
 
@@ -104,8 +112,7 @@ def _cast_stream(fmt, vals, dt):
 
 
 def spmm(a, b, schedule: Schedule | None = None, *,
-         bias=None, residual=None, impl: str = "pallas",
-         interpret: bool = True):
+         bias=None, residual=None, impl: str = "pallas"):
     """out = A @ B for sparse A (CSR / QuantizedCSR / GroupedCOO / ELL)
     and dense B, with the schedule's fused epilogue applied in-kernel.
 
@@ -166,7 +173,7 @@ def spmm(a, b, schedule: Schedule | None = None, *,
     elif vd is not None:
         b = b.astype(operand_dtype(vd))
 
-    col_tile = min(schedule.col_tile, round_up(b.shape[1], 8))
+    col_tile = _col_tile(schedule, b.shape[1])
     b_pad, n = _pad_cols(b, col_tile)
     n_pad = b_pad.shape[1]
 
@@ -187,7 +194,7 @@ def spmm(a, b, schedule: Schedule | None = None, *,
             nnz_tile=schedule.nnz_tile, col_tile=col_tile,
             group_size=schedule.group_size, strategy=schedule.strategy,
             heavy_tiles=a.heavy_tiles, epilogue=ep, scales=scales,
-            bias=bias_p, residual=res_p, interpret=interpret)
+            bias=bias_p, residual=res_p)
         return out[:, :n]
 
     # rb path
@@ -211,12 +218,12 @@ def spmm(a, b, schedule: Schedule | None = None, *,
     bias_p, res_p = _pad_epilogue_operands(ep, bias, residual, r_pad, n_pad)
     out = _spmm_rb(ecols, evals, b_pad, row_tile=schedule.row_tile,
                    col_tile=col_tile, epilogue=ep, scales=scales_p,
-                   bias=bias_p, residual=res_p, interpret=interpret)
+                   bias=bias_p, residual=res_p)
     return out[: a.shape[0], :n]
 
 
 def sddmm(rows, cols, a, b, scale=None, *, nnz_tile: int = 256,
-          impl: str = "pallas", interpret: bool = True):
+          impl: str = "pallas"):
     """vals[t] = <A[rows[t]], B[cols[t]]> (* scale[t]); rows/cols (nnz,).
 
     ``scale=None`` skips the scale operand entirely (no ``ones((nnz,))``
@@ -239,7 +246,7 @@ def sddmm(rows, cols, a, b, scale=None, *, nnz_tile: int = 256,
         a = jnp.pad(a, ((0, 0), (0, d_pad - d)))
         b = jnp.pad(b, ((0, 0), (0, d_pad - d)))
     out = _sddmm_kernel(rows_p, cols_p, a, b, scale_p, nnz_tile=nnz_tile,
-                        d_tile=d_tile, interpret=interpret)
+                        d_tile=d_tile)
     return out[:nnz]
 
 
@@ -271,7 +278,7 @@ def grouped_matmul_ref(x, tile_experts, weights, *, bias=None,
 def grouped_matmul(x, tile_experts, weights, *, bias=None,
                    epilogue: Epilogue = _NOOP_EP, token_tile: int = 128,
                    f_tile: int = 128, d_tile: int = 128,
-                   impl: str = "pallas", interpret: bool = True):
+                   impl: str = "pallas"):
     """Differentiable epilogued grouped matmul — the MoE expert GEMM as
     one Pallas launch per tile (GEMM + bias/activation/cast fused onto
     the output block; ``repro.fuse`` routes ``grouped_matmul`` chain
@@ -291,8 +298,7 @@ def grouped_matmul(x, tile_experts, weights, *, bias=None,
     def run(xx, ww, bb):
         return _gmm_pallas(xx, tile_experts, ww, bias=bb,
                            epilogue=epilogue, token_tile=token_tile,
-                           f_tile=f_tile, d_tile=d_tile,
-                           interpret=interpret)
+                           f_tile=f_tile, d_tile=d_tile)
 
     @jax.custom_vjp
     def fn(xx, ww, bb):

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import upcast_f32
+from .common import pallas_call, upcast_f32
 
 
 def _sddmm_kernel(*refs, has_scale: bool):
@@ -51,9 +51,9 @@ def _sddmm_kernel(*refs, has_scale: bool):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("nnz_tile", "d_tile", "interpret"))
+    jax.jit, static_argnames=("nnz_tile", "d_tile"))
 def sddmm(rows, cols, a, b, scale=None, *, nnz_tile: int = 256,
-          d_tile: int = 128, interpret: bool = True):
+          d_tile: int = 128):
     """rows/cols/scale: (nnz_pad,) padded to nnz_tile (scale 0 on padding,
     or scale omitted entirely — the wrapper crops trailing pad lanes);
     a: (M, D), b: (N, D) with D padded to d_tile by the wrapper."""
@@ -69,11 +69,10 @@ def sddmm(rows, cols, a, b, scale=None, *, nnz_tile: int = 256,
         pl.BlockSpec((m, d_tile), lambda i, u: (0, u)),
         pl.BlockSpec((n, d_tile), lambda i, u: (0, u)),
     ]
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_sddmm_kernel, has_scale=has_scale),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((nnz_tile,), lambda i, u: (i,)),
         out_shape=jax.ShapeDtypeStruct((nnz_pad,), jnp.float32),
-        interpret=interpret,
     )(*operands)

@@ -5,11 +5,15 @@ Grid: (col_tiles, nnz_tiles) — nnz innermost so consecutive grid steps
 revisit the same output block and accumulation is race-free.
 
 Per grid cell (one ``NNZ_TILE × COL_TILE`` block):
-  1. gather dense rows      B[cols]            (zero extension: padded
-                                                lanes gather row 0, val 0)
+  1. gather dense rows      B[cols]            one dynamic row window of
+                                               the resident B block per
+                                               lane, its index a scalar
+                                               read from SMEM (zero
+                                               extension: padded lanes
+                                               gather row 0, val 0)
   2. scale by values        P = vals ⊙ B[cols]
-  3. segment-group reduce   width-G one-hot MXU reduce + runtime
-                            writeback (see kernels/common.py)
+  3. segment-group reduce   per-run masked reduce + runtime writeback
+                            (see kernels/common.py)
   4. on the *last* nnz step of a column block: the fused epilogue
      (bias / activation / residual / dtype cast — DESIGN.md §8), so a
      GCN layer's ``act(A @ XW + b)`` is one kernel instead of three HBM
@@ -17,10 +21,12 @@ Per grid cell (one ``NNZ_TILE × COL_TILE`` block):
      ``epilogue-fold`` rule targets (``repro.fuse``, DESIGN.md §10):
      ewise chain nodes legal under ``Epilogue.extended`` land here.
 
-VMEM working set per cell:  B block (K × COL_TILE) + partials
-(NNZ_TILE × COL_TILE) + out block (n_rows × COL_TILE). The kernel targets
-the paper's *balance-intensive* regime (few dense columns), where these
-comfortably fit VMEM; ``ops.spmm`` asserts the footprint.
+VMEM working set (``vmem_need_eb``): the B block (K × COL_TILE) and the
+out block (n_rows × COL_TILE), both resident for a whole column block,
+both lane-padded to 128 and double-buffered unless one column tile
+covers N, plus the partials (NNZ_TILE × COL_TILE).  The launch asks the
+compiler for that plus headroom (``common.pallas_call``); operands
+whose blocks exceed the chip's VMEM are refused, not windowed.
 """
 from __future__ import annotations
 
@@ -29,13 +35,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.schedule import Epilogue
 from .common import (
     apply_epilogue,
+    block_buffers,
     group_reduce_scatter,
+    pallas_call,
     split_epilogue_refs,
     upcast_f32,
+    vmem_bytes,
 )
 
 _NOOP = Epilogue()
@@ -46,6 +56,7 @@ def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
                     epilogue: Epilogue, narrowed: bool, quantized: bool):
     if quantized:
         scales_ref, *refs = refs
+    *refs, part_ref = refs
     bias_ref, res_ref, out_ref, acc_ref = split_epilogue_refs(
         refs, epilogue, narrowed)
     # out_dtype narrowing: accumulate in the f32 scratch, cast only at
@@ -56,21 +67,24 @@ def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    rows = rows_ref[...]
-    cols = cols_ref[...]
-    # storage may be narrow (bf16/fp8) or int8 codes — all arithmetic is
-    # f32 from here on (the upcast_f32 accumulation contract)
-    vals = upcast_f32(vals_ref[...])
-    b = upcast_f32(b_ref[...])
-    if quantized:
-        # per-lane dequant *before* the segment reduce: scales are
-        # per-row (segment-aligned), so partials combine exactly as in
-        # the f32 kernel and the scatter stays monoid-correct.  Padded
-        # lanes gather the pad row's scale with val 0 — still zero.
-        vals = vals * jnp.take(upcast_f32(scales_ref[...]), rows)
+    # gather: lane t takes dense row cols[t], a dynamic one-row window of
+    # the resident B block addressed by a scalar from SMEM.  Storage may
+    # be narrow (bf16/fp8) or int8 codes — all arithmetic is f32 from
+    # here on (the upcast_f32 accumulation contract)
+    def gather(t, c):
+        row = upcast_f32(b_ref[pl.ds(cols_ref[0, t], 1), :])
+        if quantized:
+            # per-lane dequant *before* the segment reduce: scales are
+            # per-row (segment-aligned), so partials combine exactly as
+            # in the f32 kernel and the scatter stays monoid-correct.
+            # Padded lanes take the pad row's scale with val 0 — still 0
+            row = row * scales_ref[0, rows_ref[0, t]]
+        part_ref[pl.ds(t, 1), :] = row
+        return c
 
-    gathered = jnp.take(b, cols, axis=0)  # (T, C)
-    partial = gathered * vals[:, None]
+    jax.lax.fori_loop(0, part_ref.shape[0], gather, 0)
+    # scale by values: the (1, T) lane row turns into a (T, 1) column
+    part_ref[...] = part_ref[...] * upcast_f32(vals_ref[...]).T
     if heavy_tiles > 0 and strategy != "parallel":
         # two-level skew layout (DESIGN.md §11): the leading heavy tiles
         # hold single-row groups, so they run the registry's 'parallel'
@@ -78,20 +92,56 @@ def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
         # group, the accumulate-style cross-group combine for split rows
         @pl.when(pl.program_id(1) < heavy_tiles)
         def _heavy():
-            group_reduce_scatter(rows, partial, acc, group_size,
+            group_reduce_scatter(rows_ref, part_ref, acc, group_size,
                                  "parallel")
 
         @pl.when(pl.program_id(1) >= heavy_tiles)
         def _tail():
-            group_reduce_scatter(rows, partial, acc, group_size, strategy)
+            group_reduce_scatter(rows_ref, part_ref, acc, group_size,
+                                 strategy)
     else:
-        group_reduce_scatter(rows, partial, acc, group_size, strategy)
+        group_reduce_scatter(rows_ref, part_ref, acc, group_size, strategy)
 
     if not epilogue.is_noop:
         @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
         def _epilogue():
             apply_epilogue(out_ref, epilogue, bias_ref, res_ref,
                            acc_ref=acc_ref)
+
+
+def vmem_need_eb(k: int, n_rows: int, *, nnz_tile: int, col_tile: int,
+                 n: int | None = None, b_dtype=jnp.float32,
+                 vals_dtype=jnp.float32, epilogue: Epilogue = _NOOP) -> int:
+    """Padded, buffered VMEM bytes of one ``spmm_eb`` launch over an
+    N-wide dense operand (``n=None``: more than one column tile): the
+    whole-K B column block and the whole-n_rows output column block
+    (single-buffered when one column tile covers N, double-buffered
+    otherwise), the value lanes, the partials scratch, and the
+    epilogue's bias/residual blocks and f32 accumulator.  Row/column
+    indices and scales live in SMEM.
+
+    This is the compiler's scoped allocation when XLA leaves the
+    operands in HBM (``tests/test_tpu_compile.py`` checks it to the
+    byte).  XLA may place narrow operands in VMEM itself — at PubMed
+    size with 16 columns it places B, the output and the value lanes
+    there (``S(1)`` in the compiled HLO).  The kernel then reads them
+    in place and only the partials scratch stays scoped; those blocks
+    still take VMEM, XLA's instead of the kernel's, so the count bounds
+    what the launch holds and the limit it asks for over-reserves by
+    them."""
+    out_dtype = jnp.dtype(epilogue.out_dtype or jnp.float32)
+    cb = block_buffers(1 if n == col_tile else 2)
+    need = (vmem_bytes((k, col_tile), b_dtype, cb)
+            + vmem_bytes((n_rows, col_tile), out_dtype, cb)
+            + vmem_bytes((1, nnz_tile), vals_dtype, 2)
+            + vmem_bytes((nnz_tile, col_tile), jnp.float32))
+    if epilogue.bias:
+        need += vmem_bytes((1, col_tile), jnp.float32, cb)
+    if epilogue.residual:
+        need += vmem_bytes((n_rows, col_tile), jnp.float32, cb)
+    if out_dtype != jnp.float32:
+        need += vmem_bytes((n_rows, col_tile), jnp.float32)
+    return need
 
 
 @functools.partial(
@@ -103,7 +153,7 @@ def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
             col_tile: int = 128, group_size: int = 32,
             strategy: str = "segment", heavy_tiles: int = 0,
             epilogue: Epilogue = _NOOP, scales=None,
-            bias=None, residual=None, interpret: bool = True):
+            bias=None, residual=None, interpret: bool | None = None):
     """out (n_rows, N) = scatter-reduce over padded COO triplets × B,
     with the fused ``epilogue`` applied to each output block on its last
     reduction step (``bias`` (1, N) and ``residual`` (n_rows, N) are
@@ -119,26 +169,39 @@ def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
     ``scales`` (n_rows,) f32, when given, selects the quantized value
     path (DESIGN.md §13): ``vals`` holds int8 codes and every lane is
     dequantized ``val * scales[row]`` before the segment reduce.  The
-    scale vector stays resident in VMEM across nnz steps (constant index
+    scale vector stays resident in SMEM across nnz steps (constant index
     map) — the dequant adds no per-nnz HBM traffic.
+
+    ``interpret`` defaults to the backend's answer (``common.pallas_call``);
+    a test compiles for a described TPU by passing ``False``.
     """
     nnz_pad = vals.shape[0]
     k, n = b.shape
     assert nnz_pad % nnz_tile == 0 and n % col_tile == 0, (nnz_pad, n)
     grid = (n // col_tile, nnz_pad // nnz_tile)
 
-    operands = [rows, cols, vals, b]
+    # lanes travel as (tiles, 1, nnz_tile): one (1, nnz_tile) block per
+    # nnz step — indices to SMEM (scalar reads), values to VMEM
+    def lanes(x):
+        return x.reshape(-1, 1, nnz_tile)
+
+    def lane_spec(space=None):
+        return pl.BlockSpec((None, 1, nnz_tile), lambda j, i: (i, 0, 0),
+                            memory_space=space)
+
+    operands = [lanes(rows), lanes(cols), lanes(vals), b]
     in_specs = [
-        pl.BlockSpec((nnz_tile,), lambda j, i: (i,)),
-        pl.BlockSpec((nnz_tile,), lambda j, i: (i,)),
-        pl.BlockSpec((nnz_tile,), lambda j, i: (i,)),
+        lane_spec(pltpu.SMEM),
+        lane_spec(pltpu.SMEM),
+        lane_spec(),
         pl.BlockSpec((k, col_tile), lambda j, i: (0, j)),
     ]
     quantized = scales is not None
     if quantized:
         assert scales.shape == (n_rows,), (scales.shape, n_rows)
-        operands.append(scales)
-        in_specs.append(pl.BlockSpec((n_rows,), lambda j, i: (0,)))
+        operands.append(scales.reshape(1, n_rows))
+        in_specs.append(pl.BlockSpec((1, n_rows), lambda j, i: (0, 0),
+                                     memory_space=pltpu.SMEM))
     if epilogue.bias:
         assert bias is not None and bias.shape == (1, n), (n, bias)
         operands.append(bias)
@@ -150,22 +213,22 @@ def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
             pl.BlockSpec((n_rows, col_tile), lambda j, i: (0, j)))
     out_dtype = jnp.dtype(epilogue.out_dtype or jnp.float32)
     narrowed = out_dtype != jnp.float32
-    scratch = []
-    if narrowed:
-        from jax.experimental.pallas import tpu as pltpu
-
-        scratch = [pltpu.VMEM((n_rows, col_tile), jnp.float32)]
+    scratch = [pltpu.VMEM((n_rows, col_tile), jnp.float32)] if narrowed else []
+    scratch.append(pltpu.VMEM((nnz_tile, col_tile), jnp.float32))
 
     kernel = functools.partial(
         _spmm_eb_kernel, group_size=group_size, strategy=strategy,
         heavy_tiles=heavy_tiles, epilogue=epilogue, narrowed=narrowed,
         quantized=quantized)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((n_rows, col_tile), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((n_rows, n), out_dtype),
         scratch_shapes=scratch,
+        vmem_need=vmem_need_eb(k, n_rows, nnz_tile=nnz_tile,
+                               col_tile=col_tile, n=n, b_dtype=b.dtype,
+                               vals_dtype=vals.dtype, epilogue=epilogue),
         interpret=interpret,
     )(*operands)

@@ -1,20 +1,21 @@
-"""Real-backend setup helpers (platform, XLA flags, precision defaults).
+"""Backend setup helpers (platform, compile cache, precision defaults).
 
-Everything in this repo runs interpreted Pallas on CPU by default; this
-module is the one place that knows how to point the same code at a real
-backend.  All helpers only take effect when called *before* jax
-initializes its backends (first device query / first trace), which is
-why none of them are called at import time anywhere in the library —
-launch scripts call :func:`setup` as their first statement.
+The Pallas kernels run compiled on a TPU and interpreted everywhere else
+(:func:`pallas_interpret_default`, which ``kernels.common.pallas_call``
+consults at every launch).  The setup helpers only take effect when
+called *before* jax initializes its backends (first device query / first
+trace), which is why none of them are called at import time anywhere in
+the library — launch scripts call :func:`setup` as their first
+statement.
 
-``backend_info`` is safe to call any time and is what benches/CI record
+``backend_info`` is safe to call any time and is what benches record
 next to their numbers, so a result file says which backend (and whether
 fp8 storage was real or degraded) produced it.
 """
 from __future__ import annotations
 
 import os
-import warnings
+from pathlib import Path
 
 __all__ = [
     "backend_info",
@@ -25,38 +26,24 @@ __all__ = [
     "setup",
 ]
 
-# XLA GPU flags that help bandwidth-bound sparse workloads (latency
-# hiding + async collectives); harmless elsewhere, only applied for gpu.
-_GPU_XLA_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true "
-    "--xla_gpu_enable_highest_priority_async_stream=true"
-)
-
-
-def _append_xla_flags(flags: str) -> None:
-    cur = os.environ.get("XLA_FLAGS", "")
-    missing = [f for f in flags.split() if f not in cur]
-    if missing:
-        os.environ["XLA_FLAGS"] = " ".join(([cur] if cur else []) + missing)
+#: Persistent compile cache used when ``JAX_COMPILATION_CACHE_DIR`` is not
+#: set: a fixed directory inside the checkout (listed in ``.gitignore``).
+#: The path is part of the cache key, so it never depends on a tempdir,
+#: a pid or the clock.
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def set_platform(platform: str = "cpu") -> None:
-    """Pin jax to ``'cpu'``/``'gpu'``/``'tpu'``; call before any jax use.
-
-    GPU additionally gets the bandwidth-oriented XLA flags (appended to
-    any existing ``XLA_FLAGS``, never clobbering e.g. a forced host
-    device count)."""
-    if platform not in ("cpu", "gpu", "tpu"):
+    """Pin jax to ``'cpu'`` or ``'tpu'``; call before any jax use."""
+    if platform not in ("cpu", "tpu"):
         raise ValueError(f"unknown platform {platform!r}")
     import jax
 
     jax.config.update("jax_platform_name", platform)
-    if platform == "gpu":
-        _append_xla_flags(_GPU_XLA_FLAGS)
 
 
 def set_host_device_count(n: int) -> None:
-    """Force ``n`` host (CPU) devices via XLA_FLAGS — the multi-device CI
+    """Force ``n`` host (CPU) devices via XLA_FLAGS — the multi-device test
     lane's mechanism (``launch/dryrun.py`` idiom).  Must run before the
     first jax import in the process to take effect; appending here keeps
     other flags intact."""
@@ -79,9 +66,9 @@ def enable_x64(on: bool = True) -> None:
 
 
 def pallas_interpret_default() -> bool:
-    """Whether Pallas kernels should run interpreted on this backend:
-    True off-TPU (interpret mode is the only Pallas path on CPU), False
-    on real TPU hardware."""
+    """Whether Pallas kernels run interpreted on this backend: True
+    off-TPU (interpret mode is the only Pallas path on CPU), False on TPU
+    hardware, where every kernel runs compiled."""
     import jax
 
     return jax.default_backend() != "tpu"
@@ -90,27 +77,30 @@ def pallas_interpret_default() -> bool:
 def setup(platform: str | None = None, *, host_devices: int | None = None,
           x64: bool = False) -> dict:
     """One-call launch-script prologue: optionally pin the platform and
-    host device count, set precision defaults, and return
-    :func:`backend_info` for logging.  Warns (instead of failing) when
-    jax already initialized — the flags would silently not apply."""
+    host device count, keep compiled programs in the persistent cache,
+    set precision defaults, and return :func:`backend_info` (plus the
+    cache directory) for logging.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
+    no cache directory is set here; otherwise the cache goes to
+    :data:`DEFAULT_COMPILE_CACHE`."""
     import jax
 
-    if jax._src.xla_bridge._backends and (platform or host_devices):
-        warnings.warn(
-            "launch.backend.setup() called after jax backend "
-            "initialization; platform/device-count settings may not "
-            "apply", RuntimeWarning, stacklevel=2)
     if host_devices is not None:
         set_host_device_count(host_devices)
     if platform is not None:
         set_platform(platform)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = str(DEFAULT_COMPILE_CACHE)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     enable_x64(x64)
-    return backend_info()
+    return {**backend_info(), "compile_cache": cache_dir}
 
 
 def backend_info() -> dict:
     """Snapshot of the realized backend: platform, device kind/count,
-    whether fp8 storage is native (vs the bf16 degradation,
+    whether fp8 storage is on (vs the bf16 degradation,
     ``core.dtypes.fp8_supported``), and the Pallas interpret default."""
     import jax
 

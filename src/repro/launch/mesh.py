@@ -30,10 +30,9 @@ def make_reduction_mesh(axis_size: int | None = None, *,
                         axis: str = "shards"):
     """1-D mesh for the distributed reduction collectives (DESIGN.md §12:
     ``repro.sparse.dist_spmm`` / ``dist_attention_shard_map`` and the
-    distributed tuner).  Unlike the production builders this avoids
-    ``jax.sharding.AxisType`` (absent in older jax), so it works on the
-    pinned toolchain and under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` in CI."""
+    distributed tuner) over this process's devices — the chips of a TPU
+    host, or the forced host devices of
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` in tests."""
     n = len(jax.devices())
     if axis_size is None:
         axis_size = n

@@ -177,8 +177,7 @@ def _flash_bwd(causal, q_chunk, kv_chunk, res, do):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def graph_attention(adj, q, k, v, *, schedule=None, scale=None,
-                    interpret: bool = True):
+def graph_attention(adj, q, k, v, *, schedule=None, scale=None):
     """Sparse (graph) attention over an adjacency pattern through the
     fused one-pass SDDMM→softmax→SpMM kernel
     (``repro.sparse.sparse_attention``), fused in both directions.
@@ -192,8 +191,7 @@ def graph_attention(adj, q, k, v, *, schedule=None, scale=None,
     """
     from ..sparse import sparse_attention
 
-    return sparse_attention(adj, q, k, v, schedule=schedule, scale=scale,
-                            interpret=interpret)
+    return sparse_attention(adj, q, k, v, schedule=schedule, scale=scale)
 
 
 def attention_ref(q, k, v, causal=True):

@@ -75,7 +75,7 @@ def apply_norm(cfg, p, x):
 
 
 def gcn_layer(adj, x, w, b=None, *, activation="relu", residual=None,
-              schedule="auto", interpret: bool = True):
+              schedule="auto"):
     """One GCN layer, fused: ``act(Ã (x @ w) + b) [+ residual]`` runs as a
     *single* scheduled SpMM kernel with an in-kernel epilogue
     (DESIGN.md §8) instead of three HBM passes (spmm → bias-add → act).
@@ -87,12 +87,12 @@ def gcn_layer(adj, x, w, b=None, *, activation="relu", residual=None,
     ep = Epilogue(activation=activation, bias=b is not None,
                   residual=residual is not None)
     return spmm(adj, x @ w, schedule=schedule, bias=b, residual=residual,
-                epilogue=ep, interpret=interpret)
+                epilogue=ep)
 
 
 def gcn_two_layer(adj, x, w0, w1, b0=None, b1=None, *,
                   activation="relu", final_activation=None, schedule=None,
-                  plan=None, interpret: bool = True):
+                  plan=None):
     """Two-layer GCN — ``Ã act(Ã (x @ w0) + b0) @ w1 [+ b1]`` — built as
     a ``repro.fuse`` chain and executed by the fusion planner: the
     activations/biases fold into their producing SpMM's epilogue, so the
@@ -112,7 +112,7 @@ def gcn_two_layer(adj, x, w0, w1, b0=None, b1=None, *,
                               final_activation=final_activation,
                               schedule=schedule)
     p = plan_chain(chain) if plan is None else plan
-    return run_plan(p, x, params, interpret=interpret)
+    return run_plan(p, x, params)
 
 
 # ---------------------------------------------------------------- linear

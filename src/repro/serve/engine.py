@@ -82,7 +82,7 @@ class ServeEngine:
         return sched
 
     def prepare_dist(self, csr, n_dense_cols: int, *, mesh, axis: str,
-                     value_dtypes=None, interpret: bool = True):
+                     value_dtypes=None):
         """Ahead-of-time tuning for a *sharded* sparse operand: one
         joint search over local tiling × collective mode × value dtype
         (:func:`~repro.tune.tune_dist_spmm` on the §14 driver), persisted
@@ -95,8 +95,7 @@ class ServeEngine:
         if value_dtypes is not None:
             kw["value_dtypes"] = value_dtypes
         res = tune_dist_spmm(csr, n_dense_cols, mesh=mesh, axis=axis,
-                             cache=self.tuner_cache, interpret=interpret,
-                             **kw)
+                             cache=self.tuner_cache, **kw)
         axis_size = int(mesh.shape[axis])
         self._sched_memo[
             f"dist:{cache_key(csr, n_dense_cols)}|mesh:{axis_size}"

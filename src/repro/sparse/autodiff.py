@@ -19,7 +19,7 @@ from ..kernels import ref
 
 
 def make_spmm(rows, cols, n_rows: int, n_cols: int, *, impl: str = "ref",
-              schedule=None, interpret: bool = True):
+              schedule=None):
     """Returns spmm_fn(vals, b) -> (n_rows, b.shape[1]) differentiable in
     vals and b. rows/cols: (nnz,) int32 (row-sorted preferred)."""
 
@@ -35,7 +35,7 @@ def make_spmm(rows, cols, n_rows: int, n_cols: int, *, impl: str = "ref",
             g = GroupedCOO(rows=rows, cols=cols, vals=vals,
                            shape=(n_rows, n_cols), nnz=vals.shape[0],
                            nnz_tile=vals.shape[0])
-            return kspmm(g, b, sched, interpret=interpret)
+            return kspmm(g, b, sched)
         return ref.spmm_coo_ref(rows, cols, vals, b, n_rows)
 
     @jax.custom_vjp

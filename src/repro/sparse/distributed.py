@@ -41,28 +41,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off (pallas_call has no
-    replication rule), tolerant of the check kwarg's rename across jax
-    versions (``check_rep`` -> ``check_vma``)."""
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("no compatible shard_map signature found")
-
 from ..core import COLLECTIVES, Schedule
 from ..kernels import ops as kops
 from ..kernels.fused_attention import NEG_INF, fused_sparse_attention
 from .formats import GroupedCOO, round_up
+
+#: ``jax.shard_map`` with replication checking off: pallas_call has no
+#: replication rule.
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 __all__ = [
     "COLLECTIVES",
@@ -175,8 +161,7 @@ def shard_nnz_counts(csr, axis_size: int, collective: str):
 # ---------------------------------------------------------------------------
 
 
-def _local_spmm(rows, cols, vals, b, n_rows, schedule: Schedule,
-                interpret: bool = True):
+def _local_spmm(rows, cols, vals, b, n_rows, schedule: Schedule):
     """Shard-local tuned Pallas SpMM over a (traced) padded COO slice.
 
     The skew layout is a host-side pass over concrete indices, and the
@@ -199,7 +184,7 @@ def _local_spmm(rows, cols, vals, b, n_rows, schedule: Schedule,
     g = GroupedCOO(rows=rows, cols=cols, vals=vals,
                    shape=(n_rows, int(b.shape[0])),
                    nnz=nnz_local, nnz_tile=s.nnz_tile)
-    return kops.spmm(g, b, s, interpret=interpret)
+    return kops.spmm(g, b, s)
 
 
 def _resolve_collective(mode, schedule):
@@ -218,8 +203,7 @@ def _resolve_collective(mode, schedule):
 
 def spmm_shard_map(rows, cols, vals, b, *, n_rows: int, mesh, axis: str,
                    mode: str | None = None,
-                   schedule: Schedule | None = None,
-                   interpret: bool = True):
+                   schedule: Schedule | None = None):
     """rows/cols/vals: (nnz_pad,) padded COO (pad val=0); b: (K, N).
 
     Sharding contract (enforced via shard_map in/out specs):
@@ -249,8 +233,7 @@ def spmm_shard_map(rows, cols, vals, b, *, n_rows: int, mesh, axis: str,
             out_specs=P(axis),
         )
         def _row(r, c, v, bb):
-            return _local_spmm(r, c, v, bb, n_rows // axis_size, sched,
-                               interpret)
+            return _local_spmm(r, c, v, bb, n_rows // axis_size, sched)
 
         return _row(rows, cols, vals, b)
 
@@ -262,7 +245,7 @@ def spmm_shard_map(rows, cols, vals, b, *, n_rows: int, mesh, axis: str,
             out_specs=P(),
         )
         def _ar(r, c, v, bb):
-            partial = _local_spmm(r, c, v, bb, n_rows, sched, interpret)
+            partial = _local_spmm(r, c, v, bb, n_rows, sched)
             return jax.lax.psum(partial, axis)  # atomic-style combine
 
         return _ar(rows, cols, vals, b)
@@ -279,7 +262,7 @@ def spmm_shard_map(rows, cols, vals, b, *, n_rows: int, mesh, axis: str,
         out_specs=P(axis),
     )
     def _rs(r, c, v, bb):
-        partial = _local_spmm(r, c, v, bb, n_rows, sched, interpret)
+        partial = _local_spmm(r, c, v, bb, n_rows, sched)
         # segment-style combine: each shard finalizes its row block
         return jax.lax.psum_scatter(
             partial, axis, scatter_dimension=0, tiled=True)
@@ -288,7 +271,7 @@ def spmm_shard_map(rows, cols, vals, b, *, n_rows: int, mesh, axis: str,
 
 
 def dist_spmm(csr, b, *, mesh, axis: str, schedule=None,
-              cache=None, backend=None, interpret: bool = True):
+              cache=None, backend=None):
     """``csr @ b`` under shard_map, partitioning chosen by the schedule.
 
     ``schedule`` accepts a :class:`Schedule` (its ``collective`` picks
@@ -320,8 +303,7 @@ def dist_spmm(csr, b, *, mesh, axis: str, schedule=None,
         vals, b = _storage_feed(vals, b, sched.value_dtype)
     return spmm_shard_map(rows, cols, vals, b, n_rows=csr.shape[0],
                           mesh=mesh, axis=axis, mode=mode,
-                          schedule=sched.replace(collective=mode),
-                          interpret=interpret)
+                          schedule=sched.replace(collective=mode))
 
 
 # ---------------------------------------------------------------------------
@@ -330,7 +312,7 @@ def dist_spmm(csr, b, *, mesh, axis: str, schedule=None,
 
 
 def _local_attention(rows, cols, q, k, v, *, n_rows, dv_tile, scale,
-                     sched, bias=None, interpret=True):
+                     sched, bias=None):
     """Run the fused kernel over a shard's lanes at height ``n_rows`` + 1
     phantom row (pad lanes land there; the caller crops it)."""
     strategy = (sched.strategy
@@ -349,8 +331,7 @@ def _local_attention(rows, cols, q, k, v, *, n_rows, dv_tile, scale,
         rows, cols, q_ph, k, v, n_rows=n_rows + 1,
         nnz=int(rows.shape[0]), nnz_tile=sched.nnz_tile,
         dv_tile=dv_tile, scale=scale,
-        group_size=sched.group_size, strategy=strategy, bias=bias,
-        interpret=interpret)
+        group_size=sched.group_size, strategy=strategy, bias=bias)
     return out[:, :n_rows], m[:, :n_rows], l[:, :n_rows]
 
 
@@ -383,8 +364,7 @@ def _combine_partials(out_s, m_s, l_s, axis, *, scatter):
 def dist_attention_shard_map(rows, cols, q, k, v, *, n_rows: int, mesh,
                              axis: str, mode: str | None = None,
                              schedule: Schedule | None = None,
-                             scale: float | None = None, bias=None,
-                             interpret: bool = True):
+                             scale: float | None = None, bias=None):
     """Sparse attention under shard_map with the row/nnz_ar/nnz_rs trio.
 
     rows/cols: (nnz_pad,) adjacency lane streams built by the partition
@@ -441,8 +421,7 @@ def dist_attention_shard_map(rows, cols, q, k, v, *, n_rows: int, mesh,
             qq, kk, vv = rest[-3:]
             out, _, _ = _local_attention(r, c, qq, kk, vv, n_rows=block,
                                          dv_tile=dv_tile, scale=scale,
-                                         sched=sched, bias=b,
-                                         interpret=interpret)
+                                         sched=sched, bias=b)
             return out
 
         args = (rows, cols) + ((bias,) if has_bias else ()) + (q, k, v)
@@ -463,7 +442,7 @@ def dist_attention_shard_map(rows, cols, q, k, v, *, n_rows: int, mesh,
             qq, kk, vv = rest[-3:]
             out_s, m_s, l_s = _local_attention(
                 r, c, qq, kk, vv, n_rows=n_rows, dv_tile=dv_tile,
-                scale=scale, sched=sched, bias=b, interpret=interpret)
+                scale=scale, sched=sched, bias=b)
             return _combine_partials(out_s, m_s, l_s, axis,
                                      scatter=mode == "nnz_rs")
 

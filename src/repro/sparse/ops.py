@@ -101,8 +101,7 @@ def _derive_epilogue(schedule, epilogue, bias, residual) -> Epilogue | None:
 
 
 def spmm(a, b, schedule="auto", *, bias=None, residual=None,
-         epilogue: Epilogue | None = None, impl: str = "pallas",
-         interpret: bool = True):
+         epilogue: Epilogue | None = None, impl: str = "pallas"):
     """out = epilogue(A @ B) for sparse A (CSR / GroupedCOO / ELL) and
     dense B.
 
@@ -132,17 +131,16 @@ def spmm(a, b, schedule="auto", *, bias=None, residual=None,
     sched = _resolve_schedule(a, b, schedule, epilogue=ep)
     if impl != "ref":
         if isinstance(a, QuantizedCSR):
-            return _spmm_quant_diff(a, b, sched, interpret, bias, residual)
+            return _spmm_quant_diff(a, b, sched, bias, residual)
         if isinstance(a, CSR):
             if sched.value_dtype == "int8":
-                return _spmm_quant_diff(a.quantized(), b, sched,
-                                        interpret, bias, residual)
-            return _spmm_csr_diff(a, b, sched, interpret, bias, residual)
-    return kops.spmm(a, b, sched, bias=bias, residual=residual,
-                     impl=impl, interpret=interpret)
+                return _spmm_quant_diff(a.quantized(), b, sched, bias,
+                                        residual)
+            return _spmm_csr_diff(a, b, sched, bias, residual)
+    return kops.spmm(a, b, sched, bias=bias, residual=residual, impl=impl)
 
 
-def _spmm_csr_diff(a: CSR, b, sched: Schedule, interpret: bool,
+def _spmm_csr_diff(a: CSR, b, sched: Schedule,
                    bias=None, residual=None):
     """Custom-VJP wrapper: scheduled (epilogued) kernel forward, ref
     backward.  ``y = act(A@B + bias) + residual`` (then dtype cast), so
@@ -174,7 +172,7 @@ def _spmm_csr_diff(a: CSR, b, sched: Schedule, interpret: bool,
                                shape=g0.shape, nnz=g0.nnz,
                                nnz_tile=g0.nnz_tile, skew=g0.skew)
                 return kops.spmm(g, bb, sched, bias=bias_x,
-                                 residual=res_x, interpret=interpret)
+                                 residual=res_x)
         else:
             pad = g0.nnz_padded - g0.nnz
 
@@ -185,7 +183,7 @@ def _spmm_csr_diff(a: CSR, b, sched: Schedule, interpret: bool,
                                shape=g0.shape, nnz=g0.nnz,
                                nnz_tile=g0.nnz_tile)
                 return kops.spmm(g, bb, sched, bias=bias_x,
-                                 residual=res_x, interpret=interpret)
+                                 residual=res_x)
     else:
         ell0 = a.ell(row_tile=sched.row_tile)
         rid, pos = a.ell_scatter_index()
@@ -195,8 +193,7 @@ def _spmm_csr_diff(a: CSR, b, sched: Schedule, interpret: bool,
                               vals.dtype).at[rid, pos].set(vals)
             e = ELL(cols=ell0.cols, vals=evals, shape=ell0.shape,
                     width=ell0.width)
-            return kops.spmm(e, bb, sched, bias=bias_x, residual=res_x,
-                             interpret=interpret)
+            return kops.spmm(e, bb, sched, bias=bias_x, residual=res_x)
 
     @jax.custom_vjp
     def _fn(vals, bb, bias_x, res_x):
@@ -233,7 +230,7 @@ def _spmm_csr_diff(a: CSR, b, sched: Schedule, interpret: bool,
     return _fn(a.vals, b, bias, residual)
 
 
-def _spmm_quant_diff(qa: QuantizedCSR, b, sched: Schedule, interpret: bool,
+def _spmm_quant_diff(qa: QuantizedCSR, b, sched: Schedule,
                      bias=None, residual=None):
     """Custom-VJP wrapper for the int8 quantized path: the scheduled
     kernel moves int8 codes + per-row scales forward; the backward runs
@@ -247,8 +244,7 @@ def _spmm_quant_diff(qa: QuantizedCSR, b, sched: Schedule, interpret: bool,
     vals_f = qa.dequantize().vals  # f32 stream for the ref backward
 
     def run(bb, bias_x, res_x):
-        return kops.spmm(qa, bb, sched, bias=bias_x, residual=res_x,
-                         interpret=interpret)
+        return kops.spmm(qa, bb, sched, bias=bias_x, residual=res_x)
 
     @jax.custom_vjp
     def _fn(bb, bias_x, res_x):
@@ -282,8 +278,7 @@ def _spmm_quant_diff(qa: QuantizedCSR, b, sched: Schedule, interpret: bool,
 
 
 def sddmm(rows, cols, a, b, scale=None, *, schedule=None,
-          nnz_tile: int | None = None, impl: str = "pallas",
-          interpret: bool = True):
+          nnz_tile: int | None = None, impl: str = "pallas"):
     """vals[t] = <A[rows[t]], B[cols[t]]> (* scale[t]); rows/cols (nnz,).
 
     ``schedule`` supplies the nnz tile (its ``nnz_tile`` field); an
@@ -301,12 +296,11 @@ def sddmm(rows, cols, a, b, scale=None, *, schedule=None,
         else:
             nnz_tile = as_schedule(schedule).nnz_tile
     return kops.sddmm(rows, cols, a, b, scale,
-                      nnz_tile=nnz_tile if nnz_tile else 256,
-                      impl=impl, interpret=interpret)
+                      nnz_tile=nnz_tile if nnz_tile else 256, impl=impl)
 
 
 def segment_reduce(seg_ids, data, num_segments: int, schedule=None, *,
-                   op: str = "sum", interpret: bool = True):
+                   op: str = "sum"):
     """out[s] = ⨁_{t: seg_ids[t]=s} data[t] through the segment-group
     kernel, for ``op`` in 'sum' / 'max' / 'min' / 'mean'.
 
@@ -332,13 +326,12 @@ def segment_reduce(seg_ids, data, num_segments: int, schedule=None, *,
              jnp.ones((data.shape[0], 1), jnp.float32)], axis=1)
         out = _segment_reduce_kernel(
             seg_ids, aug, num_segments=num_segments, tile=sched.nnz_tile,
-            group_size=sched.group_size, strategy=sched.strategy,
-            interpret=interpret)
+            group_size=sched.group_size, strategy=sched.strategy)
         return out[:, :-1] / jnp.maximum(out[:, -1:], 1.0)
     return _segment_reduce_kernel(
         seg_ids, data, num_segments=num_segments, tile=sched.nnz_tile,
         group_size=sched.group_size, strategy=sched.strategy,
-        op="add" if op == "sum" else op, interpret=interpret)
+        op="add" if op == "sum" else op)
 
 
 # ---------------------------------------------------------------------------
@@ -383,8 +376,7 @@ def _attn_heads(q, k, v):
 
 
 def sparse_attention(adj, q, k, v, *, schedule=None,
-                     scale: float | None = None, impl: str = "pallas",
-                     interpret: bool = True):
+                     scale: float | None = None, impl: str = "pallas"):
     """One-pass sparse attention over a sparsity pattern:
     ``out[r] = Σ_t softmax_row(<Q[r], K[c_t]> · scale + bias_t) V[c_t]``.
 
@@ -434,8 +426,8 @@ def sparse_attention(adj, q, k, v, *, schedule=None,
         from ..tune import tune_sparse_attention
 
         sched = tune_sparse_attention(
-            rows, cols, q, k, v, n_rows=n_rows, bias=bias, scale=scale,
-            interpret=interpret).schedule
+            rows, cols, q, k, v, n_rows=n_rows, bias=bias,
+            scale=scale).schedule
     else:
         sched = as_schedule(schedule)
     if sched.strategy == "parallel":
@@ -443,12 +435,12 @@ def sparse_attention(adj, q, k, v, *, schedule=None,
             "sparse_attention cannot run the 'parallel' strategy: its "
             "single-writeback contract does not hold for attention rows")
     out = _sparse_attention_diff(rows, cols, qh, kh, vh, n_rows, scale,
-                                 sched, interpret, bias)
+                                 sched, bias)
     return jnp.moveaxis(out, 0, 1) if multi else out[0]
 
 
 def _sparse_attention_diff(rows, cols, qh, kh, vh, n_rows, scale, sched,
-                           interpret, bias=None):
+                           bias=None):
     """Custom-VJP core over head-major (H, n, ·) operands: fused Pallas
     forward (saving the (m, l) softmax row stats — the O(H·n_rows)
     FlashAttention residuals), fused Pallas backward."""
@@ -470,7 +462,7 @@ def _sparse_attention_diff(rows, cols, qh, kh, vh, n_rows, scale, sched,
             rows_p, cols_p, q, k, v_p, n_rows=n_rows, nnz=nnz,
             nnz_tile=nnz_tile, dv_tile=dv_tile, scale=scale,
             group_size=sched.group_size, strategy=sched.strategy,
-            bias=bias_p, interpret=interpret)
+            bias=bias_p)
         return out[..., :dv], m, l
 
     @jax.custom_vjp
@@ -486,7 +478,7 @@ def _sparse_attention_diff(rows, cols, qh, kh, vh, n_rows, scale, sched,
         dq, dk, dv_ = _fused_attn_bwd(
             rows_p, cols_p, q, k, v, dout, m, l, n_rows=n_rows, nnz=nnz,
             nnz_tile=nnz_tile, scale=scale, group_size=sched.group_size,
-            strategy=sched.strategy, bias=bias_p, interpret=interpret)
+            strategy=sched.strategy, bias=bias_p)
         return (dq.astype(q.dtype), dk.astype(k.dtype),
                 dv_.astype(v.dtype))
 

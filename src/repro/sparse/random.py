@@ -130,6 +130,53 @@ def graph_pattern_csr(pattern: str, n_rows: int, n_cols: int | None = None,
                          avg_degree=avg_degree, alpha=alpha, seed=seed)
 
 
+#: Node, undirected-edge, feature and class counts of Planetoid PubMed
+#: (Kipf & Welling, ICLR 2017, Table 1) — the GCN workload's published
+#: widths.  The graph itself is generated (:func:`gcn_graph_csr`).
+PUBMED = {"n_nodes": 19717, "n_edges": 44338, "n_features": 500,
+          "n_classes": 3}
+
+
+def gcn_graph_csr(n_nodes: int, n_edges: int, *, alpha: float = 0.5,
+                  seed: int = 0) -> CSR:
+    """GCN propagation matrix ``D^-1/2 (A + I) D^-1/2`` of a seeded
+    undirected graph with exactly ``n_edges`` distinct non-loop edges:
+    ``2 * n_edges + n_nodes`` stored entries, symmetric, rows sorted.
+
+    Endpoints are drawn with popularity ``(rank+1)^-alpha`` over a random
+    node order, so degrees are skewed as in citation graphs (a few hubs,
+    most nodes with one or two neighbours) rather than uniform."""
+    rng = np.random.default_rng(seed)
+    w = np.arange(1, n_nodes + 1, dtype=np.float64) ** -alpha
+    w = w[rng.permutation(n_nodes)]
+    w /= w.sum()
+    keys = np.empty(0, np.int64)
+    while keys.size < n_edges:
+        u, v = rng.choice(n_nodes, size=(2, 2 * n_edges), p=w)
+        lo, hi = np.minimum(u, v), np.maximum(u, v)
+        new = (lo * n_nodes + hi)[lo != hi]
+        keys = np.concatenate([keys, new])
+        _, first = np.unique(keys, return_index=True)
+        keys = keys[np.sort(first)]  # distinct, in order of drawing
+    keys = keys[:n_edges]
+    lo, hi = keys // n_nodes, keys % n_nodes
+    loops = np.arange(n_nodes, dtype=np.int64)
+    rows = np.concatenate([lo, hi, loops])
+    cols = np.concatenate([hi, lo, loops])
+    order = np.lexsort((cols, rows))
+    rows, cols = rows[order], cols[order]
+    deg = np.bincount(rows, minlength=n_nodes).astype(np.float64)
+    vals = (deg[rows] * deg[cols]) ** -0.5
+    indptr = np.zeros(n_nodes + 1, np.int64)
+    np.cumsum(np.bincount(rows, minlength=n_nodes), out=indptr[1:])
+    import jax.numpy as jnp
+
+    return CSR(indptr=jnp.asarray(indptr, jnp.int32),
+               indices=jnp.asarray(cols, jnp.int32),
+               vals=jnp.asarray(vals, jnp.float32),
+               shape=(n_nodes, n_nodes))
+
+
 #: Row-length quantile levels exposed in :func:`matrix_stats` (as
 #: percent keys): the skew candidate generator reads q50/q90/q99 to
 #: place split/merge thresholds, and the cost model interpolates the

@@ -82,7 +82,6 @@ def tune_sparse_attention(
     warmup: Optional[int] = None,
     iters: Optional[int] = None,
     backend: Optional[str] = None,
-    interpret: bool = True,
 ) -> TuneResult:
     """Empirically pick (nnz_tile, group_size, strategy) for the fused
     sparse-attention kernel over this pattern.
@@ -141,7 +140,7 @@ def tune_sparse_attention(
                     rows_p, cols_p, qq, kk, vv, n_rows=n_rows, nnz=nnz,
                     nnz_tile=s.nnz_tile, dv_tile=dv_tile, scale=scale,
                     group_size=s.group_size, strategy=s.strategy,
-                    bias=bias_p, interpret=interpret)
+                    bias=bias_p)
 
             if direction == "fwd":
                 return time_fn(lambda qq, kk, vv: fwd(qq, kk, vv)[0],
@@ -153,7 +152,7 @@ def tune_sparse_attention(
                     rows_p, cols_p, qq, kk, vv, do, m, l, n_rows=n_rows,
                     nnz=nnz, nnz_tile=s.nnz_tile, scale=scale,
                     group_size=s.group_size, strategy=s.strategy,
-                    bias=bias_p, interpret=interpret)
+                    bias=bias_p)
 
             return time_fn(bwd, qh, kh, vh, dout,
                            warmup=warmup, iters=iters)

@@ -252,7 +252,7 @@ def measure_schedule(csr, n_dense: int, sched: Schedule, *,
 
 
 def make_dist_runner(csr, n_dense: int, sched: Schedule, *, mesh,
-                     axis: str, interpret: bool = True):
+                     axis: str):
     """Jitted (fn, args) running ``spmm_shard_map`` under ``sched`` on a
     *real* mesh (the forced-host-device mesh in CI) — unlike the
     single-device analogues there is no cheaper stand-in that still
@@ -275,8 +275,7 @@ def make_dist_runner(csr, n_dense: int, sched: Schedule, *, mesh,
 
     def _run(r, c, v, b):
         return spmm_shard_map(r, c, v, b, n_rows=csr.shape[0], mesh=mesh,
-                              axis=axis, schedule=sched,
-                              interpret=interpret)
+                              axis=axis, schedule=sched)
 
     vals_feed, b_feed = _storage_feed(vals, _dense_b(csr, n_dense),
                                       sched.value_dtype)
@@ -286,10 +285,8 @@ def make_dist_runner(csr, n_dense: int, sched: Schedule, *, mesh,
 
 def measure_dist_schedule(csr, n_dense: int, sched: Schedule, *, mesh,
                           axis: str, warmup: int | None = None,
-                          iters: int | None = None,
-                          interpret: bool = True) -> float:
+                          iters: int | None = None) -> float:
     """Seconds/call of the distributed schedule point (local tiling +
     ``sched.collective`` wire mode) — ``tune_dist_spmm``'s objective."""
-    fn, args = make_dist_runner(csr, n_dense, sched, mesh=mesh, axis=axis,
-                                interpret=interpret)
+    fn, args = make_dist_runner(csr, n_dense, sched, mesh=mesh, axis=axis)
     return time_fn(fn, *args, warmup=warmup, iters=iters)

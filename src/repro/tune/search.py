@@ -319,7 +319,6 @@ def tune_dist_spmm(
     warmup: Optional[int] = None,
     iters: Optional[int] = None,
     backend: Optional[str] = None,
-    interpret: bool = True,
     value_dtypes: Optional[tuple] = None,
     error_budget: float = 0.05,
 ) -> TuneResult:
@@ -360,7 +359,7 @@ def tune_dist_spmm(
         def measure(s: Schedule) -> float:
             return measure_dist_schedule(csr, n_dense_cols, s, mesh=mesh,
                                          axis=axis, warmup=warmup,
-                                         iters=iters, interpret=interpret)
+                                         iters=iters)
 
     if value_dtypes is None:
         value_dtypes = DIST_VALUE_DTYPES

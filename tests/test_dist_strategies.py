@@ -63,11 +63,23 @@ from repro.tune import ScheduleCache, tune_dist_spmm
 cache = ScheduleCache(path=None)
 out = dist_spmm(csr, b, mesh=mesh, axis="shards", schedule="tune",
                 cache=cache)
-err = float(jnp.max(jnp.abs(out - want)))
-assert err < 1e-4, err
 res = tune_dist_spmm(csr, 20, mesh=mesh, axis="shards", cache=cache)
 assert res.from_cache and res.n_measurements == 0, res
 assert res.schedule.collective in ("row", "nnz_ar", "nnz_rs")
+# the joint search may store values narrow when that measures faster:
+# the oracle reads the same storage the tuned run read, so the check
+# stays f32-tight whichever dtype won
+from repro.tune.measure import _storage_feed
+vals_t, b_t = _storage_feed(coo.vals, b, res.schedule.value_dtype)
+want_t = ref.spmm_coo_ref(coo.rows, coo.cols, vals_t.astype(jnp.float32),
+                          b_t.astype(jnp.float32), 128)
+err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - want_t)))
+assert err < 1e-4, (res.schedule.value_dtype, err)
+# and against the f32 oracle within the tuner's parity budget (0.05),
+# with slack as the budget gate probed a different dense operand
+rel = float(jnp.linalg.norm(out.astype(jnp.float32) - want)
+            / jnp.linalg.norm(want))
+assert rel <= 0.10, (res.schedule.value_dtype, rel)
 print("tune OK", res.schedule.collective)
 """
 

@@ -3,14 +3,9 @@ main pytest process keeps its single-device view.  The forced-device
 environment (and the device-count assertion) lives in
 ``conftest.run_distributed`` — snippets here contain only the test.
 """
-import jax
 import pytest
 
 from conftest import run_distributed as _run
-
-if not hasattr(jax.sharding, "AxisType"):
-    pytest.skip("jax.sharding.AxisType unavailable in this jax version",
-                allow_module_level=True)
 
 
 DISTRIBUTED_SPMM = """

@@ -281,8 +281,6 @@ def test_fp8_degrades_to_bf16_with_warning(monkeypatch):
     assert _rel(out8, outbf) == 0.0
 
 
-@pytest.mark.skipif(not hasattr(jnp, "float8_e4m3fn"),
-                    reason="this jax has no fp8 type")
 def test_fp8_native_when_available(monkeypatch):
     monkeypatch.delenv("REPRO_DISABLE_FP8", raising=False)
     assert fp8_supported()

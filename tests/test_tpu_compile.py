@@ -1,0 +1,175 @@
+"""Compile the GCN path's Pallas kernels for a described TPU v5e at the
+widths of Planetoid PubMed — no chip needed.
+
+Mosaic refuses patterns the interpreter accepts (gathers from VMEM
+values, dynamic slices of values, 1-D blocks that disagree with XLA's
+HBM tiling, more VMEM than a kernel asked for), so these compiles guard
+the main path between chip runs.  The topology is described inside a
+fixture: only the worker that runs this file loads the TPU compiler.
+"""
+import functools
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.schedule import Epilogue, Schedule
+from repro.kernels import common
+from repro.kernels.ops import vmem_footprint_eb
+from repro.kernels.segment_reduce import segment_reduce
+from repro.kernels.spmm_eb import spmm_eb, vmem_need_eb
+from repro.kernels.spmm_rb import spmm_rb
+from repro.sparse.formats import round_up
+from repro.sparse.random import PUBMED, gcn_graph_csr
+
+HIDDEN = 16  # Kipf & Welling's hidden width: the first layer's dense operand
+NNZ_TILE = 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back without one: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def pubmed():
+    """(n_nodes, nnz, ELL width) of the generated PubMed-scale graph."""
+    g = gcn_graph_csr(PUBMED["n_nodes"], PUBMED["n_edges"], seed=0)
+    width = int(np.max(np.diff(np.asarray(g.indptr))))
+    return PUBMED["n_nodes"], int(g.nnz), width
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _eb_shapes(one_chip, n_nodes, nnz, width):
+    nnz_pad = round_up(nnz, NNZ_TILE)
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    return (s((nnz_pad,), jnp.int32), s((nnz_pad,), jnp.int32),
+            s((nnz_pad,), jnp.float32), s((n_nodes, width), jnp.float32))
+
+
+@pytest.mark.parametrize("strategy", ["segment", "parallel", "accumulate"])
+def test_spmm_eb_compiles(one_chip, pubmed, strategy):
+    n_nodes, nnz, _ = pubmed
+    fn = functools.partial(spmm_eb, n_rows=n_nodes, nnz_tile=NNZ_TILE,
+                           col_tile=HIDDEN, group_size=32, strategy=strategy,
+                           interpret=False)
+    _assert_kernel(_compile(fn, *_eb_shapes(one_chip, n_nodes, nnz, HIDDEN)))
+
+
+def test_spmm_eb_bias_relu_epilogue_compiles(one_chip, pubmed):
+    n_nodes, nnz, _ = pubmed
+    ep = Epilogue(activation="relu", bias=True)
+
+    def fn(rows, cols, vals, b, bias):
+        return spmm_eb(rows, cols, vals, b, n_rows=n_nodes,
+                       nnz_tile=NNZ_TILE, col_tile=HIDDEN, epilogue=ep,
+                       bias=bias, interpret=False)
+
+    bias = jax.ShapeDtypeStruct((1, HIDDEN), jnp.float32, sharding=one_chip)
+    _assert_kernel(_compile(
+        fn, *_eb_shapes(one_chip, n_nodes, nnz, HIDDEN), bias))
+
+
+def test_spmm_rb_compiles(one_chip, pubmed):
+    n_nodes, _, width = pubmed
+    r_pad = round_up(n_nodes, 8)
+    fn = functools.partial(spmm_rb, row_tile=8, col_tile=HIDDEN,
+                           interpret=False)
+    _assert_kernel(_compile(
+        fn,
+        jax.ShapeDtypeStruct((r_pad, width), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((r_pad, width), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n_nodes, HIDDEN), jnp.float32,
+                             sharding=one_chip)))
+
+
+def test_segment_reduce_compiles(one_chip, pubmed):
+    n_nodes, nnz, _ = pubmed
+    fn = functools.partial(segment_reduce, num_segments=n_nodes,
+                           tile=NNZ_TILE, group_size=32, interpret=False)
+    _assert_kernel(_compile(
+        fn,
+        jax.ShapeDtypeStruct((nnz,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((nnz, HIDDEN), jnp.float32, sharding=one_chip)))
+
+
+def _compiles_within(fn, shapes, monkeypatch, limit):
+    """Whether the launch compiles with its scoped VMEM limit set to
+    ``limit`` bytes (no headroom); a scoped-VMEM refusal is False."""
+    monkeypatch.setattr(common, "VMEM_HEADROOM", 0)
+    monkeypatch.setattr(common, "VMEM_CAPACITY", limit)
+    jax.clear_caches()  # the limit is baked into the traced launch
+    try:
+        _compile(fn, *shapes)
+    except Exception as e:
+        if "Scoped allocation" not in str(e):
+            raise
+        return False
+    return True
+
+
+@pytest.mark.parametrize("width,col_tile", [(256, 128), (128, 128), (16, 16)])
+def test_vmem_footprint_matches_compiler(one_chip, pubmed, monkeypatch,
+                                         width, col_tile):
+    """The eb VMEM count (padded tiles, pipeline buffers) is the
+    compiler's scoped allocation to within one (1, 128) row: the launch
+    compiles under a limit of exactly that many bytes and is refused
+    under 512 fewer.  Double-buffered blocks when B spans column tiles
+    (the tuner's ``vmem_footprint_eb``), single-buffered when one tile
+    covers it.  At 16 columns (the GCN's hidden width) XLA places B and
+    the output in VMEM itself (``S(1)``), so only the kernel's
+    partials scratch is scoped — see ``spmm_eb.vmem_need_eb``."""
+    n_nodes, nnz, _ = pubmed
+    fn = functools.partial(spmm_eb, n_rows=n_nodes, nnz_tile=NNZ_TILE,
+                           col_tile=col_tile, interpret=False)
+    shapes = _eb_shapes(one_chip, n_nodes, nnz, width)
+    compiled = _compile(fn, *shapes)
+    _assert_kernel(compiled)
+    xla_placed = "S(1)" in compiled.as_text()
+    assert xla_placed == (width == 16)
+    if xla_placed:
+        scoped = common.vmem_bytes((NNZ_TILE, 128), jnp.float32)
+    elif width == col_tile:
+        scoped = vmem_need_eb(n_nodes, n_nodes, nnz_tile=NNZ_TILE,
+                              col_tile=col_tile, n=width)
+    else:
+        scoped = vmem_footprint_eb(
+            n_nodes, n_nodes, Schedule(nnz_tile=NNZ_TILE, col_tile=col_tile))
+    assert _compiles_within(fn, shapes, monkeypatch, scoped)
+    assert not _compiles_within(fn, shapes, monkeypatch, scoped - 512)
+    jax.clear_caches()
