@@ -1,0 +1,1 @@
+"""Traffic mixes (``<name>.json``) and the one generator that reads them."""
