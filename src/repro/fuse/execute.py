@@ -32,6 +32,7 @@ ewise              ``bias`` / ``residual`` arrays for its epilogue flags
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .ir import FusePlan, Launch
@@ -133,15 +134,15 @@ def run_plan(plan: FusePlan, x, params):
     (``len(params) == len(plan.chain)``)."""
     assert len(params) == len(plan.chain), (len(params), len(plan.chain))
     cur = x
-    for launch in plan.launches:
-        cur = _run_launch(launch, cur, params)
+    for i, launch in enumerate(plan.launches):
+        # the launch's device work, its backward too, carries this name
+        with jax.named_scope(f"fuse.launch{i}.{launch.anchor.kind}"):
+            cur = _run_launch(launch, cur, params)
     return cur
 
 
 def _run_node_ref(node, cur, p, params):
     """One node of the unfused spec composition (pure jnp / ref paths)."""
-    import jax
-
     p = p or {}
     if node.kind == "spmm":
         from ..kernels import ops as kops

@@ -32,9 +32,9 @@ Strategy variants:
   'accumulate'  per-lane RMW (the atomicAdd baseline).
 
 Every Pallas launch of the package goes through :func:`pallas_call`, the
-one place that decides compiled (TPU) vs interpreted (elsewhere) and
-asks the compiler for the VMEM the kernel's blocks need
-(:func:`vmem_bytes`).
+one place that decides compiled (TPU) vs interpreted (elsewhere), asks
+the compiler for the VMEM the kernel's blocks need (:func:`vmem_bytes`)
+and names the launch after its kernel.
 
 ``apply_epilogue`` is the shared last-grid-step epilogue applier
 (``core.Epilogue``): bias / activation / residual / dtype cast fused
@@ -113,13 +113,16 @@ def block_buffers(n_blocks: int) -> int:
     return 1 if n_blocks == 1 else 2
 
 
-def pallas_call(kernel, *, vmem_need: int | None = None, interpret=None,
-                **kw):
+def pallas_call(kernel, *, name: str, vmem_need: int | None = None,
+                interpret=None, **kw):
     """``pl.pallas_call`` with the one compiled-vs-interpreted decision
     of the kernels package: ``interpret=None`` follows the backend
     (``launch.backend.pallas_interpret_default``: compiled on a TPU,
     interpreted elsewhere).  Asking for the interpreter on a TPU raises —
     a kernel that cannot lower fails there, it never falls back.
+
+    ``name`` is the kernel's stable name, the one its launches carry in
+    the compiled program and in a profiler trace; every launch has one.
 
     Compiled with ``vmem_need`` given (the padded, buffered bytes of the
     kernel's blocks and scratch — see :func:`vmem_bytes`), the kernel's
@@ -140,7 +143,7 @@ def pallas_call(kernel, *, vmem_need: int | None = None, interpret=None,
 
         kw["compiler_params"] = pltpu.CompilerParams(
             vmem_limit_bytes=min(vmem_need + VMEM_HEADROOM, VMEM_CAPACITY))
-    return pl.pallas_call(kernel, interpret=interpret, **kw)
+    return pl.pallas_call(kernel, name=name, interpret=interpret, **kw)
 
 
 def _rmw_row(out_ref, row, delta, combine):

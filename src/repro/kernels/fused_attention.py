@@ -275,6 +275,7 @@ def fused_sparse_attention(rows, cols, q, k, v, *, n_rows: int, nnz: int,
     ]
     out, m, l, _alpha, _p = pallas_call(
         kernel,
+        name="fused_attention_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -429,6 +430,7 @@ def fused_sparse_attention_bwd(rows, cols, q, k, v, dout, m, l, *,
     ]
     dq, dk, dv_, _delta, _w, _dw = pallas_call(
         kernel,
+        name="fused_attention_bwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[

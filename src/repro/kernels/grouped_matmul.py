@@ -126,6 +126,7 @@ def grouped_matmul(x, tile_experts, weights, *, bias=None,
     )
     return pallas_call(
         functools.partial(_gmm_kernel, epilogue, narrowed),
+        name="grouped_matmul",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_pad, f), out_dtype),
     )(*operands)

@@ -71,6 +71,7 @@ def sddmm(rows, cols, a, b, scale=None, *, nnz_tile: int = 256,
     ]
     return pallas_call(
         functools.partial(_sddmm_kernel, has_scale=has_scale),
+        name="sddmm",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((nnz_tile,), lambda i, u: (i,)),

@@ -87,6 +87,7 @@ def segment_reduce(seg_ids, data, *, num_segments: int, tile: int = 256,
             + sum(vmem_bytes((tile, c), jnp.float32) for _ in scratch))
     return pallas_call(
         kernel,
+        name="segment_reduce",
         grid=grid,
         in_specs=[
             # segment ids as (1, tile) lane rows in SMEM: the writeback

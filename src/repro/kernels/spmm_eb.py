@@ -222,6 +222,7 @@ def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
         quantized=quantized)
     return pallas_call(
         kernel,
+        name="spmm_eb",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((n_rows, col_tile), lambda j, i: (0, j)),

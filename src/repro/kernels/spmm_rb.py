@@ -177,6 +177,7 @@ def spmm_rb(ecols, evals, b, *, row_tile: int = 8, col_tile: int = 128,
                                narrowed=narrowed, quantized=quantized)
     return pallas_call(
         kernel,
+        name="spmm_rb",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((row_tile, col_tile), lambda i, j, u: (i, j)),

@@ -10,8 +10,7 @@ production mesh and record memory / cost / collective analysis.
         --shape train_4k [--multi-pod]
     PYTHONPATH=src python -m repro.launch.dryrun --all
 
-Results land in experiments/dryrun/<arch>__<shape>__<mesh>.json; the
-roofline table (EXPERIMENTS.md §Roofline) is generated from them.
+Results land in experiments/dryrun/<arch>__<shape>__<mesh>.json.
 """
 import argparse
 import functools
@@ -219,7 +218,7 @@ def _ladder(arch, shape_name, *, multi_pod, zero1, n_layers, family,
 def _extrapolated_costs(arch, shape_name, *, multi_pod, zero1, n_layers,
                         family, overrides=None, microbatches=8,
                         grad_compression=None):
-    """Two measurement ladders (DESIGN.md §9 / EXPERIMENTS.md §Roofline):
+    """Two measurement ladders (DESIGN.md §9):
 
     flops  — single-trip attention chunks (q/kv_chunk = big): identical
              math, every matmul visible to cost analysis. Exact.

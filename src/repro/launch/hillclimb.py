@@ -56,7 +56,6 @@ VARIANTS = {
 #                             with useful=0.07 (attention replication);
 #   deepseek prefill_32k    — most collective-bound (coll/mem = 2.8);
 #   deepseek decode_32k     — decode memory floor (cache double-buffer).
-# See EXPERIMENTS.md §Perf for the full hypothesis->measure log.
 DEFAULT_PLAN = [
     ("qwen3-moe-235b-a22b", "train_4k", ["sp"]),
     ("deepseek-coder-33b", "prefill_32k", ["sp", "kv2048"]),

@@ -154,7 +154,6 @@ def _spmm_csr_diff(a: CSR, b, sched: Schedule,
     ep = sched.epilogue
     coo = a.tocoo()  # cached on the CSR instance
     rows, cols = coo.rows, coo.cols
-    n_rows, n_cols = a.shape
 
     if sched.kernel == "eb":
         g0 = a.grouped(sched.nnz_tile, group_size=sched.group_size,
@@ -204,30 +203,50 @@ def _spmm_csr_diff(a: CSR, b, sched: Schedule,
 
     def _bwd(res, dout):
         vals, bb, bias_x, res_x = res
-        dout = dout.astype(jnp.float32)
-        dres = dout.astype(res_x.dtype) if ep.residual else None
-        if ep.activation is not None:
-            # recompute the pre-activation z through the oracle, then
-            # pull dout back through the activation
-            z = ref.spmm_coo_ref(rows, cols, vals, bb, n_rows)
-            if ep.bias:
-                z = z + jnp.reshape(bias_x, (1, -1)).astype(jnp.float32)
-            from ..core.schedule import ACTIVATIONS
-
-            _, act_vjp = jax.vjp(ACTIVATIONS[ep.activation], z)
-            dz, = act_vjp(dout)
-        else:
-            dz = dout
-        dbias = jnp.sum(dz, axis=0).astype(
-            bias_x.dtype) if ep.bias else None
-        # dA values: sampled dense-dense product at the sparsity pattern
-        dvals = ref.sddmm_ref(rows, cols, dz, bb).astype(vals.dtype)
-        # dB: transpose SpMM (cols become the segment ids)
-        db = ref.spmm_coo_ref(cols, rows, vals, dz, n_cols).astype(bb.dtype)
-        return dvals, db, dbias, dres
+        return _spmm_bwd(ep, rows, cols, a.shape, vals, bb, bias_x, res_x,
+                         dout, dvals=True)
 
     _fn.defvjp(_fwd, _bwd)
     return _fn(a.vals, b, bias, residual)
+
+
+def _spmm_bwd(ep: Epilogue, rows, cols, shape, vals, bb, bias_x, res_x,
+              dout, *, dvals: bool):
+    """The reference backward of ``y = act(A@B + bias) + residual`` over
+    A's COO pattern, under the scope ``spmm.bwd`` with each part in a
+    child scope of its own, so that a trace splits it.  Returns
+    ``(dvals, dB, dbias, dresidual)``; ``dvals`` is None unless asked
+    for."""
+    n_rows, n_cols = shape
+    with jax.named_scope("spmm.bwd"):
+        dout = dout.astype(jnp.float32)
+        dres = dout.astype(res_x.dtype) if ep.residual else None
+        dz = dout
+        if ep.activation is not None:
+            # recompute the pre-activation z through the oracle, then
+            # pull dout back through the activation
+            from ..core.schedule import ACTIVATIONS
+
+            with jax.named_scope("recompute"):
+                z = ref.spmm_coo_ref(rows, cols, vals, bb, n_rows)
+                if ep.bias:
+                    z = z + jnp.reshape(bias_x, (1, -1)).astype(jnp.float32)
+            with jax.named_scope("act"):
+                _, act_vjp = jax.vjp(ACTIVATIONS[ep.activation], z)
+                dz, = act_vjp(dout)
+        dbias = None
+        if ep.bias:
+            with jax.named_scope("dbias"):
+                dbias = jnp.sum(dz, axis=0).astype(bias_x.dtype)
+        dv = None
+        if dvals:
+            # dA values: sampled dense-dense product at the pattern
+            with jax.named_scope("sddmm"):
+                dv = ref.sddmm_ref(rows, cols, dz, bb).astype(vals.dtype)
+        # dB: transpose SpMM (cols become the segment ids)
+        with jax.named_scope("tspmm"):
+            db = ref.spmm_coo_ref(cols, rows, vals, dz, n_cols).astype(bb.dtype)
+        return dv, db, dbias, dres
 
 
 def _spmm_quant_diff(qa: QuantizedCSR, b, sched: Schedule,
@@ -238,7 +257,6 @@ def _spmm_quant_diff(qa: QuantizedCSR, b, sched: Schedule,
     in ``b``/``bias``/``residual`` — the codes are host-calibrated data
     (see :func:`spmm`)."""
     ep = sched.epilogue
-    n_rows, n_cols = qa.shape
     coo = qa.csr.tocoo()  # cached on the inner CSR
     rows, cols = coo.rows, coo.cols
     vals_f = qa.dequantize().vals  # f32 stream for the ref backward
@@ -255,23 +273,8 @@ def _spmm_quant_diff(qa: QuantizedCSR, b, sched: Schedule,
 
     def _bwd(res, dout):
         bb, bias_x, res_x = res
-        dout = dout.astype(jnp.float32)
-        dres = dout.astype(res_x.dtype) if ep.residual else None
-        if ep.activation is not None:
-            z = ref.spmm_coo_ref(rows, cols, vals_f, bb, n_rows)
-            if ep.bias:
-                z = z + jnp.reshape(bias_x, (1, -1)).astype(jnp.float32)
-            from ..core.schedule import ACTIVATIONS
-
-            _, act_vjp = jax.vjp(ACTIVATIONS[ep.activation], z)
-            dz, = act_vjp(dout)
-        else:
-            dz = dout
-        dbias = jnp.sum(dz, axis=0).astype(
-            bias_x.dtype) if ep.bias else None
-        db = ref.spmm_coo_ref(cols, rows, vals_f, dz,
-                              n_cols).astype(bb.dtype)
-        return db, dbias, dres
+        return _spmm_bwd(ep, rows, cols, qa.shape, vals_f, bb, bias_x,
+                         res_x, dout, dvals=False)[1:]
 
     _fn.defvjp(_fwd, _bwd)
     return _fn(b, bias, residual)

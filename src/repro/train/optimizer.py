@@ -42,6 +42,7 @@ class AdamW:
     def _lr(self, step):
         return self.lr(step) if callable(self.lr) else self.lr
 
+    @jax.named_scope("optimizer")
     def update(self, grads, state: AdamState, params):
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         if self.clip_norm is not None:

@@ -173,3 +173,40 @@ def test_vmem_footprint_matches_compiler(one_chip, pubmed, monkeypatch,
     assert _compiles_within(fn, shapes, monkeypatch, scoped)
     assert not _compiles_within(fn, shapes, monkeypatch, scoped - 512)
     jax.clear_caches()
+
+
+def test_gcn_step_launches_are_named(one_chip, monkeypatch):
+    """The training cell's GCN step (``bench/modes/train.py``) at a cut
+    size, compiled for the described chip: its two forward launches run
+    ``spmm_eb``, named so in the compiled program, and the scopes the
+    program names do not hide them from ``bench.trace.pallas_launches``."""
+    import json
+
+    from bench import trace
+    from bench.modes import train
+    from bench.run import ROOT
+    from bench.traffic import gcn as traffic
+    from repro.launch import backend
+
+    cfg = json.loads((ROOT / "bench" / "configs" / "gcn-pubmed.json").read_text())
+    cfg.update(n_nodes=300, n_edges=700, n_entries=1700, n_features=40)
+    graph = traffic.config_graph(cfg)
+    with jax.default_matmul_precision(cfg["matmul_precision"]):
+        program = train.build_program(cfg, graph)
+        inputs = traffic.make_inputs(cfg, graph, 7)
+        on_chip = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+        args = (inputs["params"], program["opt"].init(inputs["params"]),
+                *train.feed(inputs))
+        # the eager forward above ran interpreted; the step's kernels
+        # are traced again, for the chip
+        monkeypatch.setattr(backend, "pallas_interpret_default", lambda: False)
+        jax.clear_caches()
+        try:
+            hlo = _compile(program["step"], *on_chip(args)).as_text()
+        finally:
+            monkeypatch.undo()
+            jax.clear_caches()
+    launches = trace.pallas_launches(hlo)
+    assert [(lc["kernel"], lc["backward"]) for lc in launches] == [("spmm_eb", False)] * 2
+    assert all(lc["name"].startswith("spmm_eb") for lc in launches)
