@@ -42,6 +42,8 @@ onto the output block (DESIGN.md §8).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -226,6 +228,106 @@ def _pallas_segment(rows_ref, partial_ref, out_ref, group_size: int, *,
 
 _REF_REALIZATIONS = frozenset(
     (_pallas_accumulate, _pallas_parallel, _pallas_segment))
+
+#: output rows a window covers beyond its chunk's lanes: one sublane
+#: tile, so a window aligned down to it still spans the chunk
+WINDOW_SLACK = 8
+#: widest chunk of a tile one window product takes: at 128 lanes its
+#: 0/1 matrix and its result stay in vector registers (a 256-lane
+#: product spills them to VMEM)
+WINDOW_LANES = 128
+
+
+def segment_window(strategy, n_rows: int, nnz_tile: int):
+    """``(lanes, rows)`` of the MXU window products an nnz tile's
+    'segment' add-reduce runs as (:func:`window_reduce_scatter`): one a
+    chunk of ``lanes`` lanes, into ``rows`` output rows.  None where
+    every tile walks its runs: a strategy other than the built-in
+    'segment' realization, or an output block shorter than a window."""
+    lanes = WINDOW_LANES if nnz_tile % WINDOW_LANES == 0 else nnz_tile
+    rows = lanes + WINDOW_SLACK
+    seg = get_strategy(strategy).pallas_fn is _pallas_segment
+    return (lanes, rows) if seg and n_rows >= rows else None
+
+
+def window_start(lo, hi, n_rows: int, window: int, xp=jnp):
+    """``(start, fits)`` of the window of a chunk whose lanes' rows run
+    from ``lo`` (the lowest) to ``hi`` (the highest), in any lane order:
+    ``lo`` aligned down to a sublane tile, clamped so the ``window`` rows
+    stay inside the ``n_rows`` block; the chunk fits when ``hi`` lies
+    inside.  Scalars in the kernel; ``xp=numpy`` over every chunk at once
+    on the host, where the program counts the tiles that fit."""
+    start = xp.minimum(lo & -WINDOW_SLACK, n_rows - window)
+    return start, hi - start < window
+
+
+def window_reduce_scatter(rows_ref, lanes_ref, partial_ref, out_ref,
+                          group_size: int, strategy: str):
+    """The 'segment' add-reduce of a tile as MXU products: for each
+    chunk of the tile's lanes (``segment_window``), a (W, L) 0/1 matrix,
+    ``hit[w, t] = rows[t] == start + w``, times the chunk's partials
+    (L, C), added into output rows ``[start, start + W)``.  ``lanes_ref``
+    holds the tile's row ids as a (1, T) VMEM lane block, ``rows_ref``
+    the same in SMEM.  Each chunk's window comes from the lowest and
+    highest of its rows (:func:`window_start`), so the lanes may come in
+    any order.  The products run at float32 (``HIGHEST``) on exact 0/1
+    weights, so they sum what the run walk sums.  A tile with a chunk
+    whose rows span more than its window (many empty rows inside it)
+    takes the walk, ``group_reduce_scatter(..., strategy)``,
+    unchanged."""
+    T = partial_ref.shape[0]
+    n_rows = out_ref.shape[0]
+    lanes, w = segment_window(strategy, n_rows, T)
+
+    def window(lo):
+        ids = lanes_ref[:, lo:lo + lanes]
+        return window_start(jnp.min(ids), jnp.max(ids), n_rows, w)
+
+    wins = [window(lo) for lo in range(0, T, lanes)]
+    fits = functools.reduce(jnp.logical_and, [ok for _, ok in wins])
+
+    @pl.when(fits)
+    def _window():
+        # one chunk an iteration: unrolled, the chunks' products would
+        # be live at once and spill their registers to VMEM
+        def body(c, carry):
+            lo = pl.multiple_of(c * lanes, lanes)
+            start = wins[0][0]
+            for k, (other, _) in enumerate(wins[1:], 1):
+                start = jnp.where(c == k, other, start)
+            hit = (jax.lax.broadcasted_iota(jnp.int32, (w, lanes), 0)
+                   + start == lanes_ref[:, pl.ds(lo, lanes)])
+            delta = jnp.dot(hit.astype(jnp.float32),
+                            partial_ref[pl.ds(lo, lanes), :],
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+            _add_window(out_ref, start, w, delta)
+            return carry
+
+        jax.lax.fori_loop(0, T // lanes, body, 0)
+
+    @pl.when(jnp.logical_not(fits))
+    def _walk():
+        group_reduce_scatter(rows_ref, partial_ref, out_ref, group_size,
+                             strategy)
+
+
+def _add_window(out_ref, start, w: int, delta):
+    """out_ref[start:start + w] += delta: at a dynamic offset where
+    ``start`` is sublane-aligned, else at the static last ``w`` rows
+    (only a window clamped there starts unaligned)."""
+    def add(rows):
+        out_ref[rows, :] = (out_ref[rows, :] + delta).astype(out_ref.dtype)
+
+    aligned = (start & (WINDOW_SLACK - 1)) == 0
+
+    @pl.when(aligned)
+    def _inside():
+        add(pl.ds(pl.multiple_of(start, WINDOW_SLACK), w))
+
+    @pl.when(jnp.logical_not(aligned))
+    def _clamped():
+        add(pl.ds(out_ref.shape[0] - w, w))
 
 
 def spec_fallback_pallas(entry):

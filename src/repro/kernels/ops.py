@@ -20,7 +20,7 @@ from ..sparse.formats import (
     round_up,
 )
 from . import ref
-from .common import VMEM_CAPACITY, VMEM_HEADROOM
+from .common import VMEM_CAPACITY, VMEM_HEADROOM, segment_window, window_start
 from .grouped_matmul import grouped_matmul as _gmm_pallas
 from .sddmm import sddmm as _sddmm_kernel
 from .spmm_eb import spmm_eb as _spmm_eb
@@ -62,7 +62,8 @@ def vmem_footprint_eb(k, n_rows, sched: Schedule) -> int:
     vd = sched.value_dtype
     return vmem_need_eb(k, n_rows, nnz_tile=sched.nnz_tile,
                         col_tile=sched.col_tile, b_dtype=operand_dtype(vd),
-                        vals_dtype=storage_dtype(vd), epilogue=sched.epilogue)
+                        vals_dtype=storage_dtype(vd), epilogue=sched.epilogue,
+                        strategy=sched.strategy)
 
 
 def vmem_footprint_rb(k, width, sched: Schedule) -> int:
@@ -100,6 +101,23 @@ def _pad_epilogue_operands(ep, bias, residual, n_rows, n_pad):
         res_p = jnp.pad(residual, ((0, n_rows - residual.shape[0]),
                                    (0, n_pad - residual.shape[1])))
     return bias_p, res_p
+
+
+def eb_window_tiles(a: GroupedCOO, strategy) -> np.ndarray:
+    """(num_tiles,) bool: the nnz tiles of an eb launch over ``a``'s
+    (concrete) lanes whose 'segment' reduce runs as MXU window products,
+    by the predicate the kernel evaluates on each chunk of a tile
+    (``common.window_start`` of the chunk's lowest and highest rows); the
+    leading heavy tiles of a skew layout run 'parallel'."""
+    fits = np.zeros(a.num_tiles, bool)
+    win = segment_window(strategy, a.shape[0], a.nnz_tile)
+    if win is not None:
+        lanes, w = win
+        rows = np.asarray(a.rows).reshape(a.num_tiles, -1, lanes)
+        _, ok = window_start(rows.min(axis=-1), rows.max(axis=-1),
+                             a.shape[0], w, xp=np)
+        fits[a.heavy_tiles:] = ok[a.heavy_tiles:].all(axis=1)
+    return fits
 
 
 def _cast_stream(fmt, vals, dt):

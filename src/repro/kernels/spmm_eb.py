@@ -12,8 +12,14 @@ Per grid cell (one ``NNZ_TILE × COL_TILE`` block):
                                                extension: padded lanes
                                                gather row 0, val 0)
   2. scale by values        P = vals ⊙ B[cols]
-  3. segment-group reduce   per-run masked reduce + runtime writeback
-                            (see kernels/common.py)
+  3. segment-group reduce   'segment' on a tile whose rows fit
+                            (128 + 8)-row output windows, one a
+                            128-lane chunk: one MXU product a chunk of
+                            a 0/1 row-match matrix and the partials,
+                            added into its window;
+                            otherwise, and for every other strategy,
+                            the registry's per-run masked reduce +
+                            runtime writeback (see kernels/common.py)
   4. on the *last* nnz step of a column block: the fused epilogue
      (bias / activation / residual / dtype cast — DESIGN.md §8), so a
      GCN layer's ``act(A @ XW + b)`` is one kernel instead of three HBM
@@ -24,8 +30,9 @@ Per grid cell (one ``NNZ_TILE × COL_TILE`` block):
 VMEM working set (``vmem_need_eb``): the B block (K × COL_TILE) and the
 out block (n_rows × COL_TILE), both resident for a whole column block,
 both lane-padded to 128 and double-buffered unless one column tile
-covers N, plus the partials (NNZ_TILE × COL_TILE).  The launch asks the
-compiler for that plus headroom (``common.pallas_call``); operands
+covers N, plus the partials (NNZ_TILE × COL_TILE) and, where the window
+reduce engages, the row ids as a second, VMEM lane block.  The launch
+asks the compiler for that plus headroom (``common.pallas_call``); operands
 whose blocks exceed the chip's VMEM are refused, not windowed.
 """
 from __future__ import annotations
@@ -43,9 +50,11 @@ from .common import (
     block_buffers,
     group_reduce_scatter,
     pallas_call,
+    segment_window,
     split_epilogue_refs,
     upcast_f32,
     vmem_bytes,
+    window_reduce_scatter,
 )
 
 _NOOP = Epilogue()
@@ -53,7 +62,10 @@ _NOOP = Epilogue()
 
 def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
                     group_size: int, strategy: str, heavy_tiles: int,
-                    epilogue: Epilogue, narrowed: bool, quantized: bool):
+                    epilogue: Epilogue, narrowed: bool, quantized: bool,
+                    window: bool):
+    if window:
+        lanes_ref, *refs = refs
     if quantized:
         scales_ref, *refs = refs
     *refs, part_ref = refs
@@ -85,6 +97,15 @@ def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
     jax.lax.fori_loop(0, part_ref.shape[0], gather, 0)
     # scale by values: the (1, T) lane row turns into a (T, 1) column
     part_ref[...] = part_ref[...] * upcast_f32(vals_ref[...]).T
+
+    def reduce():
+        if window:
+            window_reduce_scatter(rows_ref, lanes_ref, part_ref, acc,
+                                  group_size, strategy)
+        else:
+            group_reduce_scatter(rows_ref, part_ref, acc, group_size,
+                                 strategy)
+
     if heavy_tiles > 0 and strategy != "parallel":
         # two-level skew layout (DESIGN.md §11): the leading heavy tiles
         # hold single-row groups, so they run the registry's 'parallel'
@@ -97,10 +118,9 @@ def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
 
         @pl.when(pl.program_id(1) >= heavy_tiles)
         def _tail():
-            group_reduce_scatter(rows_ref, part_ref, acc, group_size,
-                                 strategy)
+            reduce()
     else:
-        group_reduce_scatter(rows_ref, part_ref, acc, group_size, strategy)
+        reduce()
 
     if not epilogue.is_noop:
         @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
@@ -111,14 +131,17 @@ def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
 
 def vmem_need_eb(k: int, n_rows: int, *, nnz_tile: int, col_tile: int,
                  n: int | None = None, b_dtype=jnp.float32,
-                 vals_dtype=jnp.float32, epilogue: Epilogue = _NOOP) -> int:
+                 vals_dtype=jnp.float32, epilogue: Epilogue = _NOOP,
+                 strategy: str = "segment") -> int:
     """Padded, buffered VMEM bytes of one ``spmm_eb`` launch over an
     N-wide dense operand (``n=None``: more than one column tile): the
     whole-K B column block and the whole-n_rows output column block
     (single-buffered when one column tile covers N, double-buffered
     otherwise), the value lanes, the partials scratch, and the
-    epilogue's bias/residual blocks and f32 accumulator.  Row/column
-    indices and scales live in SMEM.
+    epilogue's bias/residual blocks and f32 accumulator, and the row ids'
+    VMEM lane block where the window reduce engages
+    (``common.segment_window``).  Row/column indices and scales live in
+    SMEM.
 
     This is the compiler's scoped allocation when XLA leaves the
     operands in HBM (``tests/test_tpu_compile.py`` checks it to the
@@ -135,6 +158,8 @@ def vmem_need_eb(k: int, n_rows: int, *, nnz_tile: int, col_tile: int,
             + vmem_bytes((n_rows, col_tile), out_dtype, cb)
             + vmem_bytes((1, nnz_tile), vals_dtype, 2)
             + vmem_bytes((nnz_tile, col_tile), jnp.float32))
+    if segment_window(strategy, n_rows, nnz_tile) is not None:
+        need += vmem_bytes((1, nnz_tile), jnp.int32, 2)
     if epilogue.bias:
         need += vmem_bytes((1, col_tile), jnp.float32, cb)
     if epilogue.residual:
@@ -172,6 +197,10 @@ def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
     scale vector stays resident in SMEM across nnz steps (constant index
     map) — the dequant adds no per-nnz HBM traffic.
 
+    Under 'segment', a tile whose rows fit its windows of output rows
+    reduces as MXU products (``common.window_reduce_scatter``), in any
+    lane order.
+
     ``interpret`` defaults to the backend's answer (``common.pallas_call``);
     a test compiles for a described TPU by passing ``False``.
     """
@@ -196,6 +225,12 @@ def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
         lane_spec(),
         pl.BlockSpec((k, col_tile), lambda j, i: (0, j)),
     ]
+    window = segment_window(strategy, n_rows, nnz_tile) is not None
+    if window:
+        # the row ids once more, as a VMEM lane block: the window's 0/1
+        # row-match matrix and its bounds are computed from them as vectors
+        operands.append(lanes(rows))
+        in_specs.append(lane_spec())
     quantized = scales is not None
     if quantized:
         assert scales.shape == (n_rows,), (scales.shape, n_rows)
@@ -219,7 +254,7 @@ def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
     kernel = functools.partial(
         _spmm_eb_kernel, group_size=group_size, strategy=strategy,
         heavy_tiles=heavy_tiles, epilogue=epilogue, narrowed=narrowed,
-        quantized=quantized)
+        quantized=quantized, window=window)
     return pallas_call(
         kernel,
         name="spmm_eb",
@@ -230,6 +265,7 @@ def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
         scratch_shapes=scratch,
         vmem_need=vmem_need_eb(k, n_rows, nnz_tile=nnz_tile,
                                col_tile=col_tile, n=n, b_dtype=b.dtype,
-                               vals_dtype=vals.dtype, epilogue=epilogue),
+                               vals_dtype=vals.dtype, epilogue=epilogue,
+                               strategy=strategy),
         interpret=interpret,
     )(*operands)

@@ -8,10 +8,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import GroupReduceStrategy, KernelSchedule, segment_group_reduce
+from repro.core import (
+    Epilogue,
+    GroupReduceStrategy,
+    KernelSchedule,
+    segment_group_reduce,
+)
 from repro.kernels import grouped_matmul, ref, sddmm, segment_reduce, spmm
 from repro.kernels.ops import expert_tile_map
-from repro.sparse import random_csr
+from repro.sparse import CSR, random_csr
 
 RTOL = 2e-5
 ATOL = 2e-5
@@ -37,6 +42,212 @@ def test_spmm_eb_schedule_sweep(density, skew, sched):
     b = jax.random.normal(jax.random.PRNGKey(0), (150, 37))
     got = np.asarray(spmm(csr, b, sched))
     np.testing.assert_allclose(got, _want_spmm(csr, b), rtol=RTOL, atol=ATOL)
+
+
+def _lengths_csr(lengths, n_cols=60, seed=0):
+    rng = np.random.default_rng(seed)
+    dense = np.zeros((len(lengths), n_cols), np.float32)
+    for r, ln in enumerate(lengths):
+        cols = rng.choice(n_cols, size=int(ln), replace=False)
+        dense[r, cols] = rng.standard_normal(int(ln))
+    return CSR.fromdense(jnp.asarray(dense))
+
+
+# 64-lane tiles: one window of 72 rows a tile
+_WIN = dict(kernel="eb", nnz_tile=64, col_tile=8, group_size=8)
+_SPARSE_TAIL = [2] * 100 + [1 if r % 12 == 0 else 0 for r in range(200)]
+#: case -> (row lengths, schedule): tiles that fit their windows; n_rows -
+#: window unaligned (the last tile's window clamped at n_rows); empty
+#: rows planted so tiles span more than a window (the walk); an output
+#: block shorter than a window (the walk, statically); a tile's span at
+#: the window's edge; a tile of two chunks; a skew layout with heavy
+#: tiles; the epilogue, narrowed output and int8 value paths
+WINDOW_CASES = {
+    "fit": ([3] * 296, KernelSchedule(**_WIN)),
+    "clamped": ([3] * 301, KernelSchedule(**_WIN)),
+    "empty_rows": (_SPARSE_TAIL, KernelSchedule(**_WIN)),
+    "short_block": ([3] * 50, KernelSchedule(**_WIN)),
+    # the first tile's last lane in row 71 (inside its window, rows
+    # 0-71) or row 72 (just outside)
+    "span_71": ([1] * 63 + [0] * 8 + [2] + [3] * 100, KernelSchedule(**_WIN)),
+    "span_72": ([1] * 63 + [0] * 9 + [2] + [3] * 100, KernelSchedule(**_WIN)),
+    # 256-lane tiles run two 128-lane chunks; the first tile's second
+    # chunk spans 384 rows, so that tile walks though its first fits
+    "two_chunks": ([1] * 128 + [1 if r % 3 == 0 else 0 for r in range(390)]
+                   + [3] * 200,
+                   KernelSchedule(kernel="eb", nnz_tile=256, col_tile=8,
+                                  group_size=8)),
+    "skew": ([40, 33] + [2] * 150 + _SPARSE_TAIL,
+             KernelSchedule(**_WIN, split_threshold=16, merge_threshold=2)),
+    "bias_relu": ([3] * 301, KernelSchedule(
+        **_WIN, epilogue=Epilogue(activation="relu", bias=True))),
+    "bf16_out": ([3] * 301, KernelSchedule(
+        **_WIN, epilogue=Epilogue(out_dtype="bfloat16"))),
+    "int8": (_SPARSE_TAIL, KernelSchedule(**_WIN, value_dtype="int8")),
+}
+
+
+def _window_case(case):
+    lengths, sched = WINDOW_CASES[case]
+    csr = _lengths_csr(lengths)
+    b = jax.random.normal(jax.random.PRNGKey(4), (60, 13))
+    bias = jax.random.normal(jax.random.PRNGKey(5), (13,))
+    return csr, b, (bias if sched.epilogue.bias else None), sched
+
+
+def _launch_lanes(csr, b, sched):
+    """The lanes an eb launch of ``sched`` runs over ``csr``: its
+    GroupedCOO, each lane's float32 value, and the dense operand."""
+    if sched.value_dtype == "int8":
+        q = csr.quantized()
+        g = q.csr.grouped(sched.nnz_tile)
+        vals = (np.asarray(g.vals, np.float32)
+                * np.asarray(q.scales)[np.asarray(g.rows)])
+        return g, vals, b.astype(jnp.bfloat16).astype(jnp.float32)
+    skew = dict(group_size=sched.group_size,
+                split_threshold=sched.split_threshold,
+                merge_threshold=sched.merge_threshold)
+    g = csr.grouped(sched.nnz_tile, **(skew if sched.is_skew else {}))
+    return g, np.asarray(g.vals, np.float32), b
+
+
+def _lanes_ref(g, vals, b, sched, bias, keep=None):
+    """Dense reference over the launch's lanes (those of the tiles in
+    ``keep`` alone, if given), with the schedule's epilogue."""
+    if keep is not None:
+        vals = vals * np.repeat(keep, g.nnz_tile)
+    out = ref.spmm_coo_ref(g.rows, g.cols, jnp.asarray(vals), b, g.shape[0])
+    if sched.epilogue.is_noop:
+        return out
+    return sched.epilogue.apply(out, bias=bias)
+
+
+@pytest.mark.parametrize("case", list(WINDOW_CASES))
+def test_spmm_eb_window_matches_reference(case):
+    csr, b, bias, sched = _window_case(case)
+    got = np.asarray(spmm(csr, b, sched, bias=bias), np.float32)
+    want = np.asarray(_lanes_ref(*_launch_lanes(csr, b, sched), sched, bias),
+                      np.float32)
+    tol = 1e-2 if sched.epilogue.out_dtype else RTOL
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+def _no_walk(monkeypatch):
+    """Take the run walk (and every registry realization) out of the eb
+    kernel: a tile that does not take the window then adds nothing."""
+    import importlib
+
+    from repro.kernels import common
+
+    # the package's ``spmm_eb`` is the kernel's function; this is its module
+    eb = importlib.import_module("repro.kernels.spmm_eb")
+
+    def skip(*args, **kwargs):
+        del args, kwargs
+
+    monkeypatch.setattr(common, "group_reduce_scatter", skip)
+    monkeypatch.setattr(eb, "group_reduce_scatter", skip)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("case", list(WINDOW_CASES))
+def test_spmm_eb_window_count_agrees_with_kernel(case, monkeypatch):
+    """The host's count of the tiles that take the window
+    (``ops.eb_window_tiles``) is the kernel's choice: without the walk,
+    the kernel's output is the reference over exactly those tiles'
+    lanes."""
+    from repro.kernels import ops
+
+    csr, b, bias, sched = _window_case(case)
+    g, vals, b_used = _launch_lanes(csr, b, sched)
+    keep = ops.eb_window_tiles(g, sched.strategy)
+    assert (g.heavy_tiles > 0) == (case == "skew")
+    if case.startswith("span_"):
+        assert np.asarray(g.rows)[63] == int(case[-2:])
+    # planted empty rows and heavy tiles leave some tiles to the walk
+    some_walk = case in ("empty_rows", "skew", "int8", "short_block",
+                         "span_72", "two_chunks")
+    assert keep.any() == (case != "short_block")
+    assert keep.all() == (not some_walk)
+    _no_walk(monkeypatch)
+    try:
+        got = np.asarray(spmm(csr, b, sched, bias=bias), np.float32)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    want = np.asarray(_lanes_ref(g, vals, b_used, sched, bias, keep),
+                      np.float32)
+    tol = 1e-2 if sched.epilogue.out_dtype else RTOL
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+def _unsorted_lanes(case):
+    """(rows, cols, vals) of 64-lane tiles over 200 rows, two lanes a row
+    for rows 0-191, in an order other than row-sorted: each tile's lanes
+    shuffled (every tile still spans 32 rows); one lane of the first
+    tile moved to row 150, between a first and a last lane that lie
+    inside the tile's window; or all lanes shuffled across tiles."""
+    rng = np.random.default_rng(7)
+    rows = np.repeat(np.arange(192, dtype=np.int32), 2)
+    if case == "shuffled_in_tile":
+        rows = rng.permuted(rows.reshape(-1, 64), axis=1).reshape(-1)
+    elif case == "middle_lane_outside":
+        rows[30] = 150
+    else:
+        rows = rng.permutation(rows)
+    cols = rng.integers(0, 60, rows.size).astype(np.int32)
+    vals = rng.standard_normal(rows.size).astype(np.float32)
+    return rows, cols, vals
+
+
+@pytest.mark.parametrize("walk", [True, False], ids=["walk", "no_walk"])
+@pytest.mark.parametrize("case", ["shuffled_in_tile", "middle_lane_outside",
+                                  "shuffled_everywhere"])
+def test_spmm_eb_window_unsorted_lanes(case, walk, monkeypatch):
+    """Lanes in any order: a tile takes the window only where every lane's
+    row lies inside it, and the kernel's sum is the reference's.  Without
+    the walk, the output is the reference over those tiles alone."""
+    from repro.kernels.common import segment_window, window_start
+    from repro.kernels.spmm_eb import spmm_eb as kernel
+
+    rows, cols, vals = _unsorted_lanes(case)
+    b = jax.random.normal(jax.random.PRNGKey(6), (60, 16))
+    lanes, w = segment_window("segment", 200, 64)
+    tiles = rows.reshape(-1, lanes)
+    _, keep = window_start(tiles.min(axis=1), tiles.max(axis=1), 200, w, xp=np)
+    assert keep.all() == (case == "shuffled_in_tile")
+    assert keep[0] == (case == "shuffled_in_tile")
+    want_vals = vals if walk else vals * np.repeat(keep, 64)
+    if not walk:
+        _no_walk(monkeypatch)
+    try:
+        got = kernel(jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals),
+                     b, n_rows=200, nnz_tile=64, col_tile=8, group_size=8)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    want = ref.spmm_coo_ref(jnp.asarray(rows), jnp.asarray(cols),
+                            jnp.asarray(want_vals), b, 200)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=RTOL, atol=ATOL)
+
+
+def test_make_spmm_pallas_unsorted_triplets():
+    """``make_spmm(impl='pallas')`` over caller triplets in no row order
+    (an A-transpose stream): the forward and both gradients match the
+    reference implementation."""
+    from repro.sparse.autodiff import make_spmm
+
+    rows, cols, vals = _unsorted_lanes("shuffled_everywhere")
+    rows, cols = jnp.asarray(rows), jnp.asarray(cols)
+    b = jax.random.normal(jax.random.PRNGKey(8), (60, 8))
+    fns = [make_spmm(rows, cols, 200, 60, impl=impl) for impl in ("pallas", "ref")]
+    outs = [jax.value_and_grad(lambda v, x, f=f: jnp.sum(f(v, x) ** 2),
+                               argnums=(0, 1))(jnp.asarray(vals), b)
+            for f in fns]
+    for got, want in zip(jax.tree.leaves(outs[0]), jax.tree.leaves(outs[1])):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=RTOL, atol=ATOL)
 
 
 @pytest.mark.parametrize("n_rows,n_cols,n_dense", [(100, 80, 20), (64, 64, 8), (33, 70, 130)])
