@@ -89,7 +89,7 @@ def fused_attention(quick=True):
     kernel-*shape* question), fusion is a question about kernel *passes*,
     so this times the actual Pallas programs, the same way
     ``tune_segment_reduce`` times its real kernel: fused = the single
-    ``kernels.fused_attention`` pass with online renormalization;
+    ``kernels.fused_attention`` launch (a row-max pass, then a weighted sum);
     unfused = SDDMM kernel → segment-max kernel → exp/normalize →
     segment-sum kernel → SpMM kernel over the same pattern, with the
     (nnz,)-sized score/weight intermediates materialized between passes.
@@ -155,10 +155,9 @@ def fused_attention_bwd(quick=True):
     """Fused one-launch attention *backward* vs the spec-recompute VJP
     composed of kernel passes (ISSUE 5).
 
-    The fused side is ``kernels.fused_attention_bwd``: one (H, 2,
-    nnz_tiles) launch recomputing probabilities from the forward's
-    (m, l) residuals, scattering δ and dV in phase 0 and dQ/dK in phase
-    1.  The unfused side realizes the PR-4 spec-recompute VJP as the
+    The fused side is ``kernels.fused_attention_bwd``: one launch
+    recomputing the probabilities from the forward's (m, l) residuals
+    and scattering dQ by row and dK/dV by column.  The unfused side realizes the PR-4 spec-recompute VJP as the
     kernel passes training actually paid: SDDMM (score recompute) →
     segment-max → segment-sum (weights) → SDDMM (dw) → segment-sum (δ)
     → three transpose/plain SpMM passes (dV, dQ, dK) — 8 kernel
@@ -197,15 +196,16 @@ def fused_attention_bwd(quick=True):
         rows_p = jnp.pad(rows, (0, pad))
         cols_p = jnp.pad(cols, (0, pad))
         # the (m, l) residuals the custom VJP carries across fwd -> bwd
-        _, mst, lst = fused_sparse_attention(
-            rows_p, cols_p, q[None], k[None], v[None], n_rows=m, nnz=nnz,
-            nnz_tile=256, dv_tile=dv, scale=scale,
+        ost, mst, lst = fused_sparse_attention(
+            rows_p, cols_p, q[:, None], k[:, None], v[:, None], n_rows=m,
+            nnz=nnz, nnz_tile=256, scale=scale,
             group_size=sched.group_size, strategy=sched.strategy)
 
         def fused(q, k, v, do):
             return fused_sparse_attention_bwd(
-                rows_p, cols_p, q[None], k[None], v[None], do[None],
-                mst, lst, n_rows=m, nnz=nnz, nnz_tile=256, scale=scale,
+                rows_p, cols_p, q[:, None], k[:, None], v[:, None], ost,
+                do[:, None], mst, lst, n_rows=m, nnz=nnz, nnz_tile=256,
+                scale=scale,
                 group_size=sched.group_size, strategy=sched.strategy)
 
         def unfused(q, k, v, do):

@@ -63,7 +63,7 @@ _ADD = MONOIDS["add"]
 #: value is deliberately representable in float32 but NOT in float16
 #: (fp16 max ~6.5e4): any kernel that compared or accumulated scores in
 #: a low-precision input dtype would overflow it to -inf and poison the
-#: online-softmax rescale (exp(-inf - -inf) = NaN).  Kernels must
+#: softmax shift (exp(-inf - -inf) = NaN).  Kernels must
 #: therefore run score arithmetic through :func:`upcast_f32` — the floor
 #: doubles as a tripwire for precision regressions.
 NEG_INF = -1e30
@@ -72,7 +72,7 @@ NEG_INF = -1e30
 def upcast_f32(*xs):
     """Force float32 compute for (possibly fp16/bf16) kernel operands.
 
-    Score accumulation, online-softmax statistics and the probability
+    Score accumulation, softmax statistics and the probability
     algebra must happen in f32 regardless of the storage dtype: besides
     the :data:`NEG_INF` floor overflowing fp16, bf16's 8-bit mantissa
     loses the `exp(s - m)` cancellation.  Returns one array for one
@@ -370,24 +370,6 @@ def group_reduce_scatter(rows_ref, partial_ref, out_ref, group_size: int,
     fn = entry.pallas_fn or spec_fallback_pallas(entry)
     call_pallas_fn(fn, rows_ref[0, :], partial_ref[...], out_ref,
                    group_size, entry.monoid)
-
-
-def group_reduce_scatter_values(rows, partial, out_ref, group_size: int,
-                                strategy: str = "segment", op=None):
-    """:func:`group_reduce_scatter` for kernels that hold ``rows`` (T,)
-    and ``partial`` (T, C) as values: stages them into scoped SMEM/VMEM
-    refs first.  Storing a vector into SMEM does not lower on a TPU, so
-    its callers (the fused attention kernels) run interpreted only."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    def body(rows_ref, partial_ref):
-        rows_ref[...] = rows[None, :]
-        partial_ref[...] = partial
-        group_reduce_scatter(rows_ref, partial_ref, out_ref, group_size,
-                             strategy, op=op)
-
-    pl.run_scoped(body, pltpu.SMEM((1,) + rows.shape, rows.dtype),
-                  pltpu.VMEM(partial.shape, partial.dtype))
 
 
 def split_epilogue_refs(refs, epilogue: Epilogue, narrowed: bool):

@@ -1,67 +1,51 @@
-"""Fused sparse attention: SDDMM → segment softmax → SpMM in ONE kernel,
-forward AND backward, batched over heads (DESIGN.md §8–§9).
+"""Fused sparse attention: SDDMM → segment softmax → SpMM, forward and
+backward, all heads of a launch at once (DESIGN.md §8–§9).
 
-The motivating chain (graph attention / sparse transformer): for a
-sparsity pattern (rows, cols) over queries Q (n_rows, d), keys
-K (n_cols, d) and values V (n_cols, dv),
+The chain (graph attention / sparse transformer): for a sparsity pattern
+(rows, cols) over queries Q (n_rows, H, d), keys K (n_cols, H, d) and
+values V (n_cols, H, dv), per head h and entry t,
 
-    s[t]   = <Q[rows[t]], K[cols[t]]> * scale (+ bias[t])   (SDDMM)
-    w[t]   = softmax over {t' : rows[t'] = rows[t]}         (segment softmax)
-    out[r] = Σ_{t: rows[t]=r} w[t] * V[cols[t]]             (SpMM)
+    pre[t] = <Q[r_t], K[c_t]> · scale (+ bias[t])     score 'dot'
+             Q[r_t] + K[c_t] (+ bias[t])             score 'additive'
+    e[t]   = pre[t]                                   'dot'
+             LeakyReLU_slope(pre[t])                  'additive' (GAT)
+    w[t]   = softmax of e over {t' : r_t' = r_t}
+    out[r] = Σ_{t: r_t = r} w[t] · keep[t] · V[c_t]
 
-Composed as separate ops this costs three HBM round trips and
-materializes two (nnz,)-sized intermediates.  The fused forward makes
-one pass over the nonzeros with FlashAttention-style *online
-renormalization* per output row: a running row max ``m`` and denominator
-``l`` carried through the race-free sequential nnz grid —
+``keep`` is an optional (nnz, H) mask on the normalised coefficients
+(dropout: 0 or 1/(1-p)); the additive form's Q and K are GAT's per-node,
+per-head terms ``a_l·Wh`` and ``a_r·Wh`` (d = 1).
 
-    per nnz tile i:   m_new = max(m, rowmax_i(s))          (max monoid
-                      α     = exp(m - m_new)                through the
-                      l     = l·α + rowsum_i(exp(s-m_new))  strategy
-                      acc   = acc·α + Σ exp(s-m_new)·V      registry)
-    last tile:        out   = acc / l
+**Layout.**  Heads lie in lanes: every per-node operand is a 2-D
+node-major table, (rows, H·width), so one dynamic row window of a table
+brings all heads of a node at once and one lane of the launch serves
+every head.  The tables stay resident in VMEM for the whole launch,
+their heights padded to a sublane tile.  The pattern's row and column
+ids travel as (1, T) lane blocks in SMEM, read one scalar a lane; the
+row ids once more as a VMEM lane block for the window products.
 
-**Head batching.**  H heads run in ONE kernel launch: the grid is
-(H, nnz_tiles, dv_tiles) and every per-head operand is flattened to a
-2-D head-major buffer ((H·n_rows, d) queries, (H·n_rows, 1) row stats,
-…) whose BlockSpec selects head h's slab — so the in-kernel blocks stay
-2-D and the registry's scatter is reused unchanged.  The pattern
-(rows/cols/bias) is shared across heads.
+**Forward** (grid (2, nnz_tiles)): phase 0 gathers Q[r] and K[c] a lane
+at a time into (T, ·) scratch rows, computes the scores and scatters
+their row maximum (the max monoid, the registry's 'accumulate'
+realization: one read-modify-write a lane); phase 1 gathers again with
+V[c] and the finished max m[r], forms p = exp(e - m) and scatter-adds
+``[p·keep ⊗ V | p]`` by row into one accumulator: the weighted values
+and the denominator l in one reduction ('segment': MXU window products,
+``common.window_reduce_scatter``).  The caller divides by l.
 
-**Probability carry.**  The per-tile probabilities are computed once per
-nnz tile (at dv step 0, together with the row statistics) and stashed in
-an (nnz_tile, 1) carry block revisited by every grid step; later dv
-steps of the same nnz tile read the carry instead of redoing the
-d-length SDDMM dots (the PR-4 kernel recomputed scores per dv step).
+**Backward** (grid (nnz_tiles,)): one pass.  The softmax backward's row
+dot is δ[r] = Σ_t w·dw = <dout[r], out[r]> (the forward's output), which
+the caller computes, so each lane recomputes w from the forward's (m, l)
+and has everything it needs: dV[c] += w·keep·dout[r],
+de = w·(keep·<dout[r], V[c]> − δ[r]), then dQ by row (window products)
+and dK by column (with dV, one 'accumulate' read-modify-write a lane:
+columns are in no order a window could use).
 
-**Backward.**  ``_fused_attn_bwd_kernel`` is one launch over the grid
-(H, 2, nnz_tiles): the softmax backward needs the completed row dot
-``δ[r] = Σ_t w_t · <dout[r], V[c_t]>`` before any dQ/dK lane can be
-scattered, so the nnz grid is walked twice inside the same kernel —
-
-    phase 0 (per tile): recompute w from the carried forward stats
-                        (m, l — O(n_rows) residuals, FlashAttention
-                        style), stash (w, dw) in (nnz_pad, 1) carries,
-                        scatter δ (add monoid through the registry) and
-                        the transpose writes dV[c] += w·dout[r];
-    phase 1 (per tile): ds = w·(dw − δ[r])·scale from the carries (no
-                        score recompute), scatter dQ[r] += ds·K[c] and
-                        the transpose dK[c] += ds·Q[r].
-
-All scatters run through ``group_reduce_scatter_values`` (the value-form
-front of ``group_reduce_scatter``); the dK/dV transpose
-scatters hand it the *cols* as segment ids — unsorted ids are correct by
-the strategy contract (each transition opens a new run), just more
-writebacks.
-
-Scores, statistics and probabilities are **forced to float32** via
-``common.upcast_f32`` whatever the q/k/v/dout storage dtype: the
-``NEG_INF = -1e30`` masked-lane floor overflows fp16 to -inf (NaN after
-the online rescale), and bf16 loses the exp cancellation.  Padded lanes
-(trailing, from the nnz tile round-up) are masked by the static true
-``nnz``: scores floored to NEG_INF, probabilities zeroed, so they
-contribute nothing to any row or column.  Empty rows come out as exact
-zeros (matching the spec oracle).
+Scores, statistics and probabilities are float32 whatever the storage
+dtype (``common.upcast_f32``).  Padded lanes (the tile round-up) are
+masked by the static true ``nnz``; empty rows come out as exact zeros.
+A head-sum or head-broadcast of per-lane values is an MXU product with a
+0/1 matrix at ``HIGHEST``, exact for a broadcast.
 """
 from __future__ import annotations
 
@@ -70,16 +54,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .common import (
     NEG_INF,
-    group_reduce_scatter_values,
+    block_buffers,
+    group_reduce_scatter,
     pallas_call,
+    segment_window,
     upcast_f32,
+    vmem_bytes,
+    window_reduce_scatter,
 )
 
 __all__ = [
     "NEG_INF",
+    "SCORES",
     "fused_sparse_attention",
     "fused_sparse_attention_bwd",
     "sparse_attention_bwd_ref",
@@ -87,25 +77,49 @@ __all__ = [
     "sparse_softmax_weights",
 ]
 
+#: the score forms: a scaled dot product, or GAT's LeakyReLU of a sum
+SCORES = ("dot", "additive")
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _sublanes(n: int) -> int:
+    """``n`` rounded up to a sublane tile (8 rows)."""
+    return -(-n // 8) * 8
+
 
 # ---------------------------------------------------------------------------
-# Pure-JAX spec oracles
+# Pure-JAX spec oracles (one head: q (n, d), k/v (n_cols, ·), keep (nnz,))
 # ---------------------------------------------------------------------------
 
 
-def sparse_softmax_weights(rows, cols, q, k, *, n_rows: int,
-                           scale: float, bias=None):
-    """Spec of the SDDMM→segment-softmax front half: the normalized
-    per-nnz attention weights ``w``.  Shared by the forward oracle and
-    the spec VJP, so the numerically load-bearing details (the empty-row
-    isfinite guard, the 1e-30 denominator floor) cannot desynchronize
-    between forward and backward.  ``bias`` is an optional (nnz,)
-    additive score term (a CSR adjacency's stored values)."""
+def _pre_scores(rows, cols, q, k, *, scale, bias, score):
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
-    s = jnp.sum(qf[rows] * kf[cols], axis=-1) * scale  # (nnz,)
+    if score == "dot":
+        pre = jnp.sum(qf[rows] * kf[cols], axis=-1) * scale
+    elif score == "additive":
+        pre = qf[rows, 0] + kf[cols, 0]
+    else:
+        raise ValueError(f"score must be one of {SCORES}, not {score!r}")
     if bias is not None:
-        s = s + bias.astype(jnp.float32)
+        pre = pre + bias.astype(jnp.float32)
+    return pre
+
+
+def _activate(pre, score, slope):
+    return pre if score == "dot" else jnp.where(pre > 0, pre, slope * pre)
+
+
+def sparse_softmax_weights(rows, cols, q, k, *, n_rows: int, scale: float,
+                           bias=None, score: str = "dot", slope: float = 0.2):
+    """Spec of the score → segment-softmax front half: the normalized
+    per-nnz attention weights ``w`` (before any keep mask).  Shared by
+    the forward oracle and the spec VJP, so the empty-row isfinite guard
+    and the 1e-30 denominator floor cannot desynchronize.  ``bias`` is an
+    optional (nnz,) additive score term (a CSR adjacency's stored
+    values)."""
+    s = _activate(_pre_scores(rows, cols, q, k, scale=scale, bias=bias,
+                              score=score), score, slope)
     m = jax.ops.segment_max(s, rows, num_segments=n_rows)
     m = jnp.where(jnp.isfinite(m), m, 0.0)  # empty rows: any finite value
     p = jnp.exp(s - m[rows])
@@ -114,341 +128,404 @@ def sparse_softmax_weights(rows, cols, q, k, *, n_rows: int,
 
 
 def sparse_attention_ref(rows, cols, q, k, v, *, n_rows: int,
-                         scale: float | None = None, bias=None):
-    """Executable specification of the fused kernel (the oracle the
-    kernel and its VJP are tested against).  Empty rows -> zero rows."""
+                         scale: float | None = None, bias=None,
+                         score: str = "dot", slope: float = 0.2, keep=None):
+    """Executable specification of the fused kernel for one head (the
+    oracle the kernel and its VJP are tested against).  Empty rows ->
+    zero rows."""
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
-    w = sparse_softmax_weights(rows, cols, q, k, n_rows=n_rows,
-                               scale=scale, bias=bias)
+    w = sparse_softmax_weights(rows, cols, q, k, n_rows=n_rows, scale=scale,
+                               bias=bias, score=score, slope=slope)
+    if keep is not None:
+        w = w * keep.astype(jnp.float32)
     return jax.ops.segment_sum(w[:, None] * v.astype(jnp.float32)[cols],
                                rows, num_segments=n_rows)
 
 
 def sparse_attention_bwd_ref(rows, cols, q, k, v, dout, *, n_rows: int,
-                             scale: float, bias=None):
-    """Spec-recompute VJP (the PR-4 backward): pure-JAX softmax backward
-    + SDDMM / transpose-SpMM through segment ops, recomputing the
-    weights from scratch.  Returns ``(dq, dk, dv)``.  Kept as the oracle
-    the fused backward kernel is tested against and as the unfused
-    baseline ``beyond/fused_attention_bwd`` times."""
+                             scale: float, bias=None, score: str = "dot",
+                             slope: float = 0.2, keep=None):
+    """Spec-recompute VJP of one head: the softmax backward and the
+    sampled / transpose products through segment ops, recomputing the
+    weights from scratch.  Returns ``(dq, dk, dv)``."""
     qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
     do = dout.astype(jnp.float32)
-    w = sparse_softmax_weights(rows, cols, q, k, n_rows=n_rows,
-                               scale=scale, bias=bias)  # (nnz,)
-    # value gradient: transpose-SpMM of the weighted cotangent
-    dv_ = jax.ops.segment_sum(w[:, None] * do[rows], cols,
+    kp = 1.0 if keep is None else keep.astype(jnp.float32)
+    pre = _pre_scores(rows, cols, q, k, scale=scale, bias=bias, score=score)
+    w = sparse_softmax_weights(rows, cols, q, k, n_rows=n_rows, scale=scale,
+                               bias=bias, score=score, slope=slope)
+    dv_ = jax.ops.segment_sum((w * kp)[:, None] * do[rows], cols,
                               num_segments=v.shape[0])
-    # softmax backward per row: ds = w (dw - δ),  δ[r] = Σ_row w dw
-    dw = jnp.sum(do[rows] * vf[cols], axis=-1)  # SDDMM(dout, V)
+    dw = jnp.sum(do[rows] * vf[cols], axis=-1) * kp
     delta = jax.ops.segment_sum(w * dw, rows, num_segments=n_rows)
-    ds = w * (dw - delta[rows]) * scale
-    dq = jax.ops.segment_sum(ds[:, None] * kf[cols], rows,
-                             num_segments=n_rows)
-    dk = jax.ops.segment_sum(ds[:, None] * qf[rows], cols,
-                             num_segments=k.shape[0])
+    de = w * (dw - delta[rows])
+    if score == "dot":
+        dpre = de * scale
+        dq = jax.ops.segment_sum(dpre[:, None] * kf[cols], rows,
+                                 num_segments=n_rows)
+        dk = jax.ops.segment_sum(dpre[:, None] * qf[rows], cols,
+                                 num_segments=k.shape[0])
+    else:
+        dpre = de * jnp.where(pre > 0, 1.0, slope)
+        dq = jax.ops.segment_sum(dpre, rows, num_segments=n_rows)[:, None]
+        dk = jax.ops.segment_sum(dpre, cols, num_segments=k.shape[0])[:, None]
     return dq, dk, dv_
 
 
 # ---------------------------------------------------------------------------
-# The fused forward kernel
+# In-kernel pieces
 # ---------------------------------------------------------------------------
 
 
-def _fused_attn_fwd_kernel(*refs, nnz: int, nnz_tile: int, scale: float,
-                           group_size: int, strategy: str, has_bias: bool):
-    if has_bias:
-        (rows_ref, cols_ref, bias_ref, q_ref, k_ref, v_ref,
-         out_ref, m_ref, l_ref, a_ref, p_ref) = refs
+def _heads_matrix(n_heads: int, width: int):
+    """(H, H·width) 0/1: head h's lanes of a head-major row."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n_heads, n_heads * width), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (n_heads, n_heads * width), 0)
+    return ((lane >= head * width) & (lane < head * width + width)).astype(
+        jnp.float32)
+
+
+def _spread(x, width: int):
+    """(T, H) per-head values -> (T, H·width), each repeated over its
+    head's lanes."""
+    if width == 1:
+        return x
+    return jnp.dot(x, _heads_matrix(x.shape[1], width), precision=HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _head_sum(x, width: int, n_heads: int):
+    """(T, H·width) -> (T, H): the sum of each head's lanes."""
+    if width == 1:
+        return x
+    return jax.lax.dot_general(
+        x, _heads_matrix(n_heads, width), (((1,), (1,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32)
+
+
+def _scores(qg, kg, bias, *, score, d, n_heads, scale, slope):
+    """``(pre, e)`` of a tile's lanes, each (T, H)."""
+    if score == "dot":
+        pre = _head_sum(qg * kg, d, n_heads) * scale
     else:
-        (rows_ref, cols_ref, q_ref, k_ref, v_ref,
-         out_ref, m_ref, l_ref, a_ref, p_ref) = refs
-        bias_ref = None
-    i = pl.program_id(1)  # nnz tile (sequential carry within each head)
-    j = pl.program_id(2)  # dv tile (innermost)
-
-    @pl.when((i == 0) & (j == 0))
-    def _init_stats():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(i == 0)
-    def _init_out():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    rows = rows_ref[...]
-    cols = cols_ref[...]
-    lane = i * nnz_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (nnz_tile,), 0)
-    valid = lane < nnz
-
-    @pl.when(j == 0)
-    def _scores_and_stats():
-        # SDDMM front-end, once per nnz tile: f32-forced scores, padded
-        # lanes floored to NEG_INF
-        q, k = upcast_f32(q_ref[...], k_ref[...])
-        s = jnp.sum(jnp.take(q, rows, axis=0) * jnp.take(k, cols, axis=0),
-                    axis=-1) * scale
-        if bias_ref is not None:
-            s = s + upcast_f32(bias_ref[...])
-        s = jnp.where(valid, s, NEG_INF)
-        m_old = m_ref[...]  # (R, 1)
-        # running row max: the max-monoid scatter through the registry
-        group_reduce_scatter_values(rows, s[:, None], m_ref, group_size,
-                                    strategy, op="max")
-        m_new = m_ref[...]
-        alpha = jnp.where(m_old <= NEG_INF / 2, 0.0,
-                          jnp.exp(m_old - m_new))  # (R, 1)
-        a_ref[...] = alpha
-        p = jnp.where(valid,
-                      jnp.exp(jnp.where(valid, s, 0.0)
-                              - jnp.take(m_new[:, 0], rows)), 0.0)
-        # the probability carry: later dv steps of this nnz tile replay
-        # p instead of redoing the d-length dots above
-        p_ref[...] = p[:, None]
-        l_ref[...] = l_ref[...] * alpha
-        group_reduce_scatter_values(rows, p[:, None], l_ref, group_size,
-                                    strategy)
-
-    # SpMM back-end (every dv step): rescale the accumulator by this nnz
-    # tile's α, then scatter-add the carried-probability-weighted values
-    p = p_ref[...][:, 0]
-    vj = upcast_f32(v_ref[...])  # (n_cols, dv_tile)
-    out_ref[...] = out_ref[...] * a_ref[...]
-    group_reduce_scatter_values(
-        rows, p[:, None] * jnp.take(vj, cols, axis=0), out_ref, group_size,
-        strategy)
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _normalize():
-        out_ref[...] = out_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_rows", "nnz", "nnz_tile", "dv_tile", "scale",
-                     "group_size", "strategy"),
-)
-def fused_sparse_attention(rows, cols, q, k, v, *, n_rows: int, nnz: int,
-                           nnz_tile: int = 256, dv_tile: int = 128,
-                           scale: float, group_size: int = 32,
-                           strategy: str = "segment", bias=None):
-    """One-launch SDDMM→softmax→SpMM over all heads.
-
-    Inputs pre-padded by the wrapper: rows/cols (and bias) (nnz_pad,)
-    with nnz_pad % nnz_tile == 0 (``nnz`` is the true count — trailing
-    pad lanes are masked in-kernel); q/k/v carry an explicit head axis —
-    q (H, n_rows, d), k (H, n_kv, d), v (H, n_kv, dv_pad) with
-    dv_pad % dv_tile == 0.  ``bias`` is an optional (nnz_pad,) additive
-    score term shared across heads.  Returns ``(out, m, l)`` with out
-    (H, n_rows, dv_pad) final and m/l (H, n_rows) the per-row softmax
-    statistics — the O(H·n_rows) residuals the fused backward recomputes
-    probabilities from.
-    """
-    nnz_pad = rows.shape[0]
-    n_heads, n_q, d = q.shape
-    _, n_kv, dv = v.shape
-    assert nnz_pad % nnz_tile == 0 and dv % dv_tile == 0, (nnz_pad, dv)
-    assert n_q == n_rows and k.shape == (n_heads, n_kv, d)
-    grid = (n_heads, nnz_pad // nnz_tile, dv // dv_tile)
-
-    # head-major flat buffers: blocks stay 2-D, head h = block-row h
-    qf = q.reshape(n_heads * n_rows, d)
-    kf = k.reshape(n_heads * n_kv, d)
-    vf = v.reshape(n_heads * n_kv, dv)
-
-    kernel = functools.partial(
-        _fused_attn_fwd_kernel, nnz=nnz, nnz_tile=nnz_tile, scale=scale,
-        group_size=group_size, strategy=strategy,
-        has_bias=bias is not None)
-    lane_spec = pl.BlockSpec((nnz_tile,), lambda h, i, j: (i,))
-    stat_spec = pl.BlockSpec((n_rows, 1), lambda h, i, j: (h, 0))
-    in_specs = [lane_spec, lane_spec]
-    operands = [rows, cols]
+        pre = qg + kg
     if bias is not None:
-        in_specs.append(lane_spec)
-        operands.append(bias)
-    in_specs += [
-        pl.BlockSpec((n_rows, d), lambda h, i, j: (h, 0)),
-        pl.BlockSpec((n_kv, d), lambda h, i, j: (h, 0)),
-        pl.BlockSpec((n_kv, dv_tile), lambda h, i, j: (h, j)),
-    ]
-    out, m, l, _alpha, _p = pallas_call(
-        kernel,
-        name="fused_attention_fwd",
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((n_rows, dv_tile), lambda h, i, j: (h, j)),
-            stat_spec, stat_spec, stat_spec,
-            # the (nnz_tile, 1) probability carry: one resident block
-            # revisited by every grid step
-            pl.BlockSpec((nnz_tile, 1), lambda h, i, j: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_heads * n_rows, dv), jnp.float32),
-            jax.ShapeDtypeStruct((n_heads * n_rows, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n_heads * n_rows, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n_heads * n_rows, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nnz_tile, 1), jnp.float32),
-        ],
-    )(*operands, qf, kf, vf)
-    return (out.reshape(n_heads, n_rows, dv),
-            m.reshape(n_heads, n_rows), l.reshape(n_heads, n_rows))
+        pre = pre + bias
+    return pre, _activate(pre, score, slope)
 
 
-# ---------------------------------------------------------------------------
-# The fused backward kernel
-# ---------------------------------------------------------------------------
+def _lane_mask(nnz: int, tile: int, i):
+    """(T, 1): which lanes of nnz tile ``i`` are entries, not padding."""
+    lane = i * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+    return lane < nnz
 
 
-def _fused_attn_bwd_kernel(*refs, nnz: int, nnz_tile: int, scale: float,
-                           group_size: int, strategy: str, has_bias: bool):
-    if has_bias:
-        (rows_ref, cols_ref, bias_ref, q_ref, k_ref, v_ref, do_ref,
-         m_ref, l_ref,
-         dq_ref, dk_ref, dv_ref, delta_ref, w_ref, dw_ref) = refs
+def _gather(pairs, t):
+    """Lane ``t``: copy the row ``idx`` of each ``(table, idx, dst)`` into
+    row ``t`` of ``dst`` (a dynamic one-row window a table)."""
+    for table, idx, dst in pairs:
+        dst[pl.ds(t, 1), :] = table[pl.ds(idx, 1), :]
+
+
+def _row_add(rows_ref, lanes_ref, part_ref, out_ref, group_size, strategy):
+    """Add a tile's partials into ``out_ref`` by row: MXU window products
+    where the 'segment' window engages, else the strategy's walk."""
+    T = part_ref.shape[0]
+    if segment_window(strategy, out_ref.shape[0], T) is not None:
+        window_reduce_scatter(rows_ref, lanes_ref, part_ref, out_ref,
+                              group_size, strategy)
     else:
-        (rows_ref, cols_ref, q_ref, k_ref, v_ref, do_ref,
-         m_ref, l_ref,
-         dq_ref, dk_ref, dv_ref, delta_ref, w_ref, dw_ref) = refs
-        bias_ref = None
-    ph = pl.program_id(1)  # phase: 0 = δ + dV, 1 = dQ + dK
-    i = pl.program_id(2)   # nnz tile
+        group_reduce_scatter(rows_ref, part_ref, out_ref, group_size, strategy)
+
+
+def _lane_specs(nnz_tile: int, n_heads: int, has_bias: bool, has_keep: bool,
+                index):
+    """Specs of the lane operands: row and column ids in SMEM, the row ids
+    again in VMEM, the bias lanes and the (H, T) keep block."""
+    def spec(rows, space=None):
+        return pl.BlockSpec((None, rows, nnz_tile), index, memory_space=space)
+
+    specs = [spec(1, pltpu.SMEM), spec(1, pltpu.SMEM), spec(1)]
+    if has_bias:
+        specs.append(spec(1))
+    if has_keep:
+        specs.append(spec(n_heads))
+    return specs
+
+
+def _lane_operands(rows, cols, bias, keep, nnz_tile: int):
+    tiles = rows.shape[0] // nnz_tile
+    lanes = lambda x: x.reshape(tiles, 1, nnz_tile)  # noqa: E731
+    ops = [lanes(rows), lanes(cols), lanes(rows)]
+    if bias is not None:
+        ops.append(lanes(bias.astype(jnp.float32)))
+    if keep is not None:
+        # (nnz_pad, H) -> (tiles, H, T): a lane-dense block a tile
+        ops.append(keep.astype(jnp.float32).reshape(tiles, nnz_tile, -1)
+                   .transpose(0, 2, 1))
+    return ops
+
+
+def _unpack_lanes(refs, has_bias: bool, has_keep: bool):
+    rows_ref, cols_ref, lanes_ref, *refs = refs
+    bias_ref = refs.pop(0) if has_bias else None
+    keep_ref = refs.pop(0) if has_keep else None
+    return rows_ref, cols_ref, lanes_ref, bias_ref, keep_ref, refs
+
+
+def _lane_values(bias_ref, keep_ref):
+    """The tile's bias as a (T, 1) column and keep mask as (T, H)."""
+    bias = None if bias_ref is None else bias_ref[...].T
+    keep = None if keep_ref is None else keep_ref[...].T
+    return bias, keep
+
+
+def _table(x, n_pad: int):
+    """(n, H, w) or (n, H·w) -> the (n_pad, H·w) float32 node-major
+    table."""
+    n = x.shape[0]
+    x = x.reshape(n, -1).astype(jnp.float32)
+    return jnp.pad(x, ((0, n_pad - n), (0, 0))) if n_pad != n else x
+
+
+def _check(rows, q, k, v, nnz_tile, score, strategy):
+    assert rows.shape[0] % nnz_tile == 0, (rows.shape, nnz_tile)
+    assert q.ndim == k.ndim == v.ndim == 3, (q.shape, k.shape, v.shape)
+    assert q.shape[1] == k.shape[1] == v.shape[1], (q.shape, k.shape, v.shape)
+    assert k.shape[0] == v.shape[0] and q.shape[2] == k.shape[2]
+    if score not in SCORES:
+        raise ValueError(f"score must be one of {SCORES}, not {score!r}")
+    if score == "additive" and q.shape[2] != 1:
+        raise ValueError("the additive score takes per-node terms of width 1")
+    if strategy == "parallel":
+        raise ValueError("the attention kernels cannot run 'parallel': "
+                         "its one-writeback contract does not hold for rows")
+
+
+# ---------------------------------------------------------------------------
+# The forward kernel
+# ---------------------------------------------------------------------------
+
+
+def _fwd_kernel(*refs, nnz: int, d: int, dv: int, scale: float, score: str,
+                slope: float, group_size: int, strategy: str, has_bias: bool,
+                has_keep: bool):
+    rows_ref, cols_ref, lanes_ref, bias_ref, keep_ref, refs = _unpack_lanes(
+        refs, has_bias, has_keep)
+    (q_ref, k_ref, v_ref, acc_ref, m_ref,
+     qg_ref, kg_ref, vg_ref, mg_ref, part_ref) = refs
+    ph, i = pl.program_id(0), pl.program_id(1)
+    T, H = mg_ref.shape
+    valid = _lane_mask(nnz, T, i)
+    bias, keep = _lane_values(bias_ref, keep_ref)
+
+    def scores():
+        return _scores(upcast_f32(qg_ref[...]), upcast_f32(kg_ref[...]), bias,
+                       score=score, d=d, n_heads=H, scale=scale,
+                       slope=slope)[1]
 
     @pl.when((ph == 0) & (i == 0))
-    def _init():
-        dq_ref[...] = jnp.zeros_like(dq_ref)
-        dk_ref[...] = jnp.zeros_like(dk_ref)
-        dv_ref[...] = jnp.zeros_like(dv_ref)
-        delta_ref[...] = jnp.zeros_like(delta_ref)
+    def _init_max():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
 
-    rows = rows_ref[...]
-    cols = cols_ref[...]
-    lane = i * nnz_tile + jax.lax.broadcasted_iota(
-        jnp.int32, (nnz_tile,), 0)
-    valid = lane < nnz
+    @pl.when((ph == 1) & (i == 0))
+    def _init_acc():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(ph == 0)
-    def _delta_and_dv():
-        # recompute the probabilities from the carried forward stats
-        # (FlashAttention-style: O(n_rows) residuals, no (nnz,) weights
-        # saved across the fwd/bwd boundary), f32-forced
-        q, k, v, do = upcast_f32(q_ref[...], k_ref[...], v_ref[...],
-                                 do_ref[...])
-        s = jnp.sum(jnp.take(q, rows, axis=0) * jnp.take(k, cols, axis=0),
-                    axis=-1) * scale
-        if bias_ref is not None:
-            s = s + upcast_f32(bias_ref[...])
-        m_lane = jnp.take(m_ref[...][:, 0], rows)
-        m_safe = jnp.where(m_lane <= NEG_INF / 2, 0.0, m_lane)
-        linv = jnp.take(1.0 / jnp.maximum(l_ref[...][:, 0], 1e-30), rows)
-        w = jnp.where(valid,
-                      jnp.exp(jnp.where(valid, s, NEG_INF) - m_safe) * linv,
-                      0.0)
-        dw = jnp.sum(jnp.take(do, rows, axis=0)
-                     * jnp.take(v, cols, axis=0), axis=-1)  # SDDMM(dout, V)
-        # (nnz_pad, 1) carries: phase 1 replays (w, dw) with no recompute
-        w_ref[...] = w[:, None]
-        dw_ref[...] = dw[:, None]
-        # the softmax-backward row dot δ[r] = Σ w·dw — add-monoid scatter
-        group_reduce_scatter_values(rows, (w * dw)[:, None], delta_ref,
-                                    group_size, strategy)
-        # dV[c] += w · dout[r] — scatter-transpose (cols as segment ids)
-        group_reduce_scatter_values(
-            cols, w[:, None] * jnp.take(do, rows, axis=0), dv_ref,
-            group_size, strategy)
+    def _row_max():
+        def lane(t, c):
+            _gather(((q_ref, rows_ref[0, t], qg_ref),
+                     (k_ref, cols_ref[0, t], kg_ref)), t)
+            return c
+
+        jax.lax.fori_loop(0, T, lane, 0)
+        mg_ref[...] = jnp.where(valid, scores(), NEG_INF)
+        group_reduce_scatter(rows_ref, mg_ref, m_ref, group_size,
+                             "accumulate", op="max")
 
     @pl.when(ph == 1)
-    def _dq_and_dk():
-        q, k = upcast_f32(q_ref[...], k_ref[...])
-        w = w_ref[...][:, 0]
-        dw = dw_ref[...][:, 0]
-        ds = w * (dw - jnp.take(delta_ref[...][:, 0], rows)) * scale
-        group_reduce_scatter_values(
-            rows, ds[:, None] * jnp.take(k, cols, axis=0), dq_ref,
-            group_size, strategy)
-        # dK[c] += ds · Q[r] — scatter-transpose
-        group_reduce_scatter_values(
-            cols, ds[:, None] * jnp.take(q, rows, axis=0), dk_ref,
-            group_size, strategy)
+    def _weighted_sum():
+        def lane(t, c):
+            r, col = rows_ref[0, t], cols_ref[0, t]
+            _gather(((q_ref, r, qg_ref), (k_ref, col, kg_ref),
+                     (v_ref, col, vg_ref), (m_ref, r, mg_ref)), t)
+            return c
+
+        jax.lax.fori_loop(0, T, lane, 0)
+        p = jnp.where(valid, jnp.exp(scores() - mg_ref[...]), 0.0)
+        pk = p if keep is None else p * keep
+        part_ref[:, :H * dv] = _spread(pk, dv) * upcast_f32(vg_ref[...])
+        part_ref[:, H * dv:] = p
+        _row_add(rows_ref, lanes_ref, part_ref, acc_ref, group_size, strategy)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("n_rows", "nnz", "nnz_tile", "scale", "group_size",
-                     "strategy"),
+                     "strategy", "score", "slope", "interpret"),
 )
-def fused_sparse_attention_bwd(rows, cols, q, k, v, dout, m, l, *,
-                               n_rows: int, nnz: int, nnz_tile: int = 256,
-                               scale: float, group_size: int = 32,
-                               strategy: str = "segment", bias=None):
-    """One-launch fused backward: ``(dq, dk, dv)`` for all heads.
+def fused_sparse_attention(rows, cols, q, k, v, *, n_rows: int, nnz: int,
+                           nnz_tile: int = 256, scale: float = 1.0,
+                           group_size: int = 32, strategy: str = "segment",
+                           bias=None, score: str = "dot", slope: float = 0.2,
+                           keep=None, interpret: bool | None = None):
+    """One launch of the fused forward over all heads.
 
-    Grid (H, 2, nnz_tiles) — the nnz grid is walked twice inside one
-    kernel: phase 0 recomputes the probabilities from the forward's
-    (m, l) row stats, accumulates the softmax-backward row dot δ and the
-    dV transpose scatter, and stashes (w, dw) in (nnz_pad, 1) carries;
-    phase 1 forms ds from the carries and scatters dQ/dK.  Layouts match
-    :func:`fused_sparse_attention`: rows/cols/bias (nnz_pad,), q/k/v
-    (H, n, ·), dout (H, n_rows, dv), m/l (H, n_rows) as the forward
-    returned them.  No dv tiling: the backward holds whole per-head
-    feature blocks, like the forward holds whole q/k blocks.
-    """
-    nnz_pad = rows.shape[0]
-    n_heads, n_q, d = q.shape
-    _, n_kv, dv = v.shape
-    assert nnz_pad % nnz_tile == 0 and n_q == n_rows
-    assert dout.shape == (n_heads, n_rows, dv) and m.shape == (n_heads, n_q)
-    grid = (n_heads, 2, nnz_pad // nnz_tile)
-
-    qf = q.reshape(n_heads * n_rows, d)
-    kf = k.reshape(n_heads * n_kv, d)
-    vf = v.reshape(n_heads * n_kv, dv)
-    dof = dout.reshape(n_heads * n_rows, dv)
-    mf = m.reshape(n_heads * n_rows, 1)
-    lf = l.reshape(n_heads * n_rows, 1)
-
+    rows/cols (and bias) are (nnz_pad,) with nnz_pad % nnz_tile == 0
+    (``nnz`` is the true count; pad lanes are masked); q (n_rows, H, d),
+    k (n_kv, H, d), v (n_kv, H, dv) node-major, d = 1 for the additive
+    score; ``keep`` an optional (nnz_pad, H) mask on the normalised
+    coefficients.  Returns ``(out, m, l)``: out (n_rows, H, dv), and the
+    row max m and denominator l (n_rows, H), the O(H·n_rows) residuals the
+    backward recomputes the weights from.  ``interpret`` defaults to the
+    backend's answer (``common.pallas_call``)."""
+    _check(rows, q, k, v, nnz_tile, score, strategy)
+    n_kv, H, d = k.shape
+    dv = v.shape[2]
+    R, C = _sublanes(n_rows), _sublanes(n_kv)
+    tables = [_table(q, R), _table(k, C), _table(v, C)]
+    lanes = _lane_operands(rows, cols, bias, keep, nnz_tile)
+    resident = lambda p, i: (0, 0)  # noqa: E731
+    in_specs = _lane_specs(nnz_tile, H, bias is not None, keep is not None,
+                           lambda p, i: (i, 0, 0))
+    in_specs += [pl.BlockSpec(t.shape, resident) for t in tables]
+    out_shape = [jax.ShapeDtypeStruct((R, H * dv + H), jnp.float32),
+                 jax.ShapeDtypeStruct((R, H), jnp.float32)]
+    scratch = [(nnz_tile, H * d), (nnz_tile, H * d), (nnz_tile, H * dv),
+               (nnz_tile, H), (nnz_tile, H * dv + H)]
     kernel = functools.partial(
-        _fused_attn_bwd_kernel, nnz=nnz, nnz_tile=nnz_tile, scale=scale,
-        group_size=group_size, strategy=strategy,
-        has_bias=bias is not None)
-    lane_spec = pl.BlockSpec((nnz_tile,), lambda h, p, i: (i,))
-    carry_spec = pl.BlockSpec((nnz_tile, 1), lambda h, p, i: (i, 0))
-    stat_spec = pl.BlockSpec((n_rows, 1), lambda h, p, i: (h, 0))
-    in_specs = [lane_spec, lane_spec]
-    operands = [rows, cols]
-    if bias is not None:
-        in_specs.append(lane_spec)
-        operands.append(bias)
-    in_specs += [
-        pl.BlockSpec((n_rows, d), lambda h, p, i: (h, 0)),
-        pl.BlockSpec((n_kv, d), lambda h, p, i: (h, 0)),
-        pl.BlockSpec((n_kv, dv), lambda h, p, i: (h, 0)),
-        pl.BlockSpec((n_rows, dv), lambda h, p, i: (h, 0)),
-        stat_spec, stat_spec,
-    ]
-    dq, dk, dv_, _delta, _w, _dw = pallas_call(
+        _fwd_kernel, nnz=nnz, d=d, dv=dv, scale=scale, score=score,
+        slope=slope, group_size=group_size, strategy=strategy,
+        has_bias=bias is not None, has_keep=keep is not None)
+    acc, m = pallas_call(
+        kernel,
+        name="fused_attention_fwd",
+        grid=(2, rows.shape[0] // nnz_tile),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec(s.shape, resident) for s in out_shape],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in scratch],
+        vmem_need=_vmem_need(lanes, tables, out_shape, scratch),
+        interpret=interpret,
+    )(*lanes, *tables)
+    l = acc[:n_rows, H * dv:]
+    out = acc[:n_rows, :H * dv].reshape(n_rows, H, dv)
+    return out / jnp.maximum(l, 1e-30)[..., None], m[:n_rows], l
+
+
+def _vmem_need(lanes, tables, out_shape, scratch) -> int:
+    """Padded, buffered VMEM bytes of a launch: its lane blocks
+    double-buffered, its resident tables and outputs once, its scratch."""
+    need = sum(vmem_bytes(x.shape[1:], x.dtype, block_buffers(2))
+               for x in lanes)
+    need += sum(vmem_bytes(x.shape, x.dtype) for x in (*tables, *out_shape))
+    return need + sum(vmem_bytes(s, jnp.float32) for s in scratch)
+
+
+# ---------------------------------------------------------------------------
+# The backward kernel
+# ---------------------------------------------------------------------------
+
+
+def _bwd_kernel(*refs, nnz: int, d: int, dv: int, scale: float, score: str,
+                slope: float, group_size: int, strategy: str, has_bias: bool,
+                has_keep: bool):
+    rows_ref, cols_ref, lanes_ref, bias_ref, keep_ref, refs = _unpack_lanes(
+        refs, has_bias, has_keep)
+    (q_ref, k_ref, v_ref, do_ref, st_ref, dq_ref, dkv_ref,
+     qg_ref, kg_ref, vg_ref, dog_ref, sg_ref, pq_ref, pkv_ref) = refs
+    i = pl.program_id(0)
+    T = qg_ref.shape[0]
+    H = sg_ref.shape[1] // 3
+    valid = _lane_mask(nnz, T, i)
+    bias, keep = _lane_values(bias_ref, keep_ref)
+
+    @pl.when(i == 0)
+    def _init():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+        dkv_ref[...] = jnp.zeros_like(dkv_ref)
+
+    def lane(t, c):
+        r, col = rows_ref[0, t], cols_ref[0, t]
+        _gather(((q_ref, r, qg_ref), (k_ref, col, kg_ref), (v_ref, col, vg_ref),
+                 (do_ref, r, dog_ref), (st_ref, r, sg_ref)), t)
+        return c
+
+    jax.lax.fori_loop(0, T, lane, 0)
+    qg, kg = upcast_f32(qg_ref[...], kg_ref[...])
+    vg, dog = upcast_f32(vg_ref[...], dog_ref[...])
+    st = sg_ref[...]
+    m, linv, delta = st[:, :H], st[:, H:2 * H], st[:, 2 * H:]
+    pre, e = _scores(qg, kg, bias, score=score, d=d, n_heads=H, scale=scale,
+                     slope=slope)
+    w = jnp.where(valid, jnp.exp(e - m) * linv, 0.0)
+    wk = w if keep is None else w * keep
+    dw = _head_sum(dog * vg, dv, H)
+    if keep is not None:
+        dw = dw * keep
+    de = w * (dw - delta)
+    if score == "dot":
+        ds = _spread(de * scale, d)
+        gq, gk = ds * kg, ds * qg
+    else:
+        gq = gk = de * jnp.where(pre > 0, 1.0, slope)
+    pq_ref[...] = gq
+    pkv_ref[:, :H * d] = gk
+    pkv_ref[:, H * d:] = _spread(wk, dv) * dog
+    _row_add(rows_ref, lanes_ref, pq_ref, dq_ref, group_size, strategy)
+    group_reduce_scatter(cols_ref, pkv_ref, dkv_ref, group_size, "accumulate")
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_rows", "nnz", "nnz_tile", "scale", "group_size",
+                     "strategy", "score", "slope", "interpret"),
+)
+def fused_sparse_attention_bwd(rows, cols, q, k, v, out, dout, m, l, *,
+                               n_rows: int, nnz: int, nnz_tile: int = 256,
+                               scale: float = 1.0, group_size: int = 32,
+                               strategy: str = "segment", bias=None,
+                               score: str = "dot", slope: float = 0.2,
+                               keep=None, interpret: bool | None = None):
+    """One launch of the fused backward: ``(dq, dk, dv)`` for all heads,
+    in the layouts of :func:`fused_sparse_attention`; ``out``, ``m`` and
+    ``l`` are what the forward returned, ``dout`` (n_rows, H, dv) the
+    output's cotangent."""
+    _check(rows, q, k, v, nnz_tile, score, strategy)
+    n_kv, H, d = k.shape
+    dv = v.shape[2]
+    R, C = _sublanes(n_rows), _sublanes(n_kv)
+    delta = jnp.sum(dout.astype(jnp.float32) * out, axis=-1)
+    stats = jnp.concatenate([m, 1.0 / jnp.maximum(l, 1e-30), delta], axis=1)
+    tables = [_table(q, R), _table(k, C), _table(v, C), _table(dout, R),
+              _table(stats, R)]
+    lanes = _lane_operands(rows, cols, bias, keep, nnz_tile)
+    resident = lambda i: (0, 0)  # noqa: E731
+    in_specs = _lane_specs(nnz_tile, H, bias is not None, keep is not None,
+                           lambda i: (i, 0, 0))
+    in_specs += [pl.BlockSpec(t.shape, resident) for t in tables]
+    out_shape = [jax.ShapeDtypeStruct((R, H * d), jnp.float32),
+                 jax.ShapeDtypeStruct((C, H * d + H * dv), jnp.float32)]
+    scratch = [(nnz_tile, H * d), (nnz_tile, H * d), (nnz_tile, H * dv),
+               (nnz_tile, H * dv), (nnz_tile, 3 * H), (nnz_tile, H * d),
+               (nnz_tile, H * d + H * dv)]
+    kernel = functools.partial(
+        _bwd_kernel, nnz=nnz, d=d, dv=dv, scale=scale, score=score,
+        slope=slope, group_size=group_size, strategy=strategy,
+        has_bias=bias is not None, has_keep=keep is not None)
+    dq, dkv = pallas_call(
         kernel,
         name="fused_attention_bwd",
-        grid=grid,
+        grid=(rows.shape[0] // nnz_tile,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((n_rows, d), lambda h, p, i: (h, 0)),
-            pl.BlockSpec((n_kv, d), lambda h, p, i: (h, 0)),
-            pl.BlockSpec((n_kv, dv), lambda h, p, i: (h, 0)),
-            stat_spec,
-            carry_spec, carry_spec,
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_heads * n_rows, d), jnp.float32),
-            jax.ShapeDtypeStruct((n_heads * n_kv, d), jnp.float32),
-            jax.ShapeDtypeStruct((n_heads * n_kv, dv), jnp.float32),
-            jax.ShapeDtypeStruct((n_heads * n_rows, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nnz_pad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nnz_pad, 1), jnp.float32),
-        ],
-    )(*operands, qf, kf, vf, dof, mf, lf)
-    return (dq.reshape(n_heads, n_rows, d),
-            dk.reshape(n_heads, n_kv, d),
-            dv_.reshape(n_heads, n_kv, dv))
+        out_specs=[pl.BlockSpec(s.shape, resident) for s in out_shape],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in scratch],
+        vmem_need=_vmem_need(lanes, tables, out_shape, scratch),
+        interpret=interpret,
+    )(*lanes, *tables)
+    return (dq[:n_rows].reshape(n_rows, H, d),
+            dkv[:n_kv, :H * d].reshape(n_kv, H, d),
+            dkv[:n_kv, H * d:].reshape(n_kv, H, dv))

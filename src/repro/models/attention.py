@@ -177,10 +177,13 @@ def _flash_bwd(causal, q_chunk, kv_chunk, res, do):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def graph_attention(adj, q, k, v, *, schedule=None, scale=None):
+def graph_attention(adj, q, k, v, *, schedule=None, scale=None,
+                    score="dot", slope=0.2, keep=None):
     """Sparse (graph) attention over an adjacency pattern through the
-    fused one-pass SDDMM→softmax→SpMM kernel
-    (``repro.sparse.sparse_attention``), fused in both directions.
+    fused score→softmax→SpMM kernels (``repro.sparse.sparse_attention``),
+    fused in both directions.  ``score='additive'`` is GAT's
+    ``LeakyReLU_slope(q_i + k_j)`` over per-node, per-head terms of width
+    1; ``keep`` an optional (nnz, H) mask on the normalised coefficients.
 
     Single-head: q (n_rows, d), k/v (n_cols, d/dv).  Multi-head: q
     (n_rows, H, d) with k/v (n_cols, H, ·) — heads share the sparsity
@@ -191,7 +194,8 @@ def graph_attention(adj, q, k, v, *, schedule=None, scale=None):
     """
     from ..sparse import sparse_attention
 
-    return sparse_attention(adj, q, k, v, schedule=schedule, scale=scale)
+    return sparse_attention(adj, q, k, v, schedule=schedule, scale=scale,
+                            score=score, slope=slope, keep=keep)
 
 
 def attention_ref(q, k, v, causal=True):

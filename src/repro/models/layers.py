@@ -115,6 +115,65 @@ def gcn_two_layer(adj, x, w0, w1, b0=None, b1=None, *,
     return run_plan(p, x, params)
 
 
+def gat_layer(pattern, x, w, a_l, a_r, b, *, concat: bool = True,
+              activation=None, slope: float = 0.2, input_keep=None,
+              coef_keep=None, schedule=None):
+    """One multi-head graph attention layer (Veličković et al., ICLR 2018,
+    §2.1): per head h, ``Wh = x W_h``, scores ``LeakyReLU(a_l·Wh_i +
+    a_r·Wh_j)`` over each row's pattern entries, softmax, then the
+    coefficient-weighted sum of ``Wh_j``; heads concatenated (``concat``)
+    or averaged, then the bias and ``activation``.
+
+    pattern     ``(rows, cols, n_rows)``: the adjacency's entries (self
+                loops included); only the pattern is attended over.
+    x           (n, F_in); w (F_in, H·F); a_l, a_r (H, F); b (H·F,) when
+                concatenated, (F,) when averaged.
+    input_keep  optional (n, F_in) dropout mask on ``x`` (0 or 1/(1-p));
+    coef_keep   optional (nnz, H) dropout mask on the normalised
+                coefficients, applied in the fused kernels.
+
+    The attention runs through ``graph_attention`` with the additive
+    score: the fused forward and backward kernels, all heads in one
+    launch each.  XLA work is named ``proj``, ``scores`` and ``out``."""
+    from .attention import graph_attention
+
+    n_heads, width = a_l.shape
+    if input_keep is not None:
+        x = x * input_keep
+    with jax.named_scope("proj"):
+        wh = (x @ w).reshape(x.shape[0], n_heads, width)
+    with jax.named_scope("scores"):
+        s = jnp.einsum("nhf,hf->nh", wh, a_l)[..., None]
+        t = jnp.einsum("nhf,hf->nh", wh, a_r)[..., None]
+    out = graph_attention(pattern, s, t, wh, schedule=schedule,
+                          score="additive", slope=slope, keep=coef_keep)
+    with jax.named_scope("out"):
+        out = (out.reshape(out.shape[0], n_heads * width) if concat
+               else jnp.mean(out, axis=1)) + b
+        return out if activation is None else activation(out)
+
+
+def gat_two_layer(pattern, x, params, *, slope: float = 0.2, keeps=None,
+                  schedule=None):
+    """The two-layer GAT of Veličković et al. (transductive setting): a
+    concatenating layer with ELU, then an averaging output layer; returns
+    the logits (n, C).  ``params`` holds ``w0, al0, ar0, b0`` and ``w1,
+    al1, ar1, b1`` (shapes as :func:`gat_layer`); ``keeps`` optional
+    dropout masks ``x0``, ``coef0``, ``x1``, ``coef1`` (the input and
+    coefficient masks of each layer; absent in evaluation).  The layers'
+    XLA work is named ``gat.layer0`` and ``gat.layer1``."""
+    keeps = keeps or {}
+    h = x
+    for i, (concat, act) in enumerate(((True, jax.nn.elu), (False, None))):
+        with jax.named_scope(f"gat.layer{i}"):
+            h = gat_layer(pattern, h, params[f"w{i}"], params[f"al{i}"],
+                          params[f"ar{i}"], params[f"b{i}"], concat=concat,
+                          activation=act, slope=slope,
+                          input_keep=keeps.get(f"x{i}"),
+                          coef_keep=keeps.get(f"coef{i}"), schedule=schedule)
+    return h
+
+
 # ---------------------------------------------------------------- linear
 
 

@@ -311,8 +311,8 @@ def dist_spmm(csr, b, *, mesh, axis: str, schedule=None,
 # ---------------------------------------------------------------------------
 
 
-def _local_attention(rows, cols, q, k, v, *, n_rows, dv_tile, scale,
-                     sched, bias=None):
+def _local_attention(rows, cols, q, k, v, *, n_rows, scale, sched,
+                     bias=None):
     """Run the fused kernel over a shard's lanes at height ``n_rows`` + 1
     phantom row (pad lanes land there; the caller crops it)."""
     strategy = (sched.strategy
@@ -326,13 +326,15 @@ def _local_attention(rows, cols, q, k, v, *, n_rows, dv_tile, scale,
         cols = jnp.concatenate([cols, jnp.zeros((pad,), jnp.int32)])
         if bias is not None:
             bias = jnp.concatenate([bias, jnp.zeros((pad,), bias.dtype)])
+    # the kernel is node-major, (n, H, ·); the shards' algebra head-major
     q_ph = jnp.pad(q, ((0, 0), (0, 1), (0, 0)))
     out, m, l = fused_sparse_attention(
-        rows, cols, q_ph, k, v, n_rows=n_rows + 1,
-        nnz=int(rows.shape[0]), nnz_tile=sched.nnz_tile,
-        dv_tile=dv_tile, scale=scale,
-        group_size=sched.group_size, strategy=strategy, bias=bias)
-    return out[:, :n_rows], m[:, :n_rows], l[:, :n_rows]
+        rows, cols, *(jnp.moveaxis(x, 0, 1) for x in (q_ph, k, v)),
+        n_rows=n_rows + 1, nnz=int(rows.shape[0]), nnz_tile=sched.nnz_tile,
+        scale=scale, group_size=sched.group_size, strategy=strategy,
+        bias=bias)
+    return (jnp.moveaxis(out, 1, 0)[:, :n_rows], m.T[:, :n_rows],
+            l.T[:, :n_rows])
 
 
 def _combine_partials(out_s, m_s, l_s, axis, *, scatter):
@@ -371,7 +373,7 @@ def dist_attention_shard_map(rows, cols, q, k, v, *, n_rows: int, mesh,
     helpers with ``phantom_row=True`` (pad lanes have no zero value, so
     they target the phantom row and are cropped, never masked).  q/k/v
     are head-major — q (H, n_rows, d), k (H, n_kv, d), v (H, n_kv, dv)
-    with dv a multiple of 8; 2-D inputs are treated as one head.
+    2-D inputs are treated as one head.
 
     row      rows pre-bucketed per shard (local indices,
              :func:`partition_rows_coo`), q row-sharded, k/v replicated;
@@ -396,11 +398,6 @@ def dist_attention_shard_map(rows, cols, q, k, v, *, n_rows: int, mesh,
         q, k, v = q[None], k[None], v[None]
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))
-    dv = int(v.shape[2])
-    dv_tile = min(128, round_up(dv, 8))
-    dv_pad = round_up(dv, dv_tile)
-    if dv_pad != dv:
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, dv_pad - dv)))
     has_bias = bias is not None
     lane_specs = (P(axis), P(axis)) + ((P(axis),) if has_bias else ())
 
@@ -420,8 +417,7 @@ def dist_attention_shard_map(rows, cols, q, k, v, *, n_rows: int, mesh,
             b = rest[0] if has_bias else None
             qq, kk, vv = rest[-3:]
             out, _, _ = _local_attention(r, c, qq, kk, vv, n_rows=block,
-                                         dv_tile=dv_tile, scale=scale,
-                                         sched=sched, bias=b)
+                                         scale=scale, sched=sched, bias=b)
             return out
 
         args = (rows, cols) + ((bias,) if has_bias else ()) + (q, k, v)
@@ -441,13 +437,12 @@ def dist_attention_shard_map(rows, cols, q, k, v, *, n_rows: int, mesh,
             b = rest[0] if has_bias else None
             qq, kk, vv = rest[-3:]
             out_s, m_s, l_s = _local_attention(
-                r, c, qq, kk, vv, n_rows=n_rows, dv_tile=dv_tile,
-                scale=scale, sched=sched, bias=b)
+                r, c, qq, kk, vv, n_rows=n_rows, scale=scale, sched=sched,
+                bias=b)
             return _combine_partials(out_s, m_s, l_s, axis,
                                      scatter=mode == "nnz_rs")
 
         args = (rows, cols) + ((bias,) if has_bias else ()) + (q, k, v)
         out = _nnz(*args)
 
-    out = out[..., :dv]
     return out[0] if squeeze else out

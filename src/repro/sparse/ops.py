@@ -21,18 +21,19 @@ ops' epilogue slots rather than callers picking per-op):
 * ``segment_reduce(..., op="max"|"mean")`` runs the monoid-generalized
   group machinery (graph pooling); ``mean`` is the add monoid with a
   fused count column (one kernel pass + a divide).
-* ``sparse_attention`` is the one-pass SDDMM → segment softmax → SpMM
-  kernel with online renormalization (``kernels.fused_attention``),
-  batched over heads in one launch, with CSR stored values as an
-  additive score bias.
+* ``sparse_attention`` is the fused score → segment softmax → SpMM
+  kernel (``kernels.fused_attention``; a row-max pass, then one
+  weighted-sum pass), all heads in one launch, with a dot-product or
+  GAT's additive score, CSR stored values as an additive score bias and
+  an optional keep mask on the coefficients.
 
 ``spmm`` over CSR and ``sparse_attention`` are differentiable: forwards
 run the scheduled Pallas kernels; ``spmm``'s backward closes the paper's
 algebra family on itself (SDDMM / transpose-SpMM / segment ops — Sgap
 Eq. 2c/2d) through the pure-JAX oracles, while ``sparse_attention``'s
 backward is itself a fused Pallas kernel (DESIGN.md §9): one launch
-recomputes the probabilities from the saved softmax row stats, scatters
-the softmax-backward row dot δ, and scatter-transposes dK/dV.
+recomputes the probabilities from the saved softmax row stats and
+scatters dQ by row and dK/dV by column.
 Feed-format conversions go through the
 per-(format, tile) caches on ``CSR``/``GroupedCOO``, so serving loops
 re-using the same matrix do not re-convert every call.
@@ -362,69 +363,72 @@ def _attn_pattern(adj):
 
 
 def _attn_heads(q, k, v):
-    """Normalize q/k/v to the kernel's head-major (H, n, ·) layout.
-    2-D inputs are a single head; 3-D inputs are (n, H, ·) — heads on
-    axis 1, matching ``models.attention``.  Returns (qh, kh, vh, multi).
+    """Normalize q/k/v to the kernel's node-major (n, H, ·) layout.
+    2-D inputs are a single head; 3-D inputs are (n, H, ·) already.
+    Returns (qh, kh, vh, multi).
     """
     if q.ndim == k.ndim == v.ndim == 2:
-        return q[None], k[None], v[None], False
+        return q[:, None], k[:, None], v[:, None], False
     if not (q.ndim == k.ndim == v.ndim == 3
             and q.shape[1] == k.shape[1] == v.shape[1]):
         raise ValueError(
             f"attention wants all-2-D (n, d) q/k/v or all-3-D (n, H, d) "
             f"with one shared head count H; got {q.shape}, {k.shape}, "
             f"{v.shape}")
-    return (jnp.moveaxis(q, 1, 0), jnp.moveaxis(k, 1, 0),
-            jnp.moveaxis(v, 1, 0), True)
+    return q, k, v, True
 
 
 def sparse_attention(adj, q, k, v, *, schedule=None,
-                     scale: float | None = None, impl: str = "pallas"):
+                     scale: float | None = None, impl: str = "pallas",
+                     score: str = "dot", slope: float = 0.2, keep=None):
     """One-pass sparse attention over a sparsity pattern:
-    ``out[r] = Σ_t softmax_row(<Q[r], K[c_t]> · scale + bias_t) V[c_t]``.
+    ``out[r] = Σ_t softmax_row(e_t) · keep_t · V[c_t]`` with
+    ``e_t = <Q[r], K[c_t]> · scale + bias_t`` (``score='dot'``) or
+    ``e_t = LeakyReLU_slope(Q[r] + K[c_t] + bias_t)`` (``score='additive'``,
+    GAT's score: q and k are per-node, per-head terms of width 1).
 
     adj       a CSR adjacency — its pattern is attended over and its
               stored values are an additive score bias (row-constant
-              values, e.g. the all-ones pattern CSR, cancel in the
-              softmax; see :func:`_attn_pattern`) — or a
-              ``(rows, cols, n_rows)`` pure-pattern tuple with rows
-              sorted non-decreasing (CSR order).
+              values, e.g. the all-ones pattern CSR, cancel in the dot
+              form's softmax, not under the additive form's LeakyReLU;
+              see :func:`_attn_pattern`) — or a ``(rows, cols, n_rows)``
+              pure-pattern tuple.
     q         (n_rows, d) queries, or (n_rows, H, d) for H heads;
     k         (n_cols, d) / (n_cols, H, d) keys;
     v         (n_cols, dv) / (n_cols, H, dv) values.  All H heads share
-              the pattern and run in ONE kernel launch (the head axis is
-              folded into the kernel grid).
+              the pattern and run in ONE kernel launch (heads lie in
+              the kernel's lanes).
+    keep      optional (nnz, H) (or (nnz,) for one head) mask on the
+              normalised coefficients, in both directions: dropout's
+              0 or 1/(1-p).
     schedule  supplies (nnz_tile, group_size, strategy) for the fused
-              kernel's grid; ``"tune"`` measures the real fused kernel
-              for this pattern (``repro.tune.tune_sparse_attention``,
-              cached by pattern fingerprint × head count × direction);
+              kernels; ``"tune"`` measures the real fused kernel for
+              this pattern (``repro.tune.tune_sparse_attention``, cached
+              by pattern fingerprint × head count × direction);
               'parallel' is excluded (its one-writeback contract does
               not hold for attention rows).
-    impl      'pallas' (the fused kernel — SDDMM → online segment
-              softmax → SpMM in one pass) or 'ref' (the spec oracle).
+    impl      'pallas' (the fused kernels) or 'ref' (the spec oracle).
 
     Differentiable in q, k, v — the custom VJP runs the fused *backward*
-    kernel (one launch over (H, 2, nnz_tiles): δ scatter + dV transpose,
-    then dQ/dK from the carried probabilities), so ``impl="pallas"`` is
-    fused in both directions.  The adjacency — pattern AND value bias —
-    is *data*, not a differentiable operand: gradients w.r.t. the CSR's
-    stored values are not defined (pass the bias through q/k features if
-    it must be learned).  ``schedule="tune"`` tunes the forward grid;
-    the backward reuses that schedule (tuning the bwd direction from the
-    training loop is a ROADMAP follow-on —
-    ``tune_sparse_attention(direction="bwd")`` exists for it).  Empty
-    rows -> zero rows.
+    kernel, so ``impl="pallas"`` is fused in both directions.  The
+    adjacency (pattern and value bias) and the keep mask are *data*, not
+    differentiable operands.  ``schedule="tune"`` tunes the forward grid;
+    the backward reuses that schedule.  Empty rows -> zero rows.
     """
     rows, cols, n_rows, bias = _attn_pattern(adj)
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
     qh, kh, vh, multi = _attn_heads(q, k, v)
+    if keep is not None and keep.ndim == 1:
+        keep = keep[:, None]
     if impl == "ref":
-        outs = [sparse_attention_ref(rows, cols, qh[h], kh[h], vh[h],
-                                     n_rows=n_rows, scale=scale, bias=bias)
-                for h in range(qh.shape[0])]
-        out = jnp.stack(outs, axis=0)
-        return jnp.moveaxis(out, 0, 1) if multi else out[0]
+        outs = [sparse_attention_ref(
+                    rows, cols, qh[:, h], kh[:, h], vh[:, h], n_rows=n_rows,
+                    scale=scale, bias=bias, score=score, slope=slope,
+                    keep=None if keep is None else keep[:, h])
+                for h in range(qh.shape[1])]
+        out = jnp.stack(outs, axis=1)
+        return out if multi else out[:, 0]
     if isinstance(schedule, str) and schedule == "tune":
         from ..tune import tune_sparse_attention
 
@@ -438,50 +442,38 @@ def sparse_attention(adj, q, k, v, *, schedule=None,
             "sparse_attention cannot run the 'parallel' strategy: its "
             "single-writeback contract does not hold for attention rows")
     out = _sparse_attention_diff(rows, cols, qh, kh, vh, n_rows, scale,
-                                 sched, bias)
-    return jnp.moveaxis(out, 0, 1) if multi else out[0]
+                                 sched, bias, score=score, slope=slope,
+                                 keep=keep)
+    return out if multi else out[:, 0]
 
 
 def _sparse_attention_diff(rows, cols, qh, kh, vh, n_rows, scale, sched,
-                           bias=None):
-    """Custom-VJP core over head-major (H, n, ·) operands: fused Pallas
-    forward (saving the (m, l) softmax row stats — the O(H·n_rows)
-    FlashAttention residuals), fused Pallas backward."""
+                           bias=None, *, score="dot", slope=0.2, keep=None):
+    """Custom-VJP core over node-major (n, H, ·) operands: fused Pallas
+    forward (saving its output and the (m, l) softmax row stats — the
+    O(H·n_rows) FlashAttention residuals), fused Pallas backward."""
     nnz = int(rows.shape[0])
-    nnz_tile = sched.nnz_tile
-    nnz_pad = max(round_up(max(nnz, 1), nnz_tile), nnz_tile)
-    rows_p = jnp.pad(rows, (0, nnz_pad - nnz))
-    cols_p = jnp.pad(cols, (0, nnz_pad - nnz))
-    bias_p = (None if bias is None
-              else jnp.pad(bias.astype(jnp.float32), (0, nnz_pad - nnz)))
-    dv = vh.shape[-1]
-    dv_tile = min(128, round_up(dv, 8))
-    dv_pad = round_up(dv, dv_tile)
-
-    def _run_fwd(q, k, v):
-        v_p = (jnp.pad(v, ((0, 0), (0, 0), (0, dv_pad - dv)))
-               if dv_pad != dv else v)
-        out, m, l = _fused_attn_fwd(
-            rows_p, cols_p, q, k, v_p, n_rows=n_rows, nnz=nnz,
-            nnz_tile=nnz_tile, dv_tile=dv_tile, scale=scale,
-            group_size=sched.group_size, strategy=sched.strategy,
-            bias=bias_p)
-        return out[..., :dv], m, l
+    nnz_pad = max(round_up(max(nnz, 1), sched.nnz_tile), sched.nnz_tile)
+    pad = lambda x: jnp.pad(x, ((0, nnz_pad - nnz),) + ((0, 0),) * (x.ndim - 1))  # noqa: E731
+    lanes = dict(
+        n_rows=n_rows, nnz=nnz, nnz_tile=sched.nnz_tile, scale=scale,
+        group_size=sched.group_size, strategy=sched.strategy, score=score,
+        slope=slope, bias=None if bias is None else pad(bias.astype(jnp.float32)),
+        keep=None if keep is None else pad(keep.astype(jnp.float32)))
+    rows_p, cols_p = pad(rows), pad(cols)
 
     @jax.custom_vjp
     def _fn(q, k, v):
-        return _run_fwd(q, k, v)[0]
+        return _fused_attn_fwd(rows_p, cols_p, q, k, v, **lanes)[0]
 
     def _fwd(q, k, v):
-        out, m, l = _run_fwd(q, k, v)
-        return out, (q, k, v, m, l)
+        out, m, l = _fused_attn_fwd(rows_p, cols_p, q, k, v, **lanes)
+        return out, (q, k, v, out, m, l)
 
     def _bwd(res, dout):
-        q, k, v, m, l = res
-        dq, dk, dv_ = _fused_attn_bwd(
-            rows_p, cols_p, q, k, v, dout, m, l, n_rows=n_rows, nnz=nnz,
-            nnz_tile=nnz_tile, scale=scale, group_size=sched.group_size,
-            strategy=sched.strategy, bias=bias_p)
+        q, k, v, out, m, l = res
+        dq, dk, dv_ = _fused_attn_bwd(rows_p, cols_p, q, k, v, out, dout, m,
+                                      l, **lanes)
         return (dq.astype(q.dtype), dk.astype(k.dtype),
                 dv_.astype(v.dtype))
 

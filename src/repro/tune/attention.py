@@ -2,9 +2,9 @@
 
 The fused attention kernels expose the same (nnz_tile, group_size,
 strategy) axes as ``segment_reduce`` — but the *objective* differs per
-direction: the forward is a (H, nnz_tiles, dv_tiles) grid with the
-probability carry, the backward a (H, 2, nnz_tiles) two-phase grid with
-twice the scatter traffic.  A schedule tuned for one is not evidence
+direction: the forward is a (2, nnz_tiles) grid (a row-max pass, then
+a weighted-sum pass), the backward one pass with a row scatter and a
+column scatter.  A schedule tuned for one is not evidence
 about the other, and batching H heads into one launch changes the
 arithmetic intensity per pattern byte.  The cache key therefore carries
 the **direction** (``fwd``/``bwd``), the **head count**, the feature
@@ -103,8 +103,8 @@ def tune_sparse_attention(
     from ..sparse.ops import _attn_heads
 
     qh, kh, vh, _ = _attn_heads(q, k, v)
-    n_heads, _, d = qh.shape
-    n_cols, dv = vh.shape[1], vh.shape[-1]
+    _, n_heads, d = qh.shape
+    n_cols, dv = vh.shape[0], vh.shape[-1]
     if scale is None:
         scale = float(d) ** -0.5
     key = attention_cache_key(rows, n_rows, n_cols=n_cols, d=d, dv=dv,
@@ -118,14 +118,10 @@ def tune_sparse_attention(
 
     if measure is None:
         nnz = int(np.asarray(rows).shape[0])
-        dv_tile = min(128, round_up(dv, 8))
-        dv_pad = round_up(dv, dv_tile)
-        v_p = (jnp.pad(vh, ((0, 0), (0, 0), (0, dv_pad - dv)))
-               if dv_pad != dv else vh)
-        # the cotangent has the OUTPUT's shape — (H, n_rows, dv), not
-        # v's (H, n_cols, dv); they only coincide on square patterns
+        # the cotangent has the OUTPUT's shape — (n_rows, H, dv), not
+        # v's (n_cols, H, dv); they only coincide on square patterns
         dout = jax.random.normal(jax.random.PRNGKey(0),
-                                 (n_heads, n_rows, dv))
+                                 (n_rows, n_heads, dv))
 
         def measure(s: Schedule) -> float:
             nnz_pad = max(round_up(max(nnz, 1), s.nnz_tile), s.nnz_tile)
@@ -138,18 +134,18 @@ def tune_sparse_attention(
             def fwd(qq, kk, vv):
                 return fused_sparse_attention(
                     rows_p, cols_p, qq, kk, vv, n_rows=n_rows, nnz=nnz,
-                    nnz_tile=s.nnz_tile, dv_tile=dv_tile, scale=scale,
+                    nnz_tile=s.nnz_tile, scale=scale,
                     group_size=s.group_size, strategy=s.strategy,
                     bias=bias_p)
 
             if direction == "fwd":
                 return time_fn(lambda qq, kk, vv: fwd(qq, kk, vv)[0],
-                               qh, kh, v_p, warmup=warmup, iters=iters)
-            _, m, l = fwd(qh, kh, v_p)
+                               qh, kh, vh, warmup=warmup, iters=iters)
+            out, m, l = fwd(qh, kh, vh)
 
             def bwd(qq, kk, vv, do):
                 return fused_sparse_attention_bwd(
-                    rows_p, cols_p, qq, kk, vv, do, m, l, n_rows=n_rows,
+                    rows_p, cols_p, qq, kk, vv, out, do, m, l, n_rows=n_rows,
                     nnz=nnz, nnz_tile=s.nnz_tile, scale=scale,
                     group_size=s.group_size, strategy=s.strategy,
                     bias=bias_p)

@@ -1,9 +1,9 @@
 """Fused sparse attention full-pipeline tests (ISSUE 5): the fused
 *backward* Pallas kernel (dQ/dK/dV parity vs the spec-recompute VJP),
-one-launch multi-head batching, the probability carry on multi-dv-tile
-grids, CSR stored values as an additive score bias, f32-forced score
-accumulation for low-precision inputs, and the fused-attention tuner's
-direction/head-count cache keys.
+one-launch multi-head batching, value rows wider than a vreg, CSR
+stored values as an additive score bias, f32-forced score accumulation
+for low-precision inputs, the fused-attention tuner's direction/head-count
+cache keys, and GAT's additive score and keep mask.
 
 Property tests run under hypothesis when installed; without it they
 degrade to a fixed seed sweep covering the same edge cases (empty rows,
@@ -122,28 +122,28 @@ def test_fused_backward_kernel_matches_spec_vjp_directly():
     nnz_pad = round_up(nnz, nnz_tile)
     rows_p = jnp.pad(rows, (0, nnz_pad - nnz))
     cols_p = jnp.pad(cols, (0, nnz_pad - nnz))
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, R, d))
-    k = jax.random.normal(jax.random.PRNGKey(1), (1, C, d))
-    v = jax.random.normal(jax.random.PRNGKey(2), (1, C, dv))
-    dout = jax.random.normal(jax.random.PRNGKey(3), (1, R, dv))
+    q = jax.random.normal(jax.random.PRNGKey(0), (R, 1, d))
+    k = jax.random.normal(jax.random.PRNGKey(1), (C, 1, d))
+    v = jax.random.normal(jax.random.PRNGKey(2), (C, 1, dv))
+    dout = jax.random.normal(jax.random.PRNGKey(3), (R, 1, dv))
     bias = jnp.asarray(rng.standard_normal(nnz).astype(np.float32))
     scale = d ** -0.5
     for b in (None, bias):
         b_p = None if b is None else jnp.pad(b, (0, nnz_pad - nnz))
-        _, m, l = fused_sparse_attention(
+        out, m, l = fused_sparse_attention(
             rows_p, cols_p, q, k, v, n_rows=R, nnz=nnz, nnz_tile=nnz_tile,
-            dv_tile=dv, scale=scale, group_size=8, bias=b_p)
+            scale=scale, group_size=8, bias=b_p)
         dq, dk, dv_ = fused_sparse_attention_bwd(
-            rows_p, cols_p, q, k, v, dout, m, l, n_rows=R, nnz=nnz,
+            rows_p, cols_p, q, k, v, out, dout, m, l, n_rows=R, nnz=nnz,
             nnz_tile=nnz_tile, scale=scale, group_size=8, bias=b_p)
         wq, wk, wv = sparse_attention_bwd_ref(
-            rows, cols, q[0], k[0], v[0], dout[0], n_rows=R, scale=scale,
-            bias=b)
-        np.testing.assert_allclose(np.asarray(dq[0]), np.asarray(wq),
+            rows, cols, q[:, 0], k[:, 0], v[:, 0], dout[:, 0], n_rows=R,
+            scale=scale, bias=b)
+        np.testing.assert_allclose(np.asarray(dq[:, 0]), np.asarray(wq),
                                    rtol=GRAD_TOL, atol=GRAD_TOL)
-        np.testing.assert_allclose(np.asarray(dk[0]), np.asarray(wk),
+        np.testing.assert_allclose(np.asarray(dk[:, 0]), np.asarray(wk),
                                    rtol=GRAD_TOL, atol=GRAD_TOL)
-        np.testing.assert_allclose(np.asarray(dv_[0]), np.asarray(wv),
+        np.testing.assert_allclose(np.asarray(dv_[:, 0]), np.asarray(wv),
                                    rtol=GRAD_TOL, atol=GRAD_TOL)
 
 
@@ -179,28 +179,28 @@ def test_fused_backward_empty_and_single_nnz_rows():
 
 
 # ---------------------------------------------------------------------------
-# Multi-dv-tile grids: the probability carry
+# Wide value rows
 # ---------------------------------------------------------------------------
 
 
 def test_forward_multi_dv_tile_probability_carry():
-    """dv spanning several dv tiles must match the oracle exactly — the
-    (nnz_tile, 1) carry replays the tile's probabilities at dv steps > 0
-    instead of recomputing scores."""
+    """Value rows wider than a vreg (2 heads x 24 lanes, several tiles of
+    lanes) must match the oracle: each lane's probabilities are spread
+    over every lane of its head's values."""
     rows, cols = _pattern(14, 10, 33, 7)
     nnz_pad = round_up(33, 32)
     rows_p = jnp.pad(rows, (0, nnz_pad - 33))
     cols_p = jnp.pad(cols, (0, nnz_pad - 33))
-    q = jax.random.normal(jax.random.PRNGKey(0), (2, 14, 8))
-    k = jax.random.normal(jax.random.PRNGKey(1), (2, 10, 8))
-    v = jax.random.normal(jax.random.PRNGKey(2), (2, 10, 24))
+    q = jax.random.normal(jax.random.PRNGKey(0), (14, 2, 8))
+    k = jax.random.normal(jax.random.PRNGKey(1), (10, 2, 8))
+    v = jax.random.normal(jax.random.PRNGKey(2), (10, 2, 72))
     out, _, _ = fused_sparse_attention(
         rows_p, cols_p, q, k, v, n_rows=14, nnz=33, nnz_tile=32,
-        dv_tile=8, scale=0.5, group_size=8)  # 3 dv tiles
+        scale=0.5, group_size=8)
     for h in range(2):
-        want = sparse_attention_ref(rows, cols, q[h], k[h], v[h],
+        want = sparse_attention_ref(rows, cols, q[:, h], k[:, h], v[:, h],
                                     n_rows=14, scale=0.5)
-        np.testing.assert_allclose(np.asarray(out[h]), np.asarray(want),
+        np.testing.assert_allclose(np.asarray(out[:, h]), np.asarray(want),
                                    rtol=RTOL, atol=ATOL)
 
 
@@ -456,3 +456,79 @@ def test_sparse_attention_schedule_tune_end_to_end():
         set_default_cache(None)
     want = np.asarray(sparse_attention_ref(rows, cols, q, k, v, n_rows=16))
     np.testing.assert_allclose(got, want, rtol=RTOL, atol=ATOL)
+
+
+# ---------------------------------------------------------------------------
+# GAT's additive score and the keep mask on the coefficients
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "keep"])
+@pytest.mark.parametrize("score", ["additive", "dot"])
+@pytest.mark.parametrize("sched", SCHEDS, ids=lambda s: s.strategy)
+def test_fused_kernels_score_and_keep_match_oracle(sched, score, masked):
+    """Kernel-level parity of the forward's output and the backward's
+    dQ/dK/dV against ``sparse_attention_ref`` and
+    ``sparse_attention_bwd_ref``, head by head, for the additive score
+    (LeakyReLU of per-node terms of width 1) and the dot product, with and
+    without a (nnz, H) keep mask; the pattern has empty rows and spans
+    several nnz tiles."""
+    R, C, nnz, H, dv = 21, 17, 90, 3, 5
+    d = 1 if score == "additive" else 4
+    rows, cols = _pattern(R, C, nnz, 13)
+    tile = sched.nnz_tile
+    nnz_pad = round_up(nnz, tile)
+    rows_p = jnp.pad(rows, (0, nnz_pad - nnz))
+    cols_p = jnp.pad(cols, (0, nnz_pad - nnz))
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    q = jax.random.normal(ks[0], (R, H, d))
+    k = jax.random.normal(ks[1], (C, H, d))
+    v = jax.random.normal(ks[2], (C, H, dv))
+    dout = jax.random.normal(ks[3], (R, H, dv))
+    keep = (jnp.where(jax.random.bernoulli(ks[4], 0.4, (nnz, H)), 2.5, 0.0)
+            if masked else None)
+    keep_p = None if keep is None else jnp.pad(keep, ((0, nnz_pad - nnz), (0, 0)))
+    kw = dict(n_rows=R, nnz=nnz, nnz_tile=tile, scale=0.5, score=score,
+              slope=0.2, group_size=sched.group_size, strategy=sched.strategy)
+    out, m, l = fused_sparse_attention(rows_p, cols_p, q, k, v, keep=keep_p,
+                                       **kw)
+    dq, dk, dv_ = fused_sparse_attention_bwd(rows_p, cols_p, q, k, v, out,
+                                             dout, m, l, keep=keep_p, **kw)
+    for h in range(H):
+        kh = None if keep is None else keep[:, h]
+        spec = dict(n_rows=R, scale=0.5, score=score, slope=0.2, keep=kh)
+        want = sparse_attention_ref(rows, cols, q[:, h], k[:, h], v[:, h],
+                                    **spec)
+        np.testing.assert_allclose(np.asarray(out[:, h]), np.asarray(want),
+                                   rtol=RTOL, atol=ATOL)
+        grads = sparse_attention_bwd_ref(rows, cols, q[:, h], k[:, h],
+                                         v[:, h], dout[:, h], **spec)
+        for got, w in zip((dq[:, h], dk[:, h], dv_[:, h]), grads):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(w),
+                                       rtol=GRAD_TOL, atol=GRAD_TOL)
+
+
+def test_public_api_keep_and_additive_grads_match_spec():
+    """``sparse_attention`` with the additive score and a keep mask,
+    differentiated end to end through the custom VJP, against the spec
+    oracle's autodiff."""
+    rows, cols = _pattern(18, 18, 60, 21)
+    H = 2
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    s = jax.random.normal(ks[0], (18, H, 1))
+    t = jax.random.normal(ks[1], (18, H, 1))
+    v = jax.random.normal(ks[2], (18, H, 6))
+    keep = jnp.where(jax.random.bernoulli(ks[3], 0.5, (60, H)), 2.0, 0.0)
+
+    def loss(impl):
+        def f(args):
+            out = sparse_attention((rows, cols, 18), *args, impl=impl,
+                                   score="additive", keep=keep)
+            return jnp.sum(jnp.sin(out))
+        return f
+
+    g_f = jax.grad(loss("pallas"))((s, t, v))
+    g_s = jax.grad(loss("ref"))((s, t, v))
+    for gf, gs in zip(g_f, g_s):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gs),
+                                   rtol=GRAD_TOL, atol=GRAD_TOL)

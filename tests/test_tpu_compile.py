@@ -1,5 +1,5 @@
-"""Compile the GCN path's Pallas kernels for a described TPU v5e at the
-widths of Planetoid PubMed — no chip needed.
+"""Compile the GCN and GAT paths' Pallas kernels for a described TPU v5e
+at the widths of Planetoid PubMed — no chip needed.
 
 Mosaic refuses patterns the interpreter accepts (gathers from VMEM
 values, dynamic slices of values, 1-D blocks that disagree with XLA's
@@ -210,3 +210,85 @@ def test_gcn_step_launches_are_named(one_chip, monkeypatch):
     launches = trace.pallas_launches(hlo)
     assert [(lc["kernel"], lc["backward"]) for lc in launches] == [("spmm_eb", False)] * 2
     assert all(lc["name"].startswith("spmm_eb") for lc in launches)
+
+
+#: GAT-PubMed (Veličković et al.): 8 heads; value widths 8 (the first
+#: layer) and 3 (the output layer's classes)
+GAT_HEADS = 8
+
+
+@pytest.mark.parametrize("width", [8, 3])
+def test_fused_attention_compiles(one_chip, pubmed, width):
+    """The fused attention forward and backward, additive score with a
+    keep mask, compile to ``tpu_custom_call`` at GAT-PubMed shapes: 19,717
+    rows, 108,393 entries, 8 heads of ``width`` values."""
+    from repro.kernels.fused_attention import (
+        fused_sparse_attention,
+        fused_sparse_attention_bwd,
+    )
+
+    n, nnz, _ = pubmed
+    nnz_pad = round_up(nnz, NNZ_TILE)
+    kw = dict(n_rows=n, nnz=nnz, nnz_tile=NNZ_TILE, score="additive",
+              interpret=False)
+
+    def s(*shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lanes = (s(nnz_pad, dt=jnp.int32), s(nnz_pad, dt=jnp.int32))
+    terms = (s(n, GAT_HEADS, 1), s(n, GAT_HEADS, 1))
+    vals = s(n, GAT_HEADS, width)
+    keep = s(nnz_pad, GAT_HEADS)
+    stats = (s(n, GAT_HEADS), s(n, GAT_HEADS))
+
+    def fwd(r, c, q, k, v, kp):
+        return fused_sparse_attention(r, c, q, k, v, keep=kp, **kw)
+
+    def bwd(r, c, q, k, v, o, do, m, l, kp):
+        return fused_sparse_attention_bwd(r, c, q, k, v, o, do, m, l, keep=kp,
+                                          **kw)
+
+    for fn, shapes, name in (
+            (fwd, (*lanes, *terms, vals, keep), "fused_attention_fwd"),
+            (bwd, (*lanes, *terms, vals, vals, vals, *stats, keep),
+             "fused_attention_bwd")):
+        compiled = _compile(fn, *shapes)
+        _assert_kernel(compiled)
+        assert name in compiled.as_text()
+
+
+def test_gat_step_has_its_four_attention_launches(one_chip, monkeypatch):
+    """The GAT training cell's step (``bench/modes/train_gat.py``) at a cut
+    size, compiled for the described chip: exactly four Pallas launches,
+    each layer's fused attention forward and backward, named so, and no
+    interpreted kernel."""
+    import json
+
+    from bench import counts_gat
+    from bench.modes import train, train_gat
+    from bench.run import ROOT
+    from bench.traffic import gat as traffic
+    from bench.traffic import gcn
+    from repro.launch import backend
+
+    cfg = json.loads((ROOT / "bench" / "configs" / "gat-pubmed.json").read_text())
+    cfg.update(n_nodes=300, n_edges=700, n_entries=1700, n_features=40)
+    graph = gcn.config_graph(cfg)
+    with jax.default_matmul_precision(cfg["matmul_precision"]):
+        program = train_gat.build_program(cfg, graph)
+        inputs = traffic.make_inputs(cfg, graph, 7)
+        on_chip = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+        args = (inputs["params"], program["opt"].init(inputs["params"]),
+                *train.feed(inputs))
+        monkeypatch.setattr(backend, "pallas_interpret_default", lambda: False)
+        jax.clear_caches()
+        try:
+            hlo = _compile(program["step"], *on_chip(args)).as_text()
+        finally:
+            monkeypatch.undo()
+            jax.clear_caches()
+    launches = counts_gat.attn_launches(hlo)
+    assert sorted(lc["kernel"] for lc in launches) == sorted(
+        counts_gat.ATTN_KERNELS * 2)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 4
