@@ -151,6 +151,11 @@ def _spmm_csr_diff(a: CSR, b, sched: Schedule,
         dB        = Aᵀ · dz                    (Eq. 2d)
         dbias     = Σ_rows dz
         dresidual = dy
+
+    The forward saves its output ``y`` with the operands.  For a
+    float32 ReLU with no residual, over float32 value storage, ``y > 0``
+    is ``act'`` itself and the backward takes it from there; every
+    other epilogue recomputes ``A@B + bias`` (see :func:`_spmm_bwd`).
     """
     ep = sched.epilogue
     coo = a.tocoo()  # cached on the CSR instance
@@ -200,30 +205,53 @@ def _spmm_csr_diff(a: CSR, b, sched: Schedule,
         return run(vals, bb, bias_x, res_x)
 
     def _fwd(vals, bb, bias_x, res_x):
-        return run(vals, bb, bias_x, res_x), (vals, bb, bias_x, res_x)
+        out = run(vals, bb, bias_x, res_x)
+        # a narrow-storage forward is not the f32 function the backward
+        # differentiates, so its output cannot stand for the f32 one's
+        saved = out if sched.value_dtype is None else None
+        return out, (vals, bb, bias_x, res_x, saved)
 
     def _bwd(res, dout):
-        vals, bb, bias_x, res_x = res
+        vals, bb, bias_x, res_x, out = res
         return _spmm_bwd(ep, rows, cols, a.shape, vals, bb, bias_x, res_x,
-                         dout, dvals=True)
+                         dout, dvals=True, out=out)
 
     _fn.defvjp(_fwd, _bwd)
     return _fn(a.vals, b, bias, residual)
 
 
 def _spmm_bwd(ep: Epilogue, rows, cols, shape, vals, bb, bias_x, res_x,
-              dout, *, dvals: bool):
+              dout, *, dvals: bool, out=None):
     """The reference backward of ``y = act(A@B + bias) + residual`` over
     A's COO pattern, under the scope ``spmm.bwd`` with each part in a
     child scope of its own, so that a trace splits it.  Returns
     ``(dvals, dB, dbias, dresidual)``; ``dvals`` is None unless asked
-    for."""
+    for.
+
+    ``out`` is the forward's output ``y`` where the forward computed the
+    float32 function this differentiates (None otherwise).  Where it is
+    float32 and the epilogue is ReLU with no residual, ``y > 0`` exactly
+    where ``z = A@B + bias > 0``, so ``dz = dy`` there and 0 elsewhere:
+    a compare and a select in place of recomputing ``z`` (a gather and a
+    scatter over the nonzeros).  At an exact tie ``z == 0`` this gives
+    0 (PyTorch's convention), where ``jnp.maximum``'s gradient gives
+    0.5; at every other ``z`` the two are the same.  Every other epilogue (GELU, SiLU, tanh,
+    sigmoid, a residual, a narrowed output) recomputes ``z``.  Either
+    way the activation's derivative comes from the child scope
+    ``recompute``."""
     n_rows, n_cols = shape
     with jax.named_scope("spmm.bwd"):
         dout = dout.astype(jnp.float32)
         dres = dout.astype(res_x.dtype) if ep.residual else None
         dz = dout
-        if ep.activation is not None:
+        from_out = (out is not None and ep.activation == "relu"
+                    and not ep.residual and out.dtype == jnp.float32)
+        if from_out:
+            with jax.named_scope("recompute"):
+                live = out > 0
+            with jax.named_scope("act"):
+                dz = jnp.where(live, dout, 0.0)
+        elif ep.activation is not None:
             # recompute the pre-activation z through the oracle, then
             # pull dout back through the activation
             from ..core.schedule import ACTIVATIONS

@@ -9,6 +9,7 @@ fixture: only the worker that runs this file loads the TPU compiler.
 """
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -179,7 +180,8 @@ def test_gcn_step_launches_are_named(one_chip, monkeypatch):
     """The training cell's GCN step (``bench/modes/train.py``) at a cut
     size, compiled for the described chip: its two forward launches run
     ``spmm_eb``, named so in the compiled program, and the scopes the
-    program names do not hide them from ``bench.trace.pallas_launches``."""
+    program names do not hide them from ``bench.trace.pallas_launches``;
+    its backward scatters only in the two transpose SpMMs."""
     import json
 
     from bench import trace
@@ -210,6 +212,11 @@ def test_gcn_step_launches_are_named(one_chip, monkeypatch):
     launches = trace.pallas_launches(hlo)
     assert [(lc["kernel"], lc["backward"]) for lc in launches] == [("spmm_eb", False)] * 2
     assert all(lc["name"].startswith("spmm_eb") for lc in launches)
+    # the first launch's ReLU takes its derivative from the saved output:
+    # the backward's scatters are the two transpose SpMMs alone
+    bwd = [ln for ln in hlo.splitlines()
+           if " scatter(" in ln and re.search(r'op_name="[^"]*spmm\.bwd/', ln)]
+    assert len(bwd) == 2 and all("/spmm.bwd/tspmm/" in ln for ln in bwd)
 
 
 #: GAT-PubMed (Veličković et al.): 8 heads; value widths 8 (the first
