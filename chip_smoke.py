@@ -58,7 +58,7 @@ def log(msg: str) -> None:
 def device_check(chips: int):
     import jax
 
-    from repro.launch.backend import pallas_interpret_default
+    from repro.kernels.common import pallas_interpret_default
 
     devs = jax.devices()
     check(devs[0].platform == "tpu",

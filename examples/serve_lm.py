@@ -7,7 +7,7 @@ import numpy as np
 import jax
 
 from repro.configs import ARCHS, smoke_config
-from repro.models import get_model
+from repro.models.registry import get_model
 from repro.serve.engine import Request, ServeEngine
 
 cfg = smoke_config(ARCHS["qwen2-7b"]).scaled(d_model=128, n_layers=4)

@@ -13,7 +13,7 @@ import jax
 
 from repro.configs import ARCHS
 from repro.data.synthetic import ShardedTokenStream
-from repro.models import get_model
+from repro.models.registry import get_model
 from repro.train.optimizer import AdamW, cosine_schedule
 from repro.train.trainer import Trainer, TrainerConfig
 

@@ -1,7 +1,7 @@
 """Architecture catalog: the 10 assigned configs + reduced smoke variants."""
 from __future__ import annotations
 
-from .base import ModelConfig, ShapeConfig  # noqa: F401
+from .base import ModelConfig  # noqa: F401
 from .dbrx_132b import CONFIG as _dbrx
 from .deepseek_coder_33b import CONFIG as _deepseek
 from .hymba_1p5b import CONFIG as _hymba
@@ -9,8 +9,6 @@ from .mamba2_2p7b import CONFIG as _mamba2
 from .paligemma_3b import CONFIG as _paligemma
 from .qwen2_7b import CONFIG as _qwen2
 from .qwen3_moe_235b import CONFIG as _qwen3moe
-from .shapes import (SHAPES, batch_from_specs, cell_is_runnable,  # noqa: F401
-                     decode_specs, train_batch_specs)
 from .starcoder2_7b import CONFIG as _starcoder2
 from .whisper_large_v3 import CONFIG as _whisper
 from .yi_34b import CONFIG as _yi
@@ -20,12 +18,6 @@ ARCHS = {
     for c in [_starcoder2, _deepseek, _yi, _qwen2, _paligemma, _mamba2,
               _qwen3moe, _dbrx, _hymba, _whisper]
 }
-
-
-def get_config(name: str) -> ModelConfig:
-    if name not in ARCHS:
-        raise KeyError(f"unknown arch {name!r}; have {sorted(ARCHS)}")
-    return ARCHS[name]
 
 
 def smoke_config(cfg: ModelConfig) -> ModelConfig:

@@ -46,21 +46,16 @@ class ModelConfig:
     q_chunk: int = 512
     kv_chunk: int = 512
     # Decode cache via fori_loop carry + dynamic-update-slice instead of
-    # scan xs→ys (§Perf hillclimb): lets XLA forward the cache buffer
-    # in place rather than double-buffering old/new caches.
+    # scan xs→ys: lets XLA forward the cache buffer in place rather than
+    # double-buffering old/new caches.
     decode_inplace_cache: bool = False
-    # Megatron-style sequence parallelism for attention (§Perf hillclimb):
+    # Megatron-style sequence parallelism for attention:
     # residual stream + q seq-sharded over 'model', K/V all-gathered, FFN
     # all-gather/reduce-scatter inserted by GSPMD. Requires ambient mesh.
     seq_parallel_attn: bool = False
-    # cost-measurement knobs (see launch/dryrun._extrapolated_costs): XLA
-    # cost_analysis counts while bodies once, so measurement compiles
-    # unroll the layer/SSD scans and run attention single-chunk.
-    scan_unroll: bool = False
-    ssd_unroll: bool = False
     # when True the MoE dispatch path calls the Pallas grouped_matmul
-    # kernel (interpret mode on CPU); False keeps the einsum path that the
-    # XLA SPMD dry-run lowers. Math is identical (tested).
+    # kernel (interpret mode on CPU); False keeps the einsum path. Math is
+    # identical (tested).
     moe_pallas_dispatch: bool = False
 
     # ------------------------------------------------------------ derived
@@ -80,27 +75,6 @@ class ModelConfig:
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
 
-    @property
-    def sub_quadratic(self) -> bool:
-        """True when long_500k decode is runnable (SSM/hybrid state models)."""
-        return self.family in ("ssm", "hybrid")
-
-    @property
-    def has_decode(self) -> bool:
-        return True  # all assigned archs are decoder-bearing
-
     def scaled(self, **overrides) -> "ModelConfig":
         """Reduced copy for smoke tests."""
         return dataclasses.replace(self, **overrides)
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # train | prefill | decode
-
-    @property
-    def tokens(self) -> int:
-        return self.seq_len * self.global_batch

@@ -11,8 +11,8 @@ Given matrix statistics and the dense-column count N, pick an
 * segment strategy when writeback targets are runtime-dependent (high CV),
   parallel strategy when rows are long and regular.
 
-Also exposes :func:`predict_cost` — the cost model used here, by the
-§Perf hillclimb loop and by the empirical autotuner (``repro.tune``).
+Also exposes :func:`predict_cost` — the cost model used here and by the
+empirical autotuner (``repro.tune``).
 The model is a weighted sum of four raw terms (:func:`cost_terms`); the
 weights default to the hand-set napkin values but are *calibratable*:
 ``repro.tune.calibrate`` least-squares fits them against measured

@@ -14,7 +14,7 @@ single-pod. Policy (Megatron-style TP + DP, see DESIGN.md §5):
 * batch over ``(pod, data)``; decode KV caches shard *sequence* over
   ``model`` (online-softmax combines become small all-reduces);
 * any proposed sharded dim that does not divide its mesh axis falls back
-  to replication for that dim (logged by the dry-run).
+  to replication for that dim.
 """
 from __future__ import annotations
 
@@ -70,7 +70,7 @@ def _param_spec(path: str, ndim: int) -> P:
     # the score tensors — measured 22 GB/layer on qwen2). The weights are
     # FSDP-sharded over the data axes (d_model dim) so the 33B dense
     # models fit HBM; XLA inserts the per-layer all-gather. Seq-parallel
-    # attention is the §Perf hillclimb.
+    # attention is ``ModelConfig.seq_parallel_attn``.
     if "attn/" in path:
         if path.endswith("/w"):
             return pad([DATA, None])
@@ -113,15 +113,6 @@ def param_shardings(mesh, params_shape):
     return jax.tree_util.tree_map_with_path(rule, params_shape)
 
 
-def batch_shardings(mesh, specs: dict):
-    dp = data_axes(mesh)
-    out = {}
-    for name, leaf in specs.items():
-        spec = P(dp, *([None] * (len(leaf.shape) - 1)))
-        out[name] = NamedSharding(mesh, _fit(mesh, spec, leaf.shape))
-    return out
-
-
 def cache_shardings(mesh, cfg, cache_shape):
     """Serve-cache shardings. KV caches (L, B, S, K, dh): batch over data
     axes, sequence over model. SSM state (L, B, H, N, P): heads over model.
@@ -147,7 +138,3 @@ def cache_shardings(mesh, cfg, cache_shape):
         return NamedSharding(mesh, _fit(mesh, spec, leaf.shape))
 
     return jax.tree_util.tree_map_with_path(rule, cache_shape)
-
-
-def replicated(mesh):
-    return NamedSharding(mesh, P())

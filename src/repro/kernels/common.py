@@ -32,9 +32,10 @@ Strategy variants:
   'accumulate'  per-lane RMW (the atomicAdd baseline).
 
 Every Pallas launch of the package goes through :func:`pallas_call`, the
-one place that decides compiled (TPU) vs interpreted (elsewhere), asks
-the compiler for the VMEM the kernel's blocks need (:func:`vmem_bytes`)
-and names the launch after its kernel.
+one place that decides compiled (TPU) vs interpreted (elsewhere,
+:func:`pallas_interpret_default`), asks the compiler for the VMEM the
+kernel's blocks need (:func:`vmem_bytes`) and names the launch after its
+kernel.
 
 ``apply_epilogue`` is the shared last-grid-step epilogue applier
 (``core.Epilogue``): bias / activation / residual / dtype cast fused
@@ -115,13 +116,21 @@ def block_buffers(n_blocks: int) -> int:
     return 1 if n_blocks == 1 else 2
 
 
+def pallas_interpret_default() -> bool:
+    """Whether Pallas kernels run interpreted on this backend: True
+    off-TPU (interpret mode is the only Pallas path on CPU), False on TPU
+    hardware, where every kernel runs compiled."""
+    return jax.default_backend() != "tpu"
+
+
 def pallas_call(kernel, *, name: str, vmem_need: int | None = None,
                 interpret=None, **kw):
     """``pl.pallas_call`` with the one compiled-vs-interpreted decision
     of the kernels package: ``interpret=None`` follows the backend
-    (``launch.backend.pallas_interpret_default``: compiled on a TPU,
-    interpreted elsewhere).  Asking for the interpreter on a TPU raises —
-    a kernel that cannot lower fails there, it never falls back.
+    (:func:`pallas_interpret_default`, looked up at every call: compiled
+    on a TPU, interpreted elsewhere).  Asking for the interpreter on a
+    TPU raises — a kernel that cannot lower fails there, it never falls
+    back.
 
     ``name`` is the kernel's stable name, the one its launches carry in
     the compiled program and in a profiler trace; every launch has one.
@@ -132,8 +141,6 @@ def pallas_call(kernel, *, name: str, vmem_need: int | None = None,
     chip's :data:`VMEM_CAPACITY`; a kernel whose blocks exceed the cap
     is refused by the compiler.  Without it the compiler's default
     scoped limit holds."""
-    from ..launch.backend import pallas_interpret_default
-
     default = pallas_interpret_default()
     if interpret is None:
         interpret = default

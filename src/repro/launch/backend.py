@@ -1,7 +1,7 @@
 """Backend setup helpers (platform, compile cache, precision defaults).
 
 The Pallas kernels run compiled on a TPU and interpreted everywhere else
-(:func:`pallas_interpret_default`, which ``kernels.common.pallas_call``
+(``kernels.common.pallas_interpret_default``, which ``pallas_call``
 consults at every launch).  The setup helpers only take effect when
 called *before* jax initializes its backends (first device query / first
 trace), which is why none of them are called at import time anywhere in
@@ -20,7 +20,6 @@ from pathlib import Path
 __all__ = [
     "backend_info",
     "enable_x64",
-    "pallas_interpret_default",
     "set_host_device_count",
     "set_platform",
     "setup",
@@ -44,9 +43,8 @@ def set_platform(platform: str = "cpu") -> None:
 
 def set_host_device_count(n: int) -> None:
     """Force ``n`` host (CPU) devices via XLA_FLAGS — the multi-device test
-    lane's mechanism (``launch/dryrun.py`` idiom).  Must run before the
-    first jax import in the process to take effect; appending here keeps
-    other flags intact."""
+    lane's mechanism.  Must run before the first jax import in the
+    process to take effect; appending here keeps other flags intact."""
     n = int(n)
     if n < 1:
         raise ValueError(f"need n >= 1, got {n}")
@@ -63,15 +61,6 @@ def enable_x64(on: bool = True) -> None:
     import jax
 
     jax.config.update("jax_enable_x64", bool(on))
-
-
-def pallas_interpret_default() -> bool:
-    """Whether Pallas kernels run interpreted on this backend: True
-    off-TPU (interpret mode is the only Pallas path on CPU), False on TPU
-    hardware, where every kernel runs compiled."""
-    import jax
-
-    return jax.default_backend() != "tpu"
 
 
 def setup(platform: str | None = None, *, host_devices: int | None = None,
@@ -105,6 +94,7 @@ def backend_info() -> dict:
     import jax
 
     from ..core.dtypes import fp8_supported
+    from ..kernels.common import pallas_interpret_default
 
     devs = jax.devices()
     return {

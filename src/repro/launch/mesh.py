@@ -1,29 +1,11 @@
-"""Production mesh builders.
+"""The reduction mesh.
 
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so
-importing this module never touches jax device state. The dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; smoke tests and benches see the real single CPU device.
+A function, not a module-level constant, so importing this module never
+touches jax device state.
 """
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(
-        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def make_local_mesh(model_parallel: int = 1):
-    """Mesh over whatever devices exist (tests / examples)."""
-    n = len(jax.devices())
-    assert n % model_parallel == 0
-    return jax.make_mesh(
-        (n // model_parallel, model_parallel), ("data", "model"),
-        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def make_reduction_mesh(axis_size: int | None = None, *,

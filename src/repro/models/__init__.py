@@ -1,1 +1,2 @@
-from .registry import ModelApi, get_model  # noqa: F401
+"""Model layers: the GCN/GAT layers (``layers``), attention and MoE blocks,
+and the language-model families behind ``registry.get_model``."""

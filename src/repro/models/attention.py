@@ -3,8 +3,7 @@
 ``flash_attention`` never materializes the (Sq × Skv) score matrix: forward
 runs a scan over KV chunks with online softmax; backward recomputes
 probabilities per chunk from the saved (o, lse) — O(S·D) residual memory
-instead of O(S²). This is what keeps prefill_32k / train_4k inside HBM on
-the dry-run meshes.
+instead of O(S²).
 
 Layout: q (B, Sq, H, Dh), k/v (B, Skv, K, Dh) with H = K·G (GQA).
 Internally (B, K, G, S, Dh).

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 from .attention import decode_attention, flash_attention
 from .layers import (apply_dense, apply_mlp, apply_norm, embed,
-                     init_embedding, init_mlp, init_norm, layer_scan,
+                     init_embedding, init_mlp, init_norm,
                      lm_loss_from_features, unembed)
 from .transformer import init_attn
 
@@ -84,7 +84,7 @@ def encode(cfg, params, frames):
     def step(x, p_l):
         return layer(p_l, x), None
 
-    x, _ = layer_scan(cfg, step, x, params["enc_layers"])
+    x, _ = jax.lax.scan(step, x, params["enc_layers"])
     return apply_norm(cfg, params["enc_norm"], x)
 
 
@@ -106,7 +106,7 @@ def decode_train(cfg, params, tokens, enc_out):
     def step(x, p_l):
         return layer(p_l, x), None
 
-    x, _ = layer_scan(cfg, step, x, params["dec_layers"])
+    x, _ = jax.lax.scan(step, x, params["dec_layers"])
     return apply_norm(cfg, params["final_norm"], x)
 
 
@@ -164,7 +164,7 @@ def prefill(cfg, params, batch, max_len, ctx=None):
         x = x + apply_mlp(cfg, p_l["mlp"], apply_norm(cfg, p_l["ln2"], x))
         return x, (k, v, ck, cv)
 
-    x, (ks, vs, cks, cvs) = layer_scan(cfg, step, x, params["dec_layers"])
+    x, (ks, vs, cks, cvs) = jax.lax.scan(step, x, params["dec_layers"])
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(params["embed"], x[:, -1])
     pad = max_len - s
@@ -207,8 +207,8 @@ def decode_step(cfg, params, cache, tokens, ctx=None):
         x = x + apply_mlp(cfg, p_l["mlp"], apply_norm(cfg, p_l["ln2"], x))
         return x, (k_c, v_c)
 
-    x, (ks, vs) = layer_scan(
-        cfg, step, x, (params["dec_layers"], cache["k"], cache["v"],
+    x, (ks, vs) = jax.lax.scan(
+        step, x, (params["dec_layers"], cache["k"], cache["v"],
                        cache["ck"], cache["cv"]))
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(params["embed"], x[:, 0])

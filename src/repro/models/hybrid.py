@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 from .attention import decode_attention
 from .layers import (apply_dense, apply_mlp, apply_norm, embed,
-                     init_embedding, init_mlp, init_norm, layer_scan,
+                     init_embedding, init_mlp, init_norm,
                      lm_loss_from_features, rmsnorm, unembed)
 from .mamba2 import init_mixer, init_mixer_cache, mixer_decode, mixer_fwd
 from .transformer import _qkv, attn_block, init_attn
@@ -69,7 +69,7 @@ def forward_features(cfg, params, tokens, ctx=None):
     def step(x, p_l):
         return layer(p_l, x), None
 
-    x, _ = layer_scan(cfg, step, x, params["layers"])
+    x, _ = jax.lax.scan(step, x, params["layers"])
     x = apply_norm(cfg, params["final_norm"], x)
     return x
 
@@ -112,7 +112,7 @@ def prefill(cfg, params, tokens, max_len, ctx=None):
         x = x + apply_mlp(cfg, p_l["mlp"], apply_norm(cfg, p_l["ln2"], x))
         return x, (k, v, st)
 
-    x, (ks, vs, states) = layer_scan(cfg, step, x, params["layers"])
+    x, (ks, vs, states) = jax.lax.scan(step, x, params["layers"])
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(params["embed"], x[:, -1])
     pad = max_len - s
@@ -145,8 +145,8 @@ def decode_step(cfg, params, cache, tokens, ctx=None):
         x = x + apply_mlp(cfg, p_l["mlp"], apply_norm(cfg, p_l["ln2"], x))
         return x, (k_c, v_c, new_mix)
 
-    x, (ks, vs, mixs) = layer_scan(
-        cfg, step, x, (params["layers"], cache["k"], cache["v"], cache["mixer"]))
+    x, (ks, vs, mixs) = jax.lax.scan(
+        step, x, (params["layers"], cache["k"], cache["v"], cache["mixer"]))
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(params["embed"], x[:, 0])
     return logits, {"k": ks, "v": vs, "mixer": mixs, "pos": pos + 1}

@@ -5,14 +5,6 @@ import jax
 import jax.numpy as jnp
 
 
-def layer_scan(cfg, step, init, xs):
-    """scan over stacked layers; unrolls when cfg.scan_unroll (so the
-    dry-run cost-measurement compiles count every layer — XLA cost
-    analysis counts while bodies exactly once)."""
-    unroll = cfg.n_layers if cfg.scan_unroll else 1
-    return jax.lax.scan(step, init, xs, unroll=unroll)
-
-
 def seq_shard(cfg, x, axis: int = 1):
     """Megatron-SP constraint: pin the sequence dim to the 'model' mesh
     axis (no-op unless cfg.seq_parallel_attn; requires an ambient mesh)."""

@@ -63,8 +63,7 @@ def _conv1d_causal(x, w, b):
     return (out + b.astype(jnp.float32)).astype(x.dtype)
 
 
-def ssd_chunked(x, dt, a, b_in, c_in, chunk, d_skip, init_state=None,
-                unroll: bool = False):
+def ssd_chunked(x, dt, a, b_in, c_in, chunk, d_skip, init_state=None):
     """Chunked SSD scan.
 
     x: (B, S, H, P); dt: (B, S, H) (already softplus'd); a: (H,) negative;
@@ -118,8 +117,7 @@ def ssd_chunked(x, dt, a, b_in, c_in, chunk, d_skip, init_state=None,
             if init_state is None else init_state.astype(jnp.float32))
     final, prev = jax.lax.scan(
         scan_fn, init,
-        (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)),
-        unroll=nc if unroll else 1)
+        (jnp.moveaxis(states, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
     prev = jnp.moveaxis(prev, 0, 1)  # (B,nc,H,N,P) state before each chunk
 
     y_off = jnp.einsum("bnqhe,bnhep,bnqh->bnqhp", ch, prev, jnp.exp(seg))
@@ -154,7 +152,7 @@ def mixer_fwd(cfg, p, x, init_state=None, return_state=False):
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["A_log"])
     y, final = ssd_chunked(xh, dt, a, b_in, c_in, cfg.ssm_chunk, p["D"],
-                           init_state, unroll=cfg.ssd_unroll)
+                           init_state)
     y = y.reshape(bs, s, cfg.d_inner)
     y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
                 p["norm"])

@@ -7,8 +7,8 @@ padding), grouped GEMM, and scatter-add + psum writeback — the
 segment-group machinery at the collective level.
 
 Two execution paths with identical math:
-  * einsum path — what the SPMD dry-run lowers (flop-accurate grouped GEMM
-    per local expert);
+  * einsum path — the SPMD path (flop-accurate grouped GEMM per local
+    expert);
   * Pallas path — ``kernels.grouped_matmul`` on the capacity-gathered
     tokens (validated in tests, CPU-interpret).
 
@@ -252,10 +252,7 @@ def balanced_expert_lengths(cfg, t_tokens: int):
 def skewed_expert_lengths(cfg, t_tokens: int, *, a: float = 1.5,
                           seed: int = 0):
     """A Zipf-skewed routing histogram — the representative hot-expert
-    workload both ``launch.hillclimb --moe`` and the
-    ``beyond/moe_tuner_gap`` benchmark tune (one definition, so the
-    offline cache-population tool and the tracked benchmark stay on the
-    same cells)."""
+    workload the ``beyond/moe_tuner_gap`` benchmark tunes."""
     import numpy as np
 
     rng = np.random.default_rng(seed)

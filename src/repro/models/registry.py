@@ -1,7 +1,7 @@
 """Model registry: family -> (init, loss, prefill, decode_step, init_cache).
 
-All entries share the same functional API so the trainer / server / dry-run
-are family-agnostic:
+All entries share the same functional API so the trainer and server are
+family-agnostic:
 
     api = get_model(cfg)
     params = api.init(key)

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from .layers import (apply_norm, embed, init_embedding, init_norm,
-                     layer_scan, lm_loss_from_features, unembed)
+                     lm_loss_from_features, unembed)
 from .mamba2 import (init_mixer, init_mixer_cache, mixer_decode, mixer_fwd)
 
 
@@ -39,7 +39,7 @@ def forward_features(cfg, params, tokens, ctx=None):
     def step(x, p_l):
         return layer(p_l, x), None
 
-    x, _ = layer_scan(cfg, step, x, params["layers"])
+    x, _ = jax.lax.scan(step, x, params["layers"])
     x = apply_norm(cfg, params["final_norm"], x)
     return x
 
@@ -74,7 +74,7 @@ def prefill(cfg, params, tokens, max_len, ctx=None):
         out, st = mixer_fwd(cfg, p_l["mixer"], h, return_state=True)
         return x + out, st
 
-    x, states = layer_scan(cfg, step, x, params["layers"])
+    x, states = jax.lax.scan(step, x, params["layers"])
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(params["embed"], x[:, -1])
     return logits, {"layers": states,
@@ -91,8 +91,8 @@ def decode_step(cfg, params, cache, tokens, ctx=None):
         out, new_cache = mixer_decode(cfg, p_l["mixer"], cache_l, h)
         return x + out, new_cache
 
-    x, new_layers = layer_scan(cfg, step, x, (params["layers"],
-                                              cache["layers"]))
+    x, new_layers = jax.lax.scan(step, x, (params["layers"],
+                                           cache["layers"]))
     x = apply_norm(cfg, params["final_norm"], x)
     return unembed(params["embed"], x), {"layers": new_layers,
                                          "pos": cache["pos"] + 1}

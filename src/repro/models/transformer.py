@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from .attention import decode_attention, flash_attention
 from .layers import (apply_dense, apply_mlp, apply_norm, apply_rope,
                      embed, init_dense, init_embedding,
-                     init_mlp, init_norm, layer_scan, lm_loss_from_features,
+                     init_mlp, init_norm, lm_loss_from_features,
                      rmsnorm, seq_shard, seq_unshard, unembed)
 from .moe import apply_moe, init_moe
 
@@ -136,7 +136,7 @@ def forward_features(cfg, params, tokens, ctx=None, inputs_embeds=None):
         x, aux = layer(p_l, x, positions)
         return x, aux
 
-    x, auxs = layer_scan(cfg, step, x, params["layers"])
+    x, auxs = jax.lax.scan(step, x, params["layers"])
     x = apply_norm(cfg, params["final_norm"], x)
     # under SP, hand the loss head an unsharded-seq tensor: the uneven
     # x[:, :-1] slice of a seq-sharded dim miscomputes the embed grad
@@ -183,7 +183,7 @@ def prefill(cfg, params, tokens, max_len, ctx=None, inputs_embeds=None):
         f, _ = ffn_block(cfg, p_l, apply_norm(cfg, p_l["ln2"], x), ctx)
         return x + f, (k, v)
 
-    x, (ks, vs) = layer_scan(cfg, step, x, params["layers"])
+    x, (ks, vs) = jax.lax.scan(step, x, params["layers"])
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(params["embed"], x[:, -1])
     pad = max_len - s
@@ -218,8 +218,8 @@ def decode_step(cfg, params, cache, tokens, ctx=None):
         f, _ = ffn_block(cfg, p_l, apply_norm(cfg, p_l["ln2"], x), ctx)
         return x + f, (k_c, v_c)
 
-    x, (ks, vs) = layer_scan(cfg, step, x, (params["layers"], cache["k"],
-                                            cache["v"]))
+    x, (ks, vs) = jax.lax.scan(step, x, (params["layers"], cache["k"],
+                                         cache["v"]))
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(params["embed"], x[:, 0])
     return logits, {"k": ks, "v": vs, "pos": pos + 1}
@@ -259,8 +259,7 @@ def _decode_step_inplace(cfg, params, cache, tokens, ctx=None):
         return (x + f, kc, vc)
 
     x, kc, vc = jax.lax.fori_loop(
-        0, cfg.n_layers, body, (x, cache["k"], cache["v"]),
-        unroll=cfg.n_layers if cfg.scan_unroll else 1)
+        0, cfg.n_layers, body, (x, cache["k"], cache["v"]))
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(params["embed"], x[:, 0])
     return logits, {"k": kc, "v": vc, "pos": pos + 1}

@@ -1,24 +1,17 @@
-"""Roofline analysis from compiled dry-run artifacts.
+"""Byte models of the sparse ops, and the collective bytes of a compiled
+module to check them against.
 
-Three terms per (arch × shape × mesh), per the assignment:
-
-    compute   = HLO_FLOPs_per_chip / peak_FLOPs_per_chip
-    memory    = HLO_bytes_per_chip / HBM_bw
-    collective= collective_bytes_per_chip / ICI_link_bw
-
-``compiled.cost_analysis()`` operates on the post-SPMD per-device module,
-so its flops/bytes are already per chip. Collective bytes are parsed from
-the compiled HLO text (result-shape bytes of every all-reduce /
-all-gather / reduce-scatter / all-to-all / collective-permute).
+``predict_spmm_arg_bytes`` / ``predict_spmm_traffic_bytes`` model an EB
+SpMM's argument footprint and HBM traffic; ``predict_collective_bytes``
+/ ``predict_attention_collective_bytes`` the collective result bytes a
+distributed reduction should compile to.  :func:`collective_bytes`
+parses those from the compiled (per-device) HLO text: result-shape
+bytes of every all-reduce / all-gather / reduce-scatter / all-to-all /
+collective-permute.
 """
 from __future__ import annotations
 
 import re
-
-# TPU v5e-like hardware constants (assignment spec)
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s/link
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3": 1,
@@ -168,118 +161,3 @@ def predict_attention_collective_bytes(collective, *, n_heads: int,
     elif collective != "nnz_ar":
         raise ValueError(f"unknown collective {collective!r}")
     return stats + lw_acc
-
-
-def extract_costs(compiled) -> dict:
-    """Raw per-chip cost numbers from one compiled module. NOTE: XLA cost
-    analysis counts a while/scan body ONCE (not × trip count); callers that
-    scan over layers must extrapolate (see dryrun._extrapolated_costs)."""
-    ca = compiled.cost_analysis() or {}
-    colls = collective_bytes(compiled.as_text())
-    return {
-        "flops": float(ca.get("flops", 0.0)),
-        "bytes": float(ca.get("bytes accessed", 0.0)),
-        "coll_bytes": float(sum(v["bytes"] for v in colls.values())),
-        "collectives": colls,
-    }
-
-
-def combine_costs(base: dict, body: dict, n_extra: int) -> dict:
-    """total = base + n_extra * body (elementwise, incl. per-op colls)."""
-    out = {
-        "flops": base["flops"] + n_extra * body["flops"],
-        "bytes": base["bytes"] + n_extra * body["bytes"],
-        "coll_bytes": base["coll_bytes"] + n_extra * body["coll_bytes"],
-    }
-    colls = {}
-    ops = set(base["collectives"]) | set(body["collectives"])
-    for op in ops:
-        b = base["collectives"].get(op, {"count": 0, "bytes": 0})
-        d = body["collectives"].get(op, {"count": 0, "bytes": 0})
-        colls[op] = {"count": b["count"] + n_extra * d["count"],
-                     "bytes": b["bytes"] + n_extra * d["bytes"]}
-    out["collectives"] = colls
-    return out
-
-
-def analyze(costs: dict, ma, *, n_chips: int, kind: str, tokens: int,
-            n_params: int, n_active_params: int) -> dict:
-    colls = costs["collectives"]
-    coll_b = costs["coll_bytes"]
-    flops = costs["flops"]
-    bytes_acc = costs["bytes"]
-
-    compute_t = flops / PEAK_FLOPS
-    memory_t = bytes_acc / HBM_BW
-    coll_t = coll_b / ICI_BW
-    terms = {"compute": compute_t, "memory": memory_t, "collective": coll_t}
-    dominant = max(terms, key=terms.get)
-
-    flops_factor = {"train": 6, "prefill": 2, "decode": 2}[kind]
-    model_flops = flops_factor * n_active_params * tokens
-    hlo_flops_global = flops * n_chips
-    useful = model_flops / hlo_flops_global if hlo_flops_global else 0.0
-
-    bound = max(terms.values())
-    # fraction of roofline = time the hardware MUST spend / modelled step
-    # time (the dominant term). Decode is bandwidth-bound by construction:
-    # its floor is one pass over params + KV state (the arg bytes), not a
-    # flop count.
-    if kind == "decode" and ma is not None:
-        floor = ma.argument_size_in_bytes / HBM_BW
-    else:
-        floor = model_flops / n_chips / PEAK_FLOPS
-    roofline_frac = floor / bound if bound else 0.0
-
-    return {
-        "per_chip": {
-            "hlo_flops": flops,
-            "hlo_bytes": bytes_acc,
-            "collective_bytes": coll_b,
-            "temp_bytes": ma.temp_size_in_bytes if ma else None,
-            "arg_bytes": ma.argument_size_in_bytes if ma else None,
-            "out_bytes": ma.output_size_in_bytes if ma else None,
-        },
-        "collectives": colls,
-        "terms_s": terms,
-        "dominant": dominant,
-        "model_flops_global": model_flops,
-        "n_params": n_params,
-        "n_active_params": n_active_params,
-        "useful_flops_ratio": useful,
-        "roofline_fraction": roofline_frac,
-        "tokens": tokens,
-        "kind": kind,
-        "n_chips": n_chips,
-    }
-
-
-def count_params(params_shape) -> int:
-    import jax
-
-    return int(sum(
-        __import__("math").prod(x.shape) for x in jax.tree.leaves(params_shape)))
-
-
-def count_active_params(params_shape, cfg) -> int:
-    """Active params per token: MoE experts count top_k/E; rest full."""
-    import math as _m
-
-    import jax
-
-    total = 0
-    expert = 0
-
-    def visit(path, leaf):
-        nonlocal total, expert
-        n = _m.prod(leaf.shape)
-        p = "/".join(str(getattr(k, "key", k)) for k in path)
-        total += n
-        if "moe/w" in p:
-            expert += n
-
-    jax.tree_util.tree_map_with_path(visit, params_shape)
-    if cfg.family == "moe" and cfg.n_experts:
-        frac = cfg.experts_per_token / cfg.n_experts
-        return int(total - expert + expert * frac)
-    return total

@@ -1,15 +1,14 @@
 """Batched serving engine: continuous batching over a fixed-slot KV cache.
 
 Slots hold independent sequences; ``step`` decodes one token for every
-active slot with a single jit'd serve_step (the decode path the dry-run
-lowers). Finished slots are refilled from the request queue via per-slot
+active slot with a single jit'd serve_step. Finished slots are refilled from the request queue via per-slot
 prefill; greedy or temperature sampling.
 
 Sparse side-channel workloads (retrieval adapters, graph features, MoE
 routing tables) go through :meth:`ServeEngine.spmm`, which resolves the
 schedule from the persistent tuner cache (``repro.tune``) — tuning
 happens ahead of time via :meth:`ServeEngine.prepare_sparse` (or
-``launch.hillclimb --spmm``); the request path itself *never* runs a
+``repro.tune.tune_schedule``); the request path itself *never* runs a
 measurement.
 """
 from __future__ import annotations
@@ -145,7 +144,7 @@ class ServeEngine:
         then the persistent tuner cache, else the static selector —
         never from an inline measurement (requests must not stall on a
         tuning run).  Cache misses are not memoized, so tuning done
-        later (``hillclimb --spmm``, another engine's ``prepare_sparse``)
+        later (``tune_schedule``, another engine's ``prepare_sparse``)
         is picked up on the next call.  Non-CSR operands have no
         fingerprint; they fall through to the library default, matching
         ``repro.sparse.spmm(..., schedule="auto")``."""

@@ -402,8 +402,8 @@ def moe_cached_or_default(
 ) -> MoeDispatchSchedule:
     """Cache-hit dispatch schedule if one exists, else the static
     default — **never measures** (the serving-path resolver; tune ahead
-    of time with :func:`tune_moe_dispatch`, ``ServeEngine.prepare_moe``
-    or ``launch.hillclimb --moe``).  ``allow_capacity_shrink`` and
+    of time with :func:`tune_moe_dispatch`, e.g. through
+    ``ServeEngine.prepare_moe``).  ``allow_capacity_shrink`` and
     ``max_tokens`` must match the tuning call — they select which
     record to replay."""
     if cache is None:

@@ -213,9 +213,9 @@ def cached_or_auto(csr, n_dense_cols: int, *,
                    key: Optional[str] = None) -> Schedule:
     """Cache-hit schedule if one exists, else the static selector's pick —
     **never measures**.  This is the serving-path resolver: a latency-
-    sensitive loop consults tuning done ahead of time (e.g. by
-    ``ServeEngine.prepare_sparse`` or ``launch.hillclimb --spmm``) and
-    must not stall a request on a tuning run."""
+    sensitive loop consults tuning done ahead of time (by
+    :func:`tune_schedule`, e.g. through ``ServeEngine.prepare_sparse``)
+    and must not stall a request on a tuning run."""
     if cache is None:
         cache = default_cache(backend)
     rec = cache.get(key if key is not None

@@ -79,7 +79,7 @@ SEQ_SHARDED_DECODE = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ARCHS, smoke_config
-from repro.models import get_model
+from repro.models.registry import get_model
 from repro.distributed.sharding import cache_shardings, param_shardings
 
 mesh = jax.make_mesh((2, 4), ("data", "model"),
@@ -127,7 +127,7 @@ def test_seq_sharded_kv_decode_matches_single():
 SEQ_PARALLEL_ATTENTION = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import ARCHS, smoke_config
-from repro.models import get_model
+from repro.models.registry import get_model
 
 mesh = jax.make_mesh((2, 4), ("data", "model"),
                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
@@ -163,7 +163,7 @@ ELASTIC_REMESH = """
 import jax, jax.numpy as jnp, numpy as np, tempfile
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ARCHS, smoke_config
-from repro.models import get_model
+from repro.models.registry import get_model
 from repro.checkpoint.manager import CheckpointManager
 from repro.train.optimizer import AdamW, constant_schedule
 from repro.train.train_step import init_state, make_train_step

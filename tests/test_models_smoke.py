@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, smoke_config
-from repro.models import get_model
+from repro.models.registry import get_model
 
 B, S = 2, 32
 MAXLEN = 48
@@ -96,7 +96,7 @@ def test_decode_inplace_matches_scan(built):
     tok = jnp.zeros((B,), jnp.int32)
     want, cache_w = api.decode_step(params, cache, tok)
     cfg2 = dataclasses.replace(cfg, decode_inplace_cache=True)
-    from repro.models import get_model as _gm
+    from repro.models.registry import get_model as _gm
 
     api2 = _gm(cfg2)
     got, cache_g = api2.decode_step(params, cache, tok)

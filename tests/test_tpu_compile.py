@@ -188,7 +188,6 @@ def test_gcn_step_launches_are_named(one_chip, monkeypatch):
     from bench.modes import train
     from bench.run import ROOT
     from bench.traffic import gcn as traffic
-    from repro.launch import backend
 
     cfg = json.loads((ROOT / "bench" / "configs" / "gcn-pubmed.json").read_text())
     cfg.update(n_nodes=300, n_edges=700, n_entries=1700, n_features=40)
@@ -202,7 +201,7 @@ def test_gcn_step_launches_are_named(one_chip, monkeypatch):
                 *train.feed(inputs))
         # the eager forward above ran interpreted; the step's kernels
         # are traced again, for the chip
-        monkeypatch.setattr(backend, "pallas_interpret_default", lambda: False)
+        monkeypatch.setattr(common, "pallas_interpret_default", lambda: False)
         jax.clear_caches()
         try:
             hlo = _compile(program["step"], *on_chip(args)).as_text()
@@ -276,7 +275,6 @@ def test_gat_step_has_its_four_attention_launches(one_chip, monkeypatch):
     from bench.run import ROOT
     from bench.traffic import gat as traffic
     from bench.traffic import gcn
-    from repro.launch import backend
 
     cfg = json.loads((ROOT / "bench" / "configs" / "gat-pubmed.json").read_text())
     cfg.update(n_nodes=300, n_edges=700, n_entries=1700, n_features=40)
@@ -288,7 +286,7 @@ def test_gat_step_has_its_four_attention_launches(one_chip, monkeypatch):
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
         args = (inputs["params"], program["opt"].init(inputs["params"]),
                 *train.feed(inputs))
-        monkeypatch.setattr(backend, "pallas_interpret_default", lambda: False)
+        monkeypatch.setattr(common, "pallas_interpret_default", lambda: False)
         jax.clear_caches()
         try:
             hlo = _compile(program["step"], *on_chip(args)).as_text()

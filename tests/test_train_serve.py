@@ -6,7 +6,7 @@ import pytest
 
 from repro.configs import ARCHS, smoke_config
 from repro.data.synthetic import ShardedTokenStream
-from repro.models import get_model
+from repro.models.registry import get_model
 from repro.serve.engine import Request, ServeEngine
 from repro.train.optimizer import AdamW, constant_schedule
 from repro.train.train_step import init_state, make_train_step
