@@ -24,14 +24,24 @@ their heights padded to a sublane tile.  The pattern's row and column
 ids travel as (1, T) lane blocks in SMEM, read one scalar a lane; the
 row ids once more as a VMEM lane block for the window products.
 
-**Forward** (grid (2, nnz_tiles)): phase 0 gathers Q[r] and K[c] a lane
-at a time into (T, ·) scratch rows, computes the scores and scatters
-their row maximum (the max monoid, the registry's 'accumulate'
-realization: one read-modify-write a lane); phase 1 gathers again with
-V[c] and the finished max m[r], forms p = exp(e - m) and scatter-adds
-``[p·keep ⊗ V | p]`` by row into one accumulator: the weighted values
-and the denominator l in one reduction ('segment': MXU window products,
-``common.window_reduce_scatter``).  The caller divides by l.
+**Forward.**  The softmax is the same for any per-row shift m that keeps
+exp(e - m) from overflowing.  Given a shift table (grid (nnz_tiles,)),
+the launch is one pass: each lane gathers Q[r], K[c], V[c] and m[r] a
+lane at a time into (T, ·) scratch rows, forms p = exp(e - m) and
+scatter-adds ``[p·keep ⊗ V | p]`` by row into one accumulator: the
+weighted values and the denominator l in one reduction ('segment': MXU
+window products, ``common.window_reduce_scatter``).  The additive score
+takes its shift from a bound (:func:`additive_shift`, in XLA): LeakyReLU
+is increasing and 1-Lipschitz, so LeakyReLU(q_i + max k + max bias) lies
+above every score of row i and at most the spread of k and bias above
+its largest; where a head's spread passes :data:`SHIFT_SPREAD` (60), or
+is not finite, the exact row maximum is taken instead.  Without a shift
+(the dot score) the grid is (2, nnz_tiles): phase 0 gathers Q[r] and
+K[c], computes the scores and scatters their row maximum (the max
+monoid, the registry's 'accumulate' realization: one read-modify-write
+a lane), and phase 1 is the pass above with the finished max.  The
+caller divides by l.  Either way the forward returns the (m, l) it
+used, so the backward's w = exp(e - m)/l is the forward's weight.
 
 **Backward** (grid (nnz_tiles,)): one pass.  The softmax backward's row
 dot is δ[r] = Σ_t w·dw = <dout[r], out[r]> (the forward's output), which
@@ -70,6 +80,8 @@ from .common import (
 __all__ = [
     "NEG_INF",
     "SCORES",
+    "SHIFT_SPREAD",
+    "additive_shift",
     "fused_sparse_attention",
     "fused_sparse_attention_bwd",
     "sparse_attention_bwd_ref",
@@ -304,18 +316,74 @@ def _check(rows, q, k, v, nnz_tile, score, strategy):
 
 
 # ---------------------------------------------------------------------------
+# The additive score's softmax shift
+# ---------------------------------------------------------------------------
+
+#: the largest per-head spread (k_max - k_min) + (b_max - b_min) for which
+#: the bound below may stand in for the row max.  LeakyReLU is increasing
+#: and 1-Lipschitz, so LeakyReLU(q_i + k_max + b_max) bounds every score of
+#: row i from above and sits at most the spread above the row's largest
+#: score.  At a spread of 60 that term is at least e^-60 ≈ 8.8e-27, a
+#: normal float32, and a term is lost only where its weight relative to
+#: the row's largest is below e^-27 ≈ 1.9e-12, far under float32's
+#: rounding of the sum.
+SHIFT_SPREAD = 60.0
+
+
+def additive_shift(rows, cols, q, k, bias=None, *, n_rows: int,
+                   slope: float):
+    """The (n_rows, H) float32 softmax shift of the additive score, at or
+    above every score of its row: ``LeakyReLU(q + max k + max bias)``
+    where each head's spread of k and bias is at most
+    :data:`SHIFT_SPREAD`, else (a NaN or inf spread too) the exact row
+    maximum, with empty rows 0.  rows/cols and bias (nnz,) are the true
+    entries, q (n_rows, H, 1), k (n_kv, H, 1).  XLA alone: the bound is
+    one reduction over k and an elementwise op; the exact branch runs only
+    where the predicate asks for it."""
+    qf = q.reshape(q.shape[0], -1).astype(jnp.float32)
+    kf = k.reshape(k.shape[0], -1).astype(jnp.float32)
+    bf = None if bias is None else bias.astype(jnp.float32)
+    with jax.named_scope("attn.shift"):
+        k_max = jnp.max(kf, axis=0)
+        spread = k_max - jnp.min(kf, axis=0)
+        b_max = 0.0
+        if bf is not None:
+            b_max = jnp.max(bf)
+            spread = spread + (b_max - jnp.min(bf))
+
+        def bound():
+            with jax.named_scope("bound"):
+                return _activate(qf + k_max + b_max, "additive", slope)
+
+        def exact():
+            with jax.named_scope("exact"):
+                pre = qf[rows] + kf[cols]
+                if bf is not None:
+                    pre = pre + bf[:, None]
+                m = jax.ops.segment_max(_activate(pre, "additive", slope),
+                                        rows, num_segments=n_rows)
+                return jnp.where(jnp.isfinite(m), m, 0.0)
+
+        return jax.lax.cond(jnp.all(spread <= SHIFT_SPREAD), bound, exact)
+
+
+# ---------------------------------------------------------------------------
 # The forward kernel
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(*refs, nnz: int, d: int, dv: int, scale: float, score: str,
                 slope: float, group_size: int, strategy: str, has_bias: bool,
-                has_keep: bool):
+                has_keep: bool, shifted: bool):
     rows_ref, cols_ref, lanes_ref, bias_ref, keep_ref, refs = _unpack_lanes(
         refs, has_bias, has_keep)
-    (q_ref, k_ref, v_ref, acc_ref, m_ref,
-     qg_ref, kg_ref, vg_ref, mg_ref, part_ref) = refs
-    ph, i = pl.program_id(0), pl.program_id(1)
+    if shifted:  # the shift m is an input: one pass over the nnz tiles
+        q_ref, k_ref, v_ref, m_ref, acc_ref, *scratch = refs
+        i = pl.program_id(0)
+    else:
+        q_ref, k_ref, v_ref, acc_ref, m_ref, *scratch = refs
+        ph, i = pl.program_id(0), pl.program_id(1)
+    qg_ref, kg_ref, vg_ref, mg_ref, part_ref = scratch
     T, H = mg_ref.shape
     valid = _lane_mask(nnz, T, i)
     bias, keep = _lane_values(bias_ref, keep_ref)
@@ -325,28 +393,10 @@ def _fwd_kernel(*refs, nnz: int, d: int, dv: int, scale: float, score: str,
                        score=score, d=d, n_heads=H, scale=scale,
                        slope=slope)[1]
 
-    @pl.when((ph == 0) & (i == 0))
-    def _init_max():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-
-    @pl.when((ph == 1) & (i == 0))
-    def _init_acc():
+    def init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(ph == 0)
-    def _row_max():
-        def lane(t, c):
-            _gather(((q_ref, rows_ref[0, t], qg_ref),
-                     (k_ref, cols_ref[0, t], kg_ref)), t)
-            return c
-
-        jax.lax.fori_loop(0, T, lane, 0)
-        mg_ref[...] = jnp.where(valid, scores(), NEG_INF)
-        group_reduce_scatter(rows_ref, mg_ref, m_ref, group_size,
-                             "accumulate", op="max")
-
-    @pl.when(ph == 1)
-    def _weighted_sum():
+    def weighted_sum():
         def lane(t, c):
             r, col = rows_ref[0, t], cols_ref[0, t]
             _gather(((q_ref, r, qg_ref), (k_ref, col, kg_ref),
@@ -360,6 +410,31 @@ def _fwd_kernel(*refs, nnz: int, d: int, dv: int, scale: float, score: str,
         part_ref[:, H * dv:] = p
         _row_add(rows_ref, lanes_ref, part_ref, acc_ref, group_size, strategy)
 
+    if shifted:
+        pl.when(i == 0)(init_acc)
+        weighted_sum()
+        return
+
+    @pl.when((ph == 0) & (i == 0))
+    def _init_max():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+
+    pl.when((ph == 1) & (i == 0))(init_acc)
+
+    @pl.when(ph == 0)
+    def _row_max():
+        def lane(t, c):
+            _gather(((q_ref, rows_ref[0, t], qg_ref),
+                     (k_ref, cols_ref[0, t], kg_ref)), t)
+            return c
+
+        jax.lax.fori_loop(0, T, lane, 0)
+        mg_ref[...] = jnp.where(valid, scores(), NEG_INF)
+        group_reduce_scatter(rows_ref, mg_ref, m_ref, group_size,
+                             "accumulate", op="max")
+
+    pl.when(ph == 1)(weighted_sum)
+
 
 @functools.partial(
     jax.jit,
@@ -370,39 +445,49 @@ def fused_sparse_attention(rows, cols, q, k, v, *, n_rows: int, nnz: int,
                            nnz_tile: int = 256, scale: float = 1.0,
                            group_size: int = 32, strategy: str = "segment",
                            bias=None, score: str = "dot", slope: float = 0.2,
-                           keep=None, interpret: bool | None = None):
+                           keep=None, shift=None,
+                           interpret: bool | None = None):
     """One launch of the fused forward over all heads.
 
     rows/cols (and bias) are (nnz_pad,) with nnz_pad % nnz_tile == 0
     (``nnz`` is the true count; pad lanes are masked); q (n_rows, H, d),
     k (n_kv, H, d), v (n_kv, H, dv) node-major, d = 1 for the additive
     score; ``keep`` an optional (nnz_pad, H) mask on the normalised
-    coefficients.  Returns ``(out, m, l)``: out (n_rows, H, dv), and the
-    row max m and denominator l (n_rows, H), the O(H·n_rows) residuals the
-    backward recomputes the weights from.  ``interpret`` defaults to the
-    backend's answer (``common.pallas_call``)."""
+    coefficients; ``shift`` an optional (n_rows, H) per-row softmax shift
+    at or above every score of its row (:func:`additive_shift`): given,
+    the launch is one pass, without the row-max phase.  Returns
+    ``(out, m, l)``: out (n_rows, H, dv), and the shift m (the row max,
+    or ``shift``) and denominator l (n_rows, H), the O(H·n_rows)
+    residuals the backward recomputes the weights from.  ``interpret``
+    defaults to the backend's answer (``common.pallas_call``)."""
     _check(rows, q, k, v, nnz_tile, score, strategy)
     n_kv, H, d = k.shape
     dv = v.shape[2]
     R, C = _sublanes(n_rows), _sublanes(n_kv)
+    shifted = shift is not None
     tables = [_table(q, R), _table(k, C), _table(v, C)]
+    if shifted:
+        tables.append(_table(shift, R))
+    tiles = rows.shape[0] // nnz_tile
+    grid = (tiles,) if shifted else (2, tiles)
+    resident = lambda *_: (0, 0)  # noqa: E731
     lanes = _lane_operands(rows, cols, bias, keep, nnz_tile)
-    resident = lambda p, i: (0, 0)  # noqa: E731
     in_specs = _lane_specs(nnz_tile, H, bias is not None, keep is not None,
-                           lambda p, i: (i, 0, 0))
+                           lambda *g: (g[-1], 0, 0))
     in_specs += [pl.BlockSpec(t.shape, resident) for t in tables]
-    out_shape = [jax.ShapeDtypeStruct((R, H * dv + H), jnp.float32),
-                 jax.ShapeDtypeStruct((R, H), jnp.float32)]
+    out_shape = [jax.ShapeDtypeStruct((R, H * dv + H), jnp.float32)]
+    if not shifted:
+        out_shape.append(jax.ShapeDtypeStruct((R, H), jnp.float32))
     scratch = [(nnz_tile, H * d), (nnz_tile, H * d), (nnz_tile, H * dv),
                (nnz_tile, H), (nnz_tile, H * dv + H)]
     kernel = functools.partial(
         _fwd_kernel, nnz=nnz, d=d, dv=dv, scale=scale, score=score,
         slope=slope, group_size=group_size, strategy=strategy,
-        has_bias=bias is not None, has_keep=keep is not None)
-    acc, m = pallas_call(
+        has_bias=bias is not None, has_keep=keep is not None, shifted=shifted)
+    acc, *m = pallas_call(
         kernel,
         name="fused_attention_fwd",
-        grid=(2, rows.shape[0] // nnz_tile),
+        grid=grid,
         in_specs=in_specs,
         out_specs=[pl.BlockSpec(s.shape, resident) for s in out_shape],
         out_shape=out_shape,
@@ -410,6 +495,7 @@ def fused_sparse_attention(rows, cols, q, k, v, *, n_rows: int, nnz: int,
         vmem_need=_vmem_need(lanes, tables, out_shape, scratch),
         interpret=interpret,
     )(*lanes, *tables)
+    m = tables[3] if shifted else m[0]
     l = acc[:n_rows, H * dv:]
     out = acc[:n_rows, :H * dv].reshape(n_rows, H, dv)
     return out / jnp.maximum(l, 1e-30)[..., None], m[:n_rows], l

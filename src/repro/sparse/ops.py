@@ -22,8 +22,9 @@ ops' epilogue slots rather than callers picking per-op):
   group machinery (graph pooling); ``mean`` is the add monoid with a
   fused count column (one kernel pass + a divide).
 * ``sparse_attention`` is the fused score → segment softmax → SpMM
-  kernel (``kernels.fused_attention``; a row-max pass, then one
-  weighted-sum pass), all heads in one launch, with a dot-product or
+  kernel (``kernels.fused_attention``; one weighted-sum pass behind
+  a bound on the additive score's row max, a row-max pass first for the
+  dot score), all heads in one launch, with a dot-product or
   GAT's additive score, CSR stored values as an additive score bias and
   an optional keep mask on the coefficients.
 
@@ -46,6 +47,7 @@ import jax.numpy as jnp
 from ..core.schedule import Epilogue, Schedule, as_schedule
 from ..kernels import ops as kops
 from ..kernels import ref
+from ..kernels.fused_attention import additive_shift
 from ..kernels.fused_attention import (
     fused_sparse_attention as _fused_attn_fwd,
 )
@@ -479,7 +481,10 @@ def _sparse_attention_diff(rows, cols, qh, kh, vh, n_rows, scale, sched,
                            bias=None, *, score="dot", slope=0.2, keep=None):
     """Custom-VJP core over node-major (n, H, ·) operands: fused Pallas
     forward (saving its output and the (m, l) softmax row stats — the
-    O(H·n_rows) FlashAttention residuals), fused Pallas backward."""
+    O(H·n_rows) FlashAttention residuals), fused Pallas backward.  The
+    additive score's forward takes its shift m from
+    ``kernels.fused_attention.additive_shift`` and runs in one pass; the
+    dot score's finds the row max in a pass of its own."""
     nnz = int(rows.shape[0])
     nnz_pad = max(round_up(max(nnz, 1), sched.nnz_tile), sched.nnz_tile)
     pad = lambda x: jnp.pad(x, ((0, nnz_pad - nnz),) + ((0, 0),) * (x.ndim - 1))  # noqa: E731
@@ -490,12 +495,19 @@ def _sparse_attention_diff(rows, cols, qh, kh, vh, n_rows, scale, sched,
         keep=None if keep is None else pad(keep.astype(jnp.float32)))
     rows_p, cols_p = pad(rows), pad(cols)
 
+    def forward(q, k, v):
+        shift = None
+        if score == "additive":
+            shift = additive_shift(rows, cols, q, k, bias, n_rows=n_rows,
+                                   slope=slope)
+        return _fused_attn_fwd(rows_p, cols_p, q, k, v, shift=shift, **lanes)
+
     @jax.custom_vjp
     def _fn(q, k, v):
-        return _fused_attn_fwd(rows_p, cols_p, q, k, v, **lanes)[0]
+        return forward(q, k, v)[0]
 
     def _fwd(q, k, v):
-        out, m, l = _fused_attn_fwd(rows_p, cols_p, q, k, v, **lanes)
+        out, m, l = forward(q, k, v)
         return out, (q, k, v, out, m, l)
 
     def _bwd(res, dout):

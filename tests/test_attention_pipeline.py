@@ -23,6 +23,8 @@ except ImportError:  # pragma: no cover - exercised in the lean container
 
 from repro.core import Schedule  # noqa: E402
 from repro.kernels.fused_attention import (  # noqa: E402
+    SHIFT_SPREAD,
+    additive_shift,
     fused_sparse_attention,
     fused_sparse_attention_bwd,
     sparse_attention_bwd_ref,
@@ -532,3 +534,161 @@ def test_public_api_keep_and_additive_grads_match_spec():
     for gf, gs in zip(g_f, g_s):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gs),
                                    rtol=GRAD_TOL, atol=GRAD_TOL)
+
+
+# ---------------------------------------------------------------------------
+# The additive score's one-pass forward: the shift from a bound
+# ---------------------------------------------------------------------------
+
+
+def _additive_problem(H, seed, k_scale=1.0):
+    """An additive-score problem over several nnz tiles with padded lanes
+    and empty rows (rows 0 and 7 hold no entry)."""
+    R, C, nnz, dv = 23, 17, 90, 4
+    rng = np.random.default_rng(seed)
+    rows = np.sort(rng.choice([r for r in range(R) if r not in (0, 7)], nnz))
+    rows, cols = jnp.asarray(rows.astype(np.int32)), jnp.asarray(
+        rng.integers(0, C, nnz).astype(np.int32))
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (R, H, 1))
+    k = jax.random.normal(ks[1], (C, H, 1)) * k_scale
+    v = jax.random.normal(ks[2], (C, H, dv))
+    return rows, cols, q, k, v, ks[3]
+
+
+def _bound(q, k, slope=0.2):
+    pre = q[:, :, 0] + jnp.max(k[:, :, 0], axis=0)
+    return jnp.where(pre > 0, pre, slope * pre)
+
+
+def _padded(x, nnz_pad):
+    return None if x is None else jnp.pad(
+        x, ((0, nnz_pad - x.shape[0]),) + ((0, 0),) * (x.ndim - 1))
+
+
+@pytest.mark.parametrize("biased", [False, True], ids=["pattern", "csr_bias"])
+@pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "keep"])
+@pytest.mark.parametrize("H", [1, 8])
+def test_one_pass_additive_forward_matches_oracle_and_two_phase(H, masked,
+                                                                biased):
+    """The forward handed ``additive_shift``'s table runs one pass, and
+    matches ``sparse_attention_ref`` and the two-phase kernel to float32
+    rounding, with 1 and 8 heads, with and without a keep mask and a
+    value bias, over empty rows and padded lanes; its (m, l) are the
+    two-phase kernel's up to the shift: l·e^m is the same."""
+    rows, cols, q, k, v, key = _additive_problem(H, 31 + H)
+    R, nnz, tile = q.shape[0], rows.shape[0], 64
+    nnz_pad = round_up(nnz, tile)
+    bias = (jax.random.normal(key, (nnz,)) if biased else None)
+    keep = (jnp.where(jax.random.bernoulli(key, 0.5, (nnz, H)), 2.0, 0.0)
+            if masked else None)
+    kw = dict(n_rows=R, nnz=nnz, nnz_tile=tile, group_size=8,
+              score="additive", slope=0.2, bias=_padded(bias, nnz_pad),
+              keep=_padded(keep, nnz_pad))
+    rows_p, cols_p = _padded(rows, nnz_pad), _padded(cols, nnz_pad)
+    shift = additive_shift(rows, cols, q, k, bias, n_rows=R, slope=0.2)
+    out1, m1, l1 = fused_sparse_attention(rows_p, cols_p, q, k, v,
+                                          shift=shift, **kw)
+    out2, m2, l2 = fused_sparse_attention(rows_p, cols_p, q, k, v, **kw)
+    np.testing.assert_array_equal(np.asarray(m1), np.asarray(shift))
+    np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
+                               rtol=RTOL, atol=ATOL)
+    full = np.asarray(l2) > 0
+    np.testing.assert_allclose(
+        np.asarray(l1 * jnp.exp(m1 - m2))[full], np.asarray(l2)[full],
+        rtol=RTOL)
+    for h in range(H):
+        want = sparse_attention_ref(
+            rows, cols, q[:, h], k[:, h], v[:, h], n_rows=R, score="additive",
+            bias=bias, keep=None if keep is None else keep[:, h])
+        np.testing.assert_allclose(np.asarray(out1[:, h]), np.asarray(want),
+                                   rtol=RTOL, atol=ATOL)
+    assert np.all(np.asarray(out1)[[0, 7]] == 0)  # empty rows
+
+
+@pytest.mark.parametrize("biased", [False, True], ids=["pattern", "csr_bias"])
+def test_additive_grads_match_bwd_ref(biased):
+    """``sparse_attention(score="additive")``, whose forward takes the
+    bound's shift, differentiated through the custom VJP: dq, dk and dv
+    against ``sparse_attention_bwd_ref``, head by head."""
+    rows, cols, q, k, v, key = _additive_problem(3, 5)
+    R, C, nnz = q.shape[0], k.shape[0], rows.shape[0]
+    bias = jax.random.normal(key, (nnz,)) if biased else None
+    adj = (rows, cols, R)
+    if biased:  # the bias rides a CSR adjacency's stored values
+        from repro.sparse.formats import CSR
+
+        indptr = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(
+            jnp.bincount(rows, length=R)).astype(jnp.int32)])
+        adj = CSR(indptr=indptr, indices=cols, vals=bias, shape=(R, C))
+    dout = jax.random.normal(jax.random.PRNGKey(9), (R, 3, v.shape[2]))
+    _, vjp = jax.vjp(lambda *a: sparse_attention(
+        adj, *a, schedule=SCHEDS[0], score="additive"), q, k, v)
+    grads = vjp(dout)
+    for h in range(3):
+        want = sparse_attention_bwd_ref(
+            rows, cols, q[:, h], k[:, h], v[:, h], dout[:, h], n_rows=R,
+            scale=1.0, bias=bias, score="additive")
+        for got, w in zip(grads, want):
+            np.testing.assert_allclose(np.asarray(got[:, h]), np.asarray(w),
+                                       rtol=GRAD_TOL, atol=GRAD_TOL)
+
+
+def test_shift_guard_takes_exact_beyond_the_spread():
+    """k of ±100 spreads 200 > 60: the shift is the exact row max, and the
+    forward still matches the oracle.  Forced onto the same input, the
+    bound sits 200 above a row whose columns all hold -100, every term
+    underflows, and the row is lost: the guard is needed."""
+    rows, cols, q, k, v, _ = _additive_problem(2, 3)
+    R, nnz, tile = q.shape[0], rows.shape[0], 64
+    low = np.asarray(cols) < 12
+    k = jnp.where(jnp.arange(k.shape[0])[:, None, None] < 12, -100.0, 100.0)
+    k = k * jnp.ones((1, 2, 1))
+    shift = additive_shift(rows, cols, q, k, n_rows=R, slope=0.2)
+    pre = q[rows, :, 0] + k[cols, :, 0]
+    act = jnp.where(pre > 0, pre, 0.2 * pre)
+    exact = jax.ops.segment_max(act, rows, num_segments=R)
+    exact = jnp.where(jnp.isfinite(exact), exact, 0.0)
+    np.testing.assert_array_equal(np.asarray(shift), np.asarray(exact))
+    nnz_pad = round_up(nnz, tile)
+    kw = dict(n_rows=R, nnz=nnz, nnz_tile=tile, group_size=8,
+              score="additive", slope=0.2)
+    rows_p, cols_p = _padded(rows, nnz_pad), _padded(cols, nnz_pad)
+    out = fused_sparse_attention(rows_p, cols_p, q, k, v, shift=shift, **kw)[0]
+    forced = fused_sparse_attention(rows_p, cols_p, q, k, v,
+                                    shift=_bound(q, k), **kw)[0]
+    lost = [r for r in range(R)
+            if np.any(np.asarray(rows) == r)
+            and np.all(low[np.asarray(rows) == r])]
+    assert lost
+    for h in range(2):
+        want = np.asarray(sparse_attention_ref(
+            rows, cols, q[:, h], k[:, h], v[:, h], n_rows=R, score="additive"))
+        np.testing.assert_allclose(np.asarray(out[:, h]), want, rtol=RTOL,
+                                   atol=ATOL)
+        assert np.all(np.abs(want[lost]).max(axis=-1) > 1e-3)
+        assert np.all(np.asarray(forced[:, h])[lost] == 0)
+
+
+@pytest.mark.parametrize("case", ["nan_k", "spread_at_limit"])
+def test_shift_guard_predicate(case):
+    """A k holding NaN fails ``spread <= 60`` and takes the exact row max
+    (finite wherever a row's columns are); a spread of exactly
+    :data:`SHIFT_SPREAD` takes the bound."""
+    rows, cols, q, k, v, _ = _additive_problem(2, 4)
+    R = q.shape[0]
+    if case == "nan_k":
+        k = k.at[3].set(jnp.nan)
+    else:
+        k = k.at[:, :, 0].set(jnp.linspace(-SHIFT_SPREAD / 2, SHIFT_SPREAD / 2,
+                                           k.shape[0])[:, None])
+        assert float(jnp.max(k) - jnp.min(k)) == SHIFT_SPREAD
+    shift = np.asarray(additive_shift(rows, cols, q, k, n_rows=R, slope=0.2))
+    if case == "spread_at_limit":
+        np.testing.assert_array_equal(shift, np.asarray(_bound(q, k)))
+        return
+    touched = np.zeros(R, bool)
+    touched[np.asarray(rows)[np.asarray(cols) == 3]] = True
+    assert touched.any() and not touched.all()
+    assert np.all(np.isfinite(shift[~touched]))
+    assert np.all(np.isnan(np.asarray(_bound(q, k))))

@@ -225,9 +225,10 @@ GAT_HEADS = 8
 
 @pytest.mark.parametrize("width", [8, 3])
 def test_fused_attention_compiles(one_chip, pubmed, width):
-    """The fused attention forward and backward, additive score with a
-    keep mask, compile to ``tpu_custom_call`` at GAT-PubMed shapes: 19,717
-    rows, 108,393 entries, 8 heads of ``width`` values."""
+    """The fused attention forward (two-phase, and one pass with a shift
+    table) and backward, additive score with a keep mask, compile to
+    ``tpu_custom_call`` at GAT-PubMed shapes: 19,717 rows, 108,393
+    entries, 8 heads of ``width`` values."""
     from repro.kernels.fused_attention import (
         fused_sparse_attention,
         fused_sparse_attention_bwd,
@@ -250,12 +251,17 @@ def test_fused_attention_compiles(one_chip, pubmed, width):
     def fwd(r, c, q, k, v, kp):
         return fused_sparse_attention(r, c, q, k, v, keep=kp, **kw)
 
+    def one_pass(r, c, q, k, v, kp, m):
+        return fused_sparse_attention(r, c, q, k, v, keep=kp, shift=m, **kw)
+
     def bwd(r, c, q, k, v, o, do, m, l, kp):
         return fused_sparse_attention_bwd(r, c, q, k, v, o, do, m, l, keep=kp,
                                           **kw)
 
     for fn, shapes, name in (
             (fwd, (*lanes, *terms, vals, keep), "fused_attention_fwd"),
+            (one_pass, (*lanes, *terms, vals, keep, stats[0]),
+             "fused_attention_fwd"),
             (bwd, (*lanes, *terms, vals, vals, vals, *stats, keep),
              "fused_attention_bwd")):
         compiled = _compile(fn, *shapes)
@@ -267,10 +273,13 @@ def test_gat_step_has_its_four_attention_launches(one_chip, monkeypatch):
     """The GAT training cell's step (``bench/modes/train_gat.py``) at a cut
     size, compiled for the described chip: exactly four Pallas launches,
     each layer's fused attention forward and backward, named so, and no
-    interpreted kernel."""
+    interpreted kernel; each layer's softmax shift stays a conditional
+    whose bound and exact branches ``attn_bound_share`` finds (not a
+    select that would run both)."""
     import json
 
     from bench import counts_gat
+    from bench.metrics import attn_bound_share
     from bench.modes import train, train_gat
     from bench.run import ROOT
     from bench.traffic import gat as traffic
@@ -297,3 +306,4 @@ def test_gat_step_has_its_four_attention_launches(one_chip, monkeypatch):
     assert sorted(lc["kernel"] for lc in launches) == sorted(
         counts_gat.ATTN_KERNELS * 2)
     assert hlo.count('custom_call_target="tpu_custom_call"') == 4
+    assert len(attn_bound_share.shift_branches(hlo)) == 2
