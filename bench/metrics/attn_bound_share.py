@@ -6,13 +6,16 @@ layer (``repro.kernels.fused_attention.additive_shift``), found in the
 compiled step's HLO; each layer's conditional runs once a step.
 
 The reduced trace keeps each operation's summed device time, not its
-number of events.  So a branch's runs are read from one operation of it:
-the first in program order that has a twin in the other branch (the same
-opcode and shape, as the copy of an (n, H) table or the pad of the
-kernel's shift table, which cost alike a run), else the first.  Where
-only one branch's operation ran in the window, that branch took every
-forward of the layer; where both ran, the layer's forwards are split in
-proportion to the two operations' time."""
+number of events.  So a branch's runs are read from one operation of it
+that has a twin in the other branch: the same opcode and shape, and not
+a fusion, whose shape says nothing of its work (in the compiled step,
+the pad of the kernel's shift table that ends each branch), so the two
+cost alike a run; the last such pair in the bound branch, else the last
+pair of fusions, else each branch's first operation.  Events of async
+copies time a wait, not a run, and are never markers.  Where only one
+branch's operation ran in the window, that branch took every forward of
+the layer; where both ran, the layer's forwards are split in proportion
+to the two operations' time."""
 from __future__ import annotations
 
 import re
@@ -24,6 +27,8 @@ KIND = re.compile(r'op_name="[^"]*attn\.shift\)*/(?:[^"]*/)?(bound|exact)[/")]')
 #: opcodes that name values without running on the device
 SILENT = {"parameter", "get-tuple-element", "tuple", "constant", "bitcast",
           "after-all"}
+#: opcodes whose events time a wait for an async copy, not a run
+ASYNC = {"copy-start", "copy-done"}
 _HEADER = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(")
 
 
@@ -84,12 +89,15 @@ def shift_branches(hlo_text: str) -> list[dict]:
 
 def markers(layer: dict) -> dict:
     """The operation of each branch whose time counts its runs: the
-    bound's first with a twin in the exact branch and that twin, else
-    each branch's first."""
-    twins = {op[1:]: op[0] for op in reversed(layer["exact"])}
-    for name, *kind in layer["bound"]:
-        if tuple(kind) in twins:
-            return {"bound": name, "exact": twins[tuple(kind)]}
+    bound's last with a twin in the exact branch that is not a fusion,
+    and that twin; else the last pair of fusions; else each branch's
+    first."""
+    twins = {op[1:]: op[0] for op in layer["exact"] if op[2] not in ASYNC}
+    pairs = [(name, twins[tuple(kind)], kind[1])
+             for name, *kind in reversed(layer["bound"]) if tuple(kind) in twins]
+    pairs.sort(key=lambda p: p[2] == "fusion")  # stable: fusions last
+    if pairs:
+        return {"bound": pairs[0][0], "exact": pairs[0][1]}
     return {k: ops[0][0] for k, ops in layer.items()}
 
 

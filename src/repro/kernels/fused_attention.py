@@ -31,11 +31,13 @@ lane at a time into (T, ·) scratch rows, forms p = exp(e - m) and
 scatter-adds ``[p·keep ⊗ V | p]`` by row into one accumulator: the
 weighted values and the denominator l in one reduction ('segment': MXU
 window products, ``common.window_reduce_scatter``).  The additive score
-takes its shift from a bound (:func:`additive_shift`, in XLA): LeakyReLU
-is increasing and 1-Lipschitz, so LeakyReLU(q_i + max k + max bias) lies
-above every score of row i and at most the spread of k and bias above
-its largest; where a head's spread passes :data:`SHIFT_SPREAD` (60), or
-is not finite, the exact row maximum is taken instead.  Without a shift
+takes its shift from bounds (:func:`additive_shift`, in XLA): LeakyReLU
+is increasing, so row i's largest score lies between LeakyReLU(q_i +
+min k + min bias) and LeakyReLU(q_i + max k + max bias); the shift sits
+at most :data:`SHIFT_ROOM` (60) below the upper bound and above the
+lower, so every term is at most e^60 and the row's largest at least
+e^-60.  Where a row's bounds lie more than 120 apart, or are not finite,
+the exact row maximum is taken instead.  Without a shift
 (the dot score) the grid is (2, nnz_tiles): phase 0 gathers Q[r] and
 K[c], computes the scores and scatters their row maximum (the max
 monoid, the registry's 'accumulate' realization: one read-modify-write
@@ -80,7 +82,7 @@ from .common import (
 __all__ = [
     "NEG_INF",
     "SCORES",
-    "SHIFT_SPREAD",
+    "SHIFT_ROOM",
     "additive_shift",
     "fused_sparse_attention",
     "fused_sparse_attention_bwd",
@@ -319,41 +321,47 @@ def _check(rows, q, k, v, nnz_tile, score, strategy):
 # The additive score's softmax shift
 # ---------------------------------------------------------------------------
 
-#: the largest per-head spread (k_max - k_min) + (b_max - b_min) for which
-#: the bound below may stand in for the row max.  LeakyReLU is increasing
-#: and 1-Lipschitz, so LeakyReLU(q_i + k_max + b_max) bounds every score of
-#: row i from above and sits at most the spread above the row's largest
-#: score.  At a spread of 60 that term is at least e^-60 ≈ 8.8e-27, a
-#: normal float32, and a term is lost only where its weight relative to
-#: the row's largest is below e^-27 ≈ 1.9e-12, far under float32's
-#: rounding of the sum.
-SHIFT_SPREAD = 60.0
+#: how far the shift may sit from its row's largest score, on either side.
+#: LeakyReLU is increasing, so a row i that holds an entry has its largest
+#: score between lo_i = LeakyReLU(q_i + min k + min bias) and
+#: hi_i = LeakyReLU(q_i + max k + max bias).  The shift min(hi_i,
+#: lo_i + 60) then keeps every term exp(e - shift) at most e^(gap - 60)
+#: and the row's largest at least e^-60, where gap = hi_i - lo_i.  So
+#: while every row's gap is at most 2 · 60, no term passes e^60 ≈ 1.1e26
+#: (a row of d terms and values v sums to at most 1.1e26·d·|v|, far from
+#: float32's 3.4e38), the largest is at least e^-60 ≈ 8.8e-27, a normal
+#: float32, and a term is lost only where its weight relative to the
+#: row's largest is below e^-27 ≈ 1.9e-12, far under float32's rounding
+#: of the sum.  A gap of at most 60 leaves the shift at hi_i, at or above
+#: every score of its row.
+SHIFT_ROOM = 60.0
 
 
 def additive_shift(rows, cols, q, k, bias=None, *, n_rows: int,
                    slope: float):
-    """The (n_rows, H) float32 softmax shift of the additive score, at or
-    above every score of its row: ``LeakyReLU(q + max k + max bias)``
-    where each head's spread of k and bias is at most
-    :data:`SHIFT_SPREAD`, else (a NaN or inf spread too) the exact row
-    maximum, with empty rows 0.  rows/cols and bias (nnz,) are the true
-    entries, q (n_rows, H, 1), k (n_kv, H, 1).  XLA alone: the bound is
-    one reduction over k and an elementwise op; the exact branch runs only
-    where the predicate asks for it."""
+    """The (n_rows, H) float32 softmax shift of the additive score:
+    ``min(hi, lo + SHIFT_ROOM)`` between the bounds ``hi =
+    LeakyReLU(q + max k + max bias)`` and ``lo = LeakyReLU(q + min k +
+    min bias)`` on each row's largest score, where every row's gap
+    ``hi - lo`` is at most 2 · :data:`SHIFT_ROOM`; else (a NaN or inf gap
+    too) the exact row maximum, with empty rows 0.  rows/cols and bias
+    (nnz,) are the true entries, q (n_rows, H, 1), k (n_kv, H, 1).  XLA
+    alone: the bounds are two reductions over k and elementwise ops on
+    q; the exact branch runs only where the predicate asks for it."""
     qf = q.reshape(q.shape[0], -1).astype(jnp.float32)
     kf = k.reshape(k.shape[0], -1).astype(jnp.float32)
     bf = None if bias is None else bias.astype(jnp.float32)
     with jax.named_scope("attn.shift"):
-        k_max = jnp.max(kf, axis=0)
-        spread = k_max - jnp.min(kf, axis=0)
-        b_max = 0.0
+        k_max, k_min = jnp.max(kf, axis=0), jnp.min(kf, axis=0)
         if bf is not None:
-            b_max = jnp.max(bf)
-            spread = spread + (b_max - jnp.min(bf))
+            k_max, k_min = k_max + jnp.max(bf), k_min + jnp.min(bf)
+        hi = lambda: _activate(qf + k_max, "additive", slope)  # noqa: E731
+        lo = lambda: _activate(qf + k_min, "additive", slope)  # noqa: E731
+        fits = jnp.all(hi() - lo() <= 2 * SHIFT_ROOM)
 
         def bound():
             with jax.named_scope("bound"):
-                return _activate(qf + k_max + b_max, "additive", slope)
+                return jnp.minimum(hi(), lo() + SHIFT_ROOM)
 
         def exact():
             with jax.named_scope("exact"):
@@ -364,7 +372,7 @@ def additive_shift(rows, cols, q, k, bias=None, *, n_rows: int,
                                         rows, num_segments=n_rows)
                 return jnp.where(jnp.isfinite(m), m, 0.0)
 
-        return jax.lax.cond(jnp.all(spread <= SHIFT_SPREAD), bound, exact)
+        return jax.lax.cond(fits, bound, exact)
 
 
 # ---------------------------------------------------------------------------

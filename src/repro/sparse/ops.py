@@ -47,8 +47,8 @@ import jax.numpy as jnp
 from ..core.schedule import Epilogue, Schedule, as_schedule
 from ..kernels import ops as kops
 from ..kernels import ref
-from ..kernels.fused_attention import additive_shift
 from ..kernels.fused_attention import (
+    additive_shift,
     fused_sparse_attention as _fused_attn_fwd,
 )
 from ..kernels.fused_attention import (

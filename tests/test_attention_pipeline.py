@@ -23,7 +23,7 @@ except ImportError:  # pragma: no cover - exercised in the lean container
 
 from repro.core import Schedule  # noqa: E402
 from repro.kernels.fused_attention import (  # noqa: E402
-    SHIFT_SPREAD,
+    SHIFT_ROOM,
     additive_shift,
     fused_sparse_attention,
     fused_sparse_attention_bwd,
@@ -556,9 +556,41 @@ def _additive_problem(H, seed, k_scale=1.0):
     return rows, cols, q, k, v, ks[3]
 
 
-def _bound(q, k, slope=0.2):
-    pre = q[:, :, 0] + jnp.max(k[:, :, 0], axis=0)
-    return jnp.where(pre > 0, pre, slope * pre)
+def _leaky(x, slope=0.2):
+    return jnp.where(x > 0, x, slope * x)
+
+
+def _bound(q, k):
+    """The upper bound alone: LeakyReLU(q + max k), at or above every
+    score of its row."""
+    return _leaky(q[:, :, 0] + jnp.max(k[:, :, 0], axis=0))
+
+
+def _two_sided(q, k):
+    """The bound branch's shift, min(hi, lo + SHIFT_ROOM)."""
+    lo = _leaky(q[:, :, 0] + jnp.min(k[:, :, 0], axis=0))
+    return jnp.minimum(_bound(q, k), lo + SHIFT_ROOM)
+
+
+def _exact(rows, cols, q, k, R):
+    m = jax.ops.segment_max(_leaky(q[rows, :, 0] + k[cols, :, 0]), rows,
+                            num_segments=R)
+    return jnp.where(jnp.isfinite(m), m, 0.0)
+
+
+def _forward(rows, cols, q, k, v, shift):
+    nnz, tile = rows.shape[0], 64
+    nnz_pad = round_up(nnz, tile)
+    return fused_sparse_attention(
+        _padded(rows, nnz_pad), _padded(cols, nnz_pad), q, k, v, shift=shift,
+        n_rows=q.shape[0], nnz=nnz, nnz_tile=tile, group_size=8,
+        score="additive", slope=0.2)[0]
+
+
+def _oracle(rows, cols, q, k, v, h):
+    return np.asarray(sparse_attention_ref(
+        rows, cols, q[:, h], k[:, h], v[:, h], n_rows=q.shape[0],
+        score="additive"))
 
 
 def _padded(x, nnz_pad):
@@ -635,60 +667,72 @@ def test_additive_grads_match_bwd_ref(biased):
 
 
 def test_shift_guard_takes_exact_beyond_the_spread():
-    """k of ±100 spreads 200 > 60: the shift is the exact row max, and the
-    forward still matches the oracle.  Forced onto the same input, the
-    bound sits 200 above a row whose columns all hold -100, every term
-    underflows, and the row is lost: the guard is needed."""
+    """k of ±300 puts a row's bounds ~600 apart, past 2·60: the shift is
+    the exact row max, and the forward still matches the oracle.  Forced
+    onto the same input, either bound shift loses rows: the upper bound
+    alone sits ~600 above a row whose columns all hold -300, so every term
+    underflows and the row reads 0; the two-sided shift sits ~60 above
+    the lower bound, so a row with a column at +300 overflows.  The guard
+    is needed."""
     rows, cols, q, k, v, _ = _additive_problem(2, 3)
-    R, nnz, tile = q.shape[0], rows.shape[0], 64
+    R = q.shape[0]
     low = np.asarray(cols) < 12
-    k = jnp.where(jnp.arange(k.shape[0])[:, None, None] < 12, -100.0, 100.0)
+    k = jnp.where(jnp.arange(k.shape[0])[:, None, None] < 12, -300.0, 300.0)
     k = k * jnp.ones((1, 2, 1))
     shift = additive_shift(rows, cols, q, k, n_rows=R, slope=0.2)
-    pre = q[rows, :, 0] + k[cols, :, 0]
-    act = jnp.where(pre > 0, pre, 0.2 * pre)
-    exact = jax.ops.segment_max(act, rows, num_segments=R)
-    exact = jnp.where(jnp.isfinite(exact), exact, 0.0)
-    np.testing.assert_array_equal(np.asarray(shift), np.asarray(exact))
-    nnz_pad = round_up(nnz, tile)
-    kw = dict(n_rows=R, nnz=nnz, nnz_tile=tile, group_size=8,
-              score="additive", slope=0.2)
-    rows_p, cols_p = _padded(rows, nnz_pad), _padded(cols, nnz_pad)
-    out = fused_sparse_attention(rows_p, cols_p, q, k, v, shift=shift, **kw)[0]
-    forced = fused_sparse_attention(rows_p, cols_p, q, k, v,
-                                    shift=_bound(q, k), **kw)[0]
-    lost = [r for r in range(R)
-            if np.any(np.asarray(rows) == r)
-            and np.all(low[np.asarray(rows) == r])]
-    assert lost
+    np.testing.assert_array_equal(np.asarray(shift),
+                                  np.asarray(_exact(rows, cols, q, k, R)))
+    out = _forward(rows, cols, q, k, v, shift)
+    under = _forward(rows, cols, q, k, v, _bound(q, k))
+    over = _forward(rows, cols, q, k, v, _two_sided(q, k))
+    r = np.asarray(rows)
+    held = [i for i in range(R) if np.any(r == i)]
+    lost = [i for i in held if np.all(low[r == i])]
+    high = [i for i in held if not np.all(low[r == i])]
+    assert lost and high
     for h in range(2):
-        want = np.asarray(sparse_attention_ref(
-            rows, cols, q[:, h], k[:, h], v[:, h], n_rows=R, score="additive"))
+        want = _oracle(rows, cols, q, k, v, h)
         np.testing.assert_allclose(np.asarray(out[:, h]), want, rtol=RTOL,
                                    atol=ATOL)
         assert np.all(np.abs(want[lost]).max(axis=-1) > 1e-3)
-        assert np.all(np.asarray(forced[:, h])[lost] == 0)
+        assert np.all(np.asarray(under[:, h])[lost] == 0)
+        assert not np.any(np.isfinite(np.asarray(over[:, h])[high]))
 
 
-@pytest.mark.parametrize("case", ["nan_k", "spread_at_limit"])
+@pytest.mark.parametrize("case", ["nan_k", "gap_at_limit", "gap_past_limit"])
 def test_shift_guard_predicate(case):
-    """A k holding NaN fails ``spread <= 60`` and takes the exact row max
-    (finite wherever a row's columns are); a spread of exactly
-    :data:`SHIFT_SPREAD` takes the bound."""
+    """A k holding NaN fails ``gap <= 120`` and takes the exact row max
+    (finite wherever a row's columns are).  q of 70 and k spread over
+    ±60 put every row's bounds exactly 2·:data:`SHIFT_ROOM` apart: the
+    bound branch's shift sits 60 above the lower bound and 60 below the
+    upper, the largest term of a row whose columns reach +60 is e^60, and
+    the forward matches the oracle.  A quarter more spread takes the
+    exact row max."""
     rows, cols, q, k, v, _ = _additive_problem(2, 4)
     R = q.shape[0]
     if case == "nan_k":
         k = k.at[3].set(jnp.nan)
-    else:
-        k = k.at[:, :, 0].set(jnp.linspace(-SHIFT_SPREAD / 2, SHIFT_SPREAD / 2,
-                                           k.shape[0])[:, None])
-        assert float(jnp.max(k) - jnp.min(k)) == SHIFT_SPREAD
-    shift = np.asarray(additive_shift(rows, cols, q, k, n_rows=R, slope=0.2))
-    if case == "spread_at_limit":
-        np.testing.assert_array_equal(shift, np.asarray(_bound(q, k)))
+        shift = np.asarray(additive_shift(rows, cols, q, k, n_rows=R,
+                                          slope=0.2))
+        touched = np.zeros(R, bool)
+        touched[np.asarray(rows)[np.asarray(cols) == 3]] = True
+        assert touched.any() and not touched.all()
+        assert np.all(np.isfinite(shift[~touched]))
+        assert np.all(np.isnan(np.asarray(_bound(q, k))))
         return
-    touched = np.zeros(R, bool)
-    touched[np.asarray(rows)[np.asarray(cols) == 3]] = True
-    assert touched.any() and not touched.all()
-    assert np.all(np.isfinite(shift[~touched]))
-    assert np.all(np.isnan(np.asarray(_bound(q, k))))
+    half = SHIFT_ROOM + (0.25 if case == "gap_past_limit" else 0.0)
+    k = k.at[:, :, 0].set(jnp.linspace(-half, half, k.shape[0])[:, None])
+    q = jnp.full_like(q, 70.0)
+    assert float(jnp.max(_bound(q, k) - _leaky(q[:, :, 0] + k.min()))) == 2 * half
+    shift = np.asarray(additive_shift(rows, cols, q, k, n_rows=R, slope=0.2))
+    if case == "gap_past_limit":
+        np.testing.assert_array_equal(
+            shift, np.asarray(_exact(rows, cols, q, k, R)))
+        return
+    np.testing.assert_array_equal(shift, np.asarray(_two_sided(q, k)))
+    np.testing.assert_array_equal(shift, 70.0)
+    out = _forward(rows, cols, q, k, v, jnp.asarray(shift))
+    for h in range(2):
+        np.testing.assert_allclose(np.asarray(out[:, h]),
+                                   _oracle(rows, cols, q, k, v, h),
+                                   rtol=RTOL, atol=ATOL)

@@ -275,7 +275,8 @@ def test_gat_step_has_its_four_attention_launches(one_chip, monkeypatch):
     each layer's fused attention forward and backward, named so, and no
     interpreted kernel; each layer's softmax shift stays a conditional
     whose bound and exact branches ``attn_bound_share`` finds (not a
-    select that would run both)."""
+    select that would run both), each counted by a twin operation of the
+    same opcode and shape that is not a fusion."""
     import json
 
     from bench import counts_gat
@@ -306,4 +307,10 @@ def test_gat_step_has_its_four_attention_launches(one_chip, monkeypatch):
     assert sorted(lc["kernel"] for lc in launches) == sorted(
         counts_gat.ATTN_KERNELS * 2)
     assert hlo.count('custom_call_target="tpu_custom_call"') == 4
-    assert len(attn_bound_share.shift_branches(hlo)) == 2
+    layers = attn_bound_share.shift_branches(hlo)
+    assert len(layers) == 2
+    for layer in layers:
+        ops = {op[0]: op[1:] for branch in layer.values() for op in branch}
+        mark = attn_bound_share.markers(layer)
+        assert ops[mark["bound"]] == ops[mark["exact"]]
+        assert ops[mark["bound"]][1] != "fusion"
