@@ -350,7 +350,8 @@ def additive_shift(rows, cols, q, k, bias=None, *, n_rows: int,
     q; the exact branch runs only where the predicate asks for it."""
     qf = q.reshape(q.shape[0], -1).astype(jnp.float32)
     kf = k.reshape(k.shape[0], -1).astype(jnp.float32)
-    bf = None if bias is None else bias.astype(jnp.float32)
+    # a bias of no entries adds to no score
+    bf = None if bias is None or bias.shape[0] == 0 else bias.astype(jnp.float32)
     with jax.named_scope("attn.shift"):
         k_max, k_min = jnp.max(kf, axis=0), jnp.min(kf, axis=0)
         if bf is not None:

@@ -736,3 +736,26 @@ def test_shift_guard_predicate(case):
         np.testing.assert_allclose(np.asarray(out[:, h]),
                                    _oracle(rows, cols, q, k, v, h),
                                    rtol=RTOL, atol=ATOL)
+
+
+@pytest.mark.parametrize("form", ["pattern", "csr_bias"])
+def test_additive_empty_pattern_gives_zero_rows(form):
+    """An adjacency with no entries, as a pattern tuple or a CSR whose
+    stored values are the score bias: the additive score's shift has no
+    bias term to bound, the output is all zero rows, and so are the
+    gradients."""
+    from repro.sparse.formats import CSR
+
+    R, C, H = 5, 4, 2
+    empty = jnp.zeros(0, jnp.int32)
+    adj = ((empty, empty, R) if form == "pattern" else
+           CSR(indptr=jnp.zeros(R + 1, jnp.int32), indices=empty,
+               vals=jnp.zeros(0, jnp.float32), shape=(R, C)))
+    q, k = jnp.ones((R, H, 1)), jnp.ones((C, H, 1))
+    v = jnp.ones((C, H, 3))
+    out, vjp = jax.vjp(lambda *a: sparse_attention(adj, *a, score="additive"),
+                       q, k, v)
+    assert np.all(np.asarray(out) == 0)
+    for g in vjp(jnp.ones_like(out)):
+        assert np.all(np.asarray(g) == 0)
+
