@@ -19,6 +19,7 @@ from .execute import moe_combine, run_chain_ref, run_plan
 from .ir import (
     EPILOGUE_CAPABLE,
     PALLAS_KINDS,
+    BatchNorm,
     FuseDecision,
     FuseNode,
     FusePlan,
@@ -29,6 +30,7 @@ from .ir import (
     gcn_chain,
     grouped_matmul_node,
     moe_expert_chain,
+    norm_node,
     segment_reduce_node,
     spmm_node,
 )
@@ -39,6 +41,7 @@ from .rules import available_rules, register_rule, unregister_rule
 __all__ = [
     "EPILOGUE_CAPABLE",
     "PALLAS_KINDS",
+    "BatchNorm",
     "FuseDecision",
     "FuseNode",
     "FusePlan",
@@ -52,6 +55,7 @@ __all__ = [
     "grouped_matmul_node",
     "moe_combine",
     "moe_expert_chain",
+    "norm_node",
     "plan",
     "plan_key",
     "register_rule",

@@ -7,7 +7,8 @@ kernel, the launch's merged epilogue attached), ``grouped_matmul``
 anchors through ``kernels.ops.grouped_matmul`` (differentiable,
 epilogued), ``segment_reduce`` through ``repro.sparse.segment_reduce``,
 ``combine`` through the jnp monoid scatter (:func:`moe_combine` — kept
-in XLA for differentiability), and unfused ``ewise`` launches apply
+in XLA for differentiability), ``norm`` launches through
+:func:`batch_norm` in XLA, and unfused ``ewise`` launches apply
 their epilogue spec in XLA.  Because every Pallas path already carries a
 custom VJP, a planned chain is differentiable end to end.
 
@@ -28,6 +29,9 @@ grouped_matmul     ``tile_experts``, ``weights``, optional ``token_tile``
 segment_reduce     ``seg_ids``, ``num_segments``
 combine            ``topi``, ``topv``, ``num_tokens``
 ewise              ``bias`` / ``residual`` arrays for its epilogue flags
+norm               ``gamma``, ``beta``, running ``mean`` / ``var``, and
+                   optionally ``keep`` (a dropout mask after the
+                   activation)
 =================  =======================================================
 """
 from __future__ import annotations
@@ -37,7 +41,7 @@ import jax.numpy as jnp
 
 from .ir import FusePlan, Launch
 
-__all__ = ["moe_combine", "run_chain_ref", "run_plan"]
+__all__ = ["batch_norm", "moe_combine", "run_chain_ref", "run_plan"]
 
 
 def moe_combine(y, topi, topv, num_tokens: int, op: str = "sum"):
@@ -64,6 +68,44 @@ def moe_combine(y, topi, topv, num_tokens: int, op: str = "sum"):
             jnp.ones((y.shape[0], 1), jnp.float32))
         return tot / jnp.maximum(cnt, 1.0)
     raise ValueError(f"moe_combine op {op!r}; one of sum/min/mean")
+
+
+def batch_norm(x, gamma, beta, mean, var, norm):
+    """``(y, (mean', var'))``: ``x`` (rows, F) normalised under the
+    :class:`~repro.fuse.ir.BatchNorm` ``norm`` (by its batch statistics
+    in training, by the running ``mean`` / ``var`` in evaluation), then
+    ``* gamma + beta``; and the running statistics it leaves (moved
+    ``norm.momentum`` of the way to the batch mean and the unbiased
+    batch variance in training, unchanged in evaluation).  Float32."""
+    x = x.astype(jnp.float32)
+    if norm.training:
+        mu = jnp.mean(x, axis=0)
+        v = jnp.mean(jnp.square(x - mu), axis=0)
+        n = x.shape[0]
+        m = norm.momentum
+        running = jax.lax.stop_gradient(
+            ((1 - m) * mean + m * mu,
+             (1 - m) * var + m * v * (n / max(n - 1, 1))))
+    else:
+        mu, v, running = mean, var, (mean, var)
+    return (x - mu) * jax.lax.rsqrt(v + norm.eps) * gamma + beta, running
+
+
+def _run_norm(node, ep, cur, p, stats):
+    """A norm launch: the node's batch norm (scope ``norm``), then its
+    epilogue and dropout mask (scope ``act``), under the node's label;
+    the running statistics go to ``stats[label]``."""
+    with jax.named_scope(node.label or "norm"):
+        with jax.named_scope("norm"):
+            y, running = batch_norm(cur, p["gamma"], p["beta"], p["mean"],
+                                    p["var"], node.norm)
+        with jax.named_scope("act"):
+            y = ep.apply(y)
+            if p.get("keep") is not None:
+                y = y * p["keep"]
+    if stats is not None:
+        stats[node.label] = running
+    return y
 
 
 def _ewise_bias(bias, params):
@@ -96,11 +138,13 @@ def _epilogue_operands(launch: Launch, params):
     return bias, residual
 
 
-def _run_launch(launch: Launch, cur, params):
+def _run_launch(launch: Launch, cur, params, stats=None):
     a = launch.anchor
     p = params[launch.anchor_idx] or {}
     ep = launch.epilogue
     bias, residual = _epilogue_operands(launch, params)
+    if a.kind == "norm":
+        return _run_norm(a, ep, cur, p, stats)
 
     if a.kind == "spmm":
         from ..sparse import spmm
@@ -129,15 +173,16 @@ def _run_launch(launch: Launch, cur, params):
                     residual=residual)
 
 
-def run_plan(plan: FusePlan, x, params):
+def run_plan(plan: FusePlan, x, params, stats=None):
     """Execute a plan: ``params`` is the per-chain-node operand list
-    (``len(params) == len(plan.chain)``)."""
+    (``len(params) == len(plan.chain)``).  ``stats``, a dict, receives
+    each norm launch's running statistics under its label."""
     assert len(params) == len(plan.chain), (len(params), len(plan.chain))
     cur = x
     for i, launch in enumerate(plan.launches):
         # the launch's device work, its backward too, carries this name
         with jax.named_scope(f"fuse.launch{i}.{launch.anchor.kind}"):
-            cur = _run_launch(launch, cur, params)
+            cur = _run_launch(launch, cur, params, stats)
     return cur
 
 
@@ -172,6 +217,8 @@ def _run_node_ref(node, cur, p, params):
     if node.kind == "combine":
         return moe_combine(cur, p["topi"], p["topv"], p["num_tokens"],
                            op=node.op)
+    if node.kind == "norm":
+        return _run_norm(node, node.epilogue, cur, p, None)
     return node.epilogue.apply(cur, bias=_ewise_bias(p.get("bias"),
                                                      params),
                                residual=p.get("residual"))

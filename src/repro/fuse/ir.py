@@ -7,8 +7,10 @@ This module makes the *shape* of those fusions first-class:
 
 * a :class:`FuseNode` is one op in a producer→consumer chain — a
   reducing kernel anchor (``spmm`` / ``grouped_matmul`` /
-  ``segment_reduce``), a scatter ``combine``, or elementwise ``ewise``
-  work expressed as the :class:`~repro.core.Epilogue` it would fuse as;
+  ``segment_reduce``), a scatter ``combine``, elementwise ``ewise``
+  work expressed as the :class:`~repro.core.Epilogue` it would fuse as,
+  or a ``norm`` over all rows (:class:`BatchNorm`), which runs in XLA
+  and ends a fold;
 * a :class:`Launch` is one executable unit the planner emitted: an
   anchor node plus the chain members folded into its epilogue slot;
 * a :class:`FusePlan` is the planner's output — the chain, its
@@ -31,6 +33,7 @@ from typing import Optional, Tuple
 from ..core.schedule import Epilogue, Schedule, as_schedule
 
 __all__ = [
+    "BatchNorm",
     "EPILOGUE_CAPABLE",
     "FuseDecision",
     "FuseNode",
@@ -44,14 +47,17 @@ __all__ = [
     "gcn_chain",
     "grouped_matmul_node",
     "moe_expert_chain",
+    "norm_node",
     "segment_reduce_node",
     "spmm_node",
 ]
 
-KINDS = ("spmm", "grouped_matmul", "segment_reduce", "combine", "ewise")
+KINDS = ("spmm", "grouped_matmul", "segment_reduce", "combine", "ewise",
+         "norm")
 
 #: kinds that execute as a Pallas kernel when they anchor a launch
-#: (``combine`` is an XLA scatter, ``ewise`` an XLA elementwise pass)
+#: (``combine`` is an XLA scatter, ``ewise`` an XLA elementwise pass,
+#: ``norm`` an XLA pass over all rows)
 PALLAS_KINDS = frozenset({"spmm", "grouped_matmul", "segment_reduce"})
 
 #: anchors exposing the shared in-kernel epilogue slot — the targets of
@@ -67,19 +73,35 @@ REDUCE_OPS = ("sum", "max", "min", "mean")
 
 
 @dataclasses.dataclass(frozen=True)
+class BatchNorm:
+    """Batch normalisation over the rows (Ioffe and Szegedy; the
+    semantics of ``torch.nn.BatchNorm1d``): in training, each column
+    normalised by its batch mean and biased variance, then scaled and
+    shifted (affine), and the running statistics moved ``momentum`` of
+    the way to the batch mean and the unbiased variance; in evaluation,
+    normalised by the running statistics."""
+
+    eps: float = 1e-5
+    momentum: float = 0.1
+    training: bool = True
+
+
+@dataclasses.dataclass(frozen=True)
 class FuseNode:
     """One chain node.  ``op`` is the reduction monoid name (reducing
     kinds only); ``epilogue`` is the node's own elementwise work — for
     ``ewise`` nodes it *is* the node, for anchors it is work requested
     at the node itself (usually noop; the planner folds downstream
-    ``ewise`` nodes into it).  ``schedule`` rides on ``spmm`` /
-    ``segment_reduce`` anchors."""
+    ``ewise`` nodes into it; a ``norm`` node's activation follows its
+    normalisation).  ``schedule`` rides on ``spmm`` /
+    ``segment_reduce`` anchors, ``norm`` on ``norm`` nodes."""
 
     kind: str
     op: str = "sum"
     epilogue: Epilogue = Epilogue()
     schedule: Optional[Schedule] = None
     label: str = ""
+    norm: Optional[BatchNorm] = None
 
     def __post_init__(self):
         if self.kind not in KINDS:
@@ -88,6 +110,9 @@ class FuseNode:
         if self.op not in REDUCE_OPS:
             raise ValueError(f"unknown reduction op {self.op!r}; "
                              f"one of {REDUCE_OPS}")
+        if (self.kind == "norm") != (self.norm is not None):
+            raise ValueError("a norm node, and only a norm node, carries "
+                             "its BatchNorm")
 
     @property
     def tag(self) -> str:
@@ -95,6 +120,8 @@ class FuseNode:
         parts = [self.kind]
         if self.kind in ("segment_reduce", "combine") or self.op != "sum":
             parts.append(self.op)
+        if self.norm is not None:
+            parts.append("batch" if self.norm.training else "batch.eval")
         if not self.epilogue.is_noop:
             parts.append(f"[{self.epilogue.tag}]")
         return ":".join(parts)
@@ -120,6 +147,16 @@ def segment_reduce_node(op: str = "sum", *, schedule=None,
     optional explicit :class:`Schedule`."""
     sched = None if schedule is None else as_schedule(schedule)
     return FuseNode("segment_reduce", op=op, schedule=sched, label=label)
+
+
+def norm_node(norm: BatchNorm = BatchNorm(), *,
+              activation: Optional[str] = None, label: str = "") -> FuseNode:
+    """A batch norm over all rows, then ``activation``: an XLA launch of
+    its own (its statistics need every row, which no kernel's per-block
+    epilogue sees).  Its params may carry a ``keep`` mask (dropout's 0 or
+    1/(1-p)), applied after the activation."""
+    return FuseNode("norm", epilogue=Epilogue(activation=activation),
+                    norm=norm, label=label)
 
 
 def combine_node(op: str = "sum", *, label: str = "") -> FuseNode:
@@ -200,25 +237,50 @@ def chain_sig(chain) -> str:
 
 
 def gcn_chain(adj, weights, biases=None, *, activation: str = "relu",
-              final_activation: Optional[str] = None, schedule=None):
-    """Two-layer GCN — ``act(Ã (X W₀) + b₀)`` → ``Ã (· W₁) + b₁`` — as a
-    4-node chain ``spmm → ewise → spmm [→ ewise]``.  The planner folds
-    each ewise into its producing SpMM's epilogue, so the whole model
-    runs in 2 Pallas launches.
+              final_activation: Optional[str] = None, schedule=None,
+              norm: Optional[BatchNorm] = None, norms=None, keeps=None):
+    """An L-layer GCN — layer ``i`` is ``Ã (H Wᵢ) + bᵢ`` — as a chain:
+    per layer an ``spmm`` anchor and an ``ewise`` with its bias, between
+    layers ``activation``, and after the last ``final_activation``.
+    The planner folds each ewise into its producing SpMM's epilogue, so
+    the model runs in L Pallas launches.
 
-    ``weights`` is ``(w0, w1)``; ``biases`` optionally ``(b0, b1)`` (a
-    ``None`` entry drops that bias).  Returns ``(chain, params)``.
+    Two layers without ``norm`` is Kipf and Welling's model, the 4-node
+    chain ``spmm → ewise(act, bias) → spmm [→ ewise]``.  With ``norm``,
+    each hidden layer's bias ewise is followed by a ``norm`` node
+    (:func:`norm_node`, labelled ``gcn.layer<i>``) that normalises, then
+    applies ``activation`` and the layer's dropout mask: the fold ends
+    there, and the norm runs in XLA between launches (OGB's GCN: conv →
+    BatchNorm → ReLU → dropout).  ``norms[i]`` holds hidden layer i's
+    ``gamma``, ``beta`` and running ``mean``, ``var``; ``keeps[i]`` its
+    mask or None.
+
+    ``weights`` is ``(w0, ..., w_{L-1})``; ``biases`` optionally one a
+    layer (a ``None`` entry drops that bias).  Returns ``(chain,
+    params)``.
     """
-    w0, w1 = weights
-    b0, b1 = biases if biases is not None else (None, None)
-    chain = [spmm_node(schedule, label="gcn0"),
-             ewise(activation, bias=b0 is not None, label="gcn0.ep"),
-             spmm_node(schedule, label="gcn1")]
-    params = [{"a": adj, "w": w0}, {"bias": b0}, {"a": adj, "w": w1}]
-    if final_activation is not None or b1 is not None:
-        chain.append(ewise(final_activation, bias=b1 is not None,
-                           label="gcn1.ep"))
-        params.append({"bias": b1})
+    n_layers = len(weights)
+    biases = biases if biases is not None else (None,) * n_layers
+    chain, params = [], []
+    for i, (w, b) in enumerate(zip(weights, biases)):
+        chain.append(spmm_node(schedule, label=f"gcn{i}"))
+        params.append({"a": adj, "w": w})
+        if i < n_layers - 1 and norm is None:
+            chain.append(ewise(activation, bias=b is not None,
+                               label=f"gcn{i}.ep"))
+            params.append({"bias": b})
+        elif i < n_layers - 1:
+            if b is not None:
+                chain.append(ewise(bias=True, label=f"gcn{i}.ep"))
+                params.append({"bias": b})
+            chain.append(norm_node(norm, activation=activation,
+                                   label=f"gcn.layer{i}"))
+            params.append({**norms[i],
+                           "keep": None if keeps is None else keeps[i]})
+        elif final_activation is not None or b is not None:
+            chain.append(ewise(final_activation, bias=b is not None,
+                               label=f"gcn{i}.ep"))
+            params.append({"bias": b})
     return tuple(chain), params
 
 

@@ -18,7 +18,10 @@ what the producer's grid writes.  Two families:
   the producer's output blocking — and a non-additive consumer monoid
   additionally cannot be composed from the producer's blocked partial
   sums (``min(a+b) != min(a)+min(b)``).  They anchor a new launch; the
-  split reason records which of the two arguments applied.
+  split reason records which of the two arguments applied;
+* **norm consumers** never fuse either: a batch norm's statistics are
+  over every row, and a kernel's epilogue sees one output block.  The
+  norm anchors an XLA launch of its own, which ends the fold.
 
 Kernel-specific operand limits also live here (grouped_matmul has no
 residual operand in the expert-sorted layout), so the planner and the
@@ -31,7 +34,7 @@ from typing import Optional, Tuple
 from ..core.schedule import Epilogue
 from .ir import EPILOGUE_CAPABLE, FuseNode, Launch
 
-__all__ = ["can_fuse", "ewise_fusable", "reduce_fusable"]
+__all__ = ["can_fuse", "ewise_fusable", "norm_fusable", "reduce_fusable"]
 
 
 def ewise_fusable(launch: Launch,
@@ -64,6 +67,14 @@ def reduce_fusable(launch: Launch,
     return None, (f"consumer '{node.kind}' reduces over its own "
                   "iteration space; its segment structure does not "
                   "align with the producer's output blocking")
+
+
+def norm_fusable(launch: Launch,
+                 node: FuseNode) -> Tuple[Optional[Epilogue], str]:
+    """A norm never folds into a producer launch: its statistics are
+    over every row, and a kernel's epilogue sees one output block."""
+    return None, (f"norm '{node.tag}' needs statistics over every row; "
+                  "a kernel's epilogue sees one output block")
 
 
 def can_fuse(launch: Launch,

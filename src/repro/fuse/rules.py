@@ -16,13 +16,15 @@ rather than ad-hoc ``Schedule`` fields:
 * ``epilogue-fold`` — elementwise consumers fold into the producer's
   epilogue slot exactly when ``Epilogue.extended`` accepts them
   (``legality.ewise_fusable``);
+* ``norm-split`` — a norm consumer anchors a launch of its own: its
+  statistics are over every row (``legality.norm_fusable``);
 * ``monoid-split`` — reducing consumers anchor a new launch, with the
   monoid-compatibility reason when their monoid is non-additive
   (``legality.reduce_fusable``).
 
 To land a new fusion (say, folding a norm into a kernel that grows a
 norm slot): implement the capability in the kernel, then
-``register_rule("norm-fold", fn, before="monoid-split")`` with ``fn``
+``register_rule("norm-fold", fn, before="norm-split")`` with ``fn``
 deciding from the launch anchor and the node — no planner changes.
 DESIGN.md §10 walks through this.
 """
@@ -97,6 +99,14 @@ def _epilogue_fold(launch: Launch, node: FuseNode):
     return ewise_fusable(launch, node)
 
 
+def _norm_split(launch: Launch, node: FuseNode):
+    if node.kind != "norm":
+        return None
+    from .legality import norm_fusable
+
+    return norm_fusable(launch, node)
+
+
 def _monoid_split(launch: Launch, node: FuseNode):
     if node.kind == "ewise":
         return None
@@ -106,4 +116,5 @@ def _monoid_split(launch: Launch, node: FuseNode):
 
 
 register_rule("epilogue-fold", _epilogue_fold)
+register_rule("norm-split", _norm_split)
 register_rule("monoid-split", _monoid_split)

@@ -14,6 +14,7 @@ from ..core.schedule import ACTIVATIONS, Epilogue, Schedule
 from ..sparse.formats import (
     CSR,
     ELL,
+    BlockedCOO,
     GroupedCOO,
     QuantizedCSR,
     _memoized,
@@ -23,8 +24,9 @@ from . import ref
 from .common import VMEM_CAPACITY, VMEM_HEADROOM, segment_window, window_start
 from .grouped_matmul import grouped_matmul as _gmm_pallas
 from .sddmm import sddmm as _sddmm_kernel
+from .spmm_eb import eb_windows, vmem_need_eb
 from .spmm_eb import spmm_eb as _spmm_eb
-from .spmm_eb import vmem_need_eb
+from .spmm_eb import spmm_eb_stream as _spmm_eb_stream
 from .spmm_rb import rb_width_tile, vmem_need_rb
 from .spmm_rb import spmm_rb as _spmm_rb
 
@@ -54,16 +56,33 @@ def _col_tile(sched: Schedule, n: int) -> int:
     return min(round_up(sched.col_tile, 128), round_up(n, 8))
 
 
-def vmem_footprint_eb(k, n_rows, sched: Schedule) -> int:
+def _eb_need(sched: Schedule, col_tile: int, n=None) -> dict:
+    """``spmm_eb.vmem_need_eb``'s keywords for a schedule's launch."""
+    vd = sched.value_dtype
+    return dict(nnz_tile=sched.nnz_tile, col_tile=col_tile, n=n,
+                b_dtype=operand_dtype(vd), vals_dtype=storage_dtype(vd),
+                epilogue=sched.epilogue, strategy=sched.strategy)
+
+
+def vmem_footprint_eb(k, n_rows, sched: Schedule, windows=None) -> int:
     """VMEM the EB kernel claims for this schedule when the dense operand
     spans more than one column tile: padded (sublane, 128) tiles and
     pipeline buffers of the schedule's value storage and dense operand,
-    as ``spmm_eb.vmem_need_eb`` counts them."""
-    vd = sched.value_dtype
-    return vmem_need_eb(k, n_rows, nnz_tile=sched.nnz_tile,
-                        col_tile=sched.col_tile, b_dtype=operand_dtype(vd),
-                        vals_dtype=storage_dtype(vd), epilogue=sched.epilogue,
-                        strategy=sched.strategy)
+    as ``spmm_eb.vmem_need_eb`` counts them (``windows``: of the
+    windowed launch)."""
+    return vmem_need_eb(k, n_rows, windows=windows,
+                        **_eb_need(sched, sched.col_tile))
+
+
+def eb_stream_windows(shape, n: int, sched: Schedule):
+    """``(row_window, col_window)`` of the windowed eb launch ``spmm``
+    runs for an ``shape`` matrix times an ``n``-wide dense operand under
+    ``sched``, or None where the resident launch fits the VMEM budget
+    (``spmm_eb.eb_windows``): decided from the operand and output bytes
+    alone."""
+    col_tile = _col_tile(sched, n)
+    return eb_windows(shape[1], shape[0], budget=_VMEM_BUDGET,
+                      **_eb_need(sched, col_tile, round_up(n, col_tile)))
 
 
 def vmem_footprint_rb(k, width, sched: Schedule) -> int:
@@ -77,13 +96,31 @@ def vmem_footprint_rb(k, width, sched: Schedule) -> int:
                         vals_dtype=storage_dtype(vd), epilogue=sched.epilogue)
 
 
+def eb_stream_layout(a: CSR, n: int, sched: Schedule):
+    """``(BlockedCOO, col_tile)`` of the windowed eb launch that
+    ``spmm(a, b, sched)`` runs for an ``n``-wide ``b`` (the memoized
+    partition itself), or None where the launch runs resident."""
+    windows = eb_stream_windows(a.shape, n, sched)
+    if windows is None:
+        return None
+    return a.blocked(sched.nnz_tile, windows), _col_tile(sched, n)
+
+
 def schedule_fits_vmem(sched: Schedule, *, n_rows: int, n_cols: int,
                        row_max: int = 0, budget: int = _VMEM_BUDGET) -> bool:
     """Whether a schedule's per-cell working set fits the VMEM budget —
     the feasibility predicate the autotuner prunes candidates with before
-    spending measurement time on them."""
+    spending measurement time on them.  An eb schedule is counted in the
+    layout its launch runs: resident, or windowed where that does not
+    fit."""
     if sched.kernel == "eb":
-        need = vmem_footprint_eb(n_cols, n_rows, sched)
+        # the windowed launch stands in where the resident one does not fit
+        try:
+            windows = eb_windows(n_cols, n_rows, budget=budget,
+                                 **_eb_need(sched, sched.col_tile))
+        except ValueError:
+            return False
+        need = vmem_footprint_eb(n_cols, n_rows, sched, windows)
     else:
         need = vmem_footprint_rb(n_cols, max(row_max, 1), sched)
     return need <= budget
@@ -131,15 +168,18 @@ def _cast_stream(fmt, vals, dt):
 
 def spmm(a, b, schedule: Schedule | None = None, *,
          bias=None, residual=None, impl: str = "pallas"):
-    """out = A @ B for sparse A (CSR / QuantizedCSR / GroupedCOO / ELL)
-    and dense B, with the schedule's fused epilogue applied in-kernel.
+    """out = A @ B for sparse A (CSR / QuantizedCSR / GroupedCOO /
+    BlockedCOO / ELL) and dense B, with the schedule's fused epilogue
+    applied in-kernel.
 
     impl='ref' runs the pure-jnp oracle (epilogue applied via its
     executable spec); impl='pallas' runs the kernel the schedule selects
     (eb -> GroupedCOO path, rb -> ELL path).  CSR inputs convert through
-    the per-(format, tile) cache on CSR.  ``bias`` (N,) / ``residual``
-    (n_rows, N) are required exactly when ``schedule.epilogue`` declares
-    them.
+    the per-(format, tile) cache on CSR.  Where the eb launch's blocks
+    exceed the VMEM budget (:func:`eb_stream_windows`), the lanes run as
+    a BlockedCOO through the windowed launch, ``spmm_eb_stream``.
+    ``bias`` (N,) / ``residual`` (n_rows, N) are required exactly when
+    ``schedule.epilogue`` declares them.
 
     ``schedule.value_dtype`` (DESIGN.md §13) selects the storage width
     the kernel *moves*: narrow floats cast the value stream and B to
@@ -163,6 +203,9 @@ def spmm(a, b, schedule: Schedule | None = None, *,
             a = a.dequantize()
         if isinstance(a, GroupedCOO):
             out = ref.spmm_coo_ref(a.rows, a.cols, a.vals, b, a.shape[0])
+        elif isinstance(a, BlockedCOO):
+            rows, cols = a.global_lanes()
+            out = ref.spmm_coo_ref(rows, cols, a.vals, b, a.shape[0])
         elif isinstance(a, CSR):
             coo = a.tocoo()
             out = ref.spmm_coo_ref(coo.rows, coo.cols, coo.vals, b,
@@ -196,11 +239,36 @@ def spmm(a, b, schedule: Schedule | None = None, *,
     n_pad = b_pad.shape[1]
 
     if schedule.kernel == "eb":
+        windows = eb_stream_windows(a.shape, n, schedule)
+        if windows is not None and scales is not None:
+            raise NotImplementedError(
+                "int8 codes run the resident eb launch only; these operands "
+                "exceed VMEM")
         skew_kw = dict(group_size=schedule.group_size,
                        split_threshold=schedule.split_threshold,
                        merge_threshold=schedule.merge_threshold)
+        if windows is not None and isinstance(a, CSR):
+            a = a.blocked(schedule.nnz_tile, windows)
         if isinstance(a, CSR):
             a = a.grouped(schedule.nnz_tile, **skew_kw)
+        if windows is not None and isinstance(a, GroupedCOO):
+            a = a.regrouped(schedule.nnz_tile).blocked(windows)
+        if isinstance(a, BlockedCOO):
+            if a.nnz_tile != schedule.nnz_tile:
+                raise ValueError(f"a BlockedCOO of {a.nnz_tile}-lane tiles "
+                                 f"under a {schedule.nnz_tile}-lane schedule")
+            vals = (a.vals if vd is None
+                    else _cast_stream(a, a.vals, storage_dtype(vd)))
+            bias_p, res_p = _pad_epilogue_operands(ep, bias, residual,
+                                                   a.shape[0], n_pad)
+            out = _spmm_eb_stream(
+                a.rows, a.cols, vals, a.tile_rows, a.tile_cols, b_pad,
+                n_rows=a.shape[0], row_window=a.windows[0],
+                col_window=a.windows[1], nnz_tile=a.nnz_tile,
+                col_tile=col_tile, group_size=schedule.group_size,
+                strategy=schedule.strategy, epilogue=ep, bias=bias_p,
+                residual=res_p)
+            return out[:, :n]
         assert isinstance(a, GroupedCOO), type(a)
         a = a.regrouped(schedule.nnz_tile, **skew_kw)  # memoized; no-op
         vals = (a.vals if vd is None or scales is not None

@@ -32,8 +32,18 @@ out block (n_rows × COL_TILE), both resident for a whole column block,
 both lane-padded to 128 and double-buffered unless one column tile
 covers N, plus the partials (NNZ_TILE × COL_TILE) and, where the window
 reduce engages, the row ids as a second, VMEM lane block.  The launch
-asks the compiler for that plus headroom (``common.pallas_call``); operands
-whose blocks exceed the chip's VMEM are refused, not windowed.
+asks the compiler for that plus headroom (``common.pallas_call``).
+
+Operands whose blocks exceed the VMEM budget run windowed, as the launch
+``spmm_eb_stream`` (:func:`spmm_eb_stream`, same kernel body): the lanes
+come as a 2-D block partition (``formats.BlockedCOO``: row window ×
+column window, each block's tiles consecutive and a row window's blocks
+consecutive), each tile's two window ids are scalar-prefetched
+(``PrefetchScalarGridSpec``), and the block specs fetch the B rows of the
+tile's column window and keep the output rows of its row window resident
+across that window's tiles.  The output window is zeroed on its first
+tile and takes the epilogue on its last.  :func:`eb_windows` sizes the
+windows from the budget.
 """
 from __future__ import annotations
 
@@ -45,6 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.schedule import Epilogue
+from ..sparse.formats import round_up
 from .common import (
     apply_epilogue,
     block_buffers,
@@ -63,7 +74,10 @@ _NOOP = Epilogue()
 def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
                     group_size: int, strategy: str, heavy_tiles: int,
                     epilogue: Epilogue, narrowed: bool, quantized: bool,
-                    window: bool):
+                    window: bool, first=None, last=None):
+    """One grid step.  ``first`` / ``last`` say whether this nnz step is
+    the first / last one of its output block (default: of the whole nnz
+    axis, where one output block spans it)."""
     if window:
         lanes_ref, *refs = refs
     if quantized:
@@ -75,7 +89,7 @@ def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
     # the final store (out_ref doubles as the accumulator otherwise)
     acc = out_ref if acc_ref is None else acc_ref
 
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(pl.program_id(1) == 0 if first is None else first)
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
@@ -123,7 +137,8 @@ def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
         reduce()
 
     if not epilogue.is_noop:
-        @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+        @pl.when(pl.program_id(1) == pl.num_programs(1) - 1 if last is None
+                 else last)
         def _epilogue():
             apply_epilogue(out_ref, epilogue, bias_ref, res_ref,
                            acc_ref=acc_ref)
@@ -132,7 +147,7 @@ def _spmm_eb_kernel(rows_ref, cols_ref, vals_ref, b_ref, *refs,
 def vmem_need_eb(k: int, n_rows: int, *, nnz_tile: int, col_tile: int,
                  n: int | None = None, b_dtype=jnp.float32,
                  vals_dtype=jnp.float32, epilogue: Epilogue = _NOOP,
-                 strategy: str = "segment") -> int:
+                 strategy: str = "segment", windows=None) -> int:
     """Padded, buffered VMEM bytes of one ``spmm_eb`` launch over an
     N-wide dense operand (``n=None``: more than one column tile): the
     whole-K B column block and the whole-n_rows output column block
@@ -143,9 +158,16 @@ def vmem_need_eb(k: int, n_rows: int, *, nnz_tile: int, col_tile: int,
     (``common.segment_window``).  Row/column indices and scales live in
     SMEM.
 
+    ``windows=(row_window, col_window)`` counts the ``spmm_eb_stream``
+    launch instead: the B block is ``col_window`` rows and the output
+    (and residual) block ``row_window`` rows, each double-buffered
+    unless it never moves (one window and one column tile).
+
     This is the compiler's scoped allocation when XLA leaves the
     operands in HBM (``tests/test_tpu_compile.py`` checks it to the
-    byte).  XLA may place narrow operands in VMEM itself — at PubMed
+    byte; for the windowed launch at ogbn-arxiv's size the compiler
+    leaves the lane and bias blocks, a few KiB, out of it).  XLA may
+    place narrow operands in VMEM itself — at PubMed
     size with 16 columns it places B, the output and the value lanes
     there (``S(1)`` in the compiled HLO).  The kernel then reads them
     in place and only the partials scratch stays scoped; those blocks
@@ -153,27 +175,169 @@ def vmem_need_eb(k: int, n_rows: int, *, nnz_tile: int, col_tile: int,
     what the launch holds and the limit it asks for over-reserves by
     them."""
     out_dtype = jnp.dtype(epilogue.out_dtype or jnp.float32)
-    cb = block_buffers(1 if n == col_tile else 2)
-    need = (vmem_bytes((k, col_tile), b_dtype, cb)
-            + vmem_bytes((n_rows, col_tile), out_dtype, cb)
+    one_tile = n == col_tile
+    b_rows, out_rows = (k, n_rows) if windows is None else windows[::-1]
+    bb = block_buffers(1 if one_tile and b_rows >= k else 2)
+    ob = block_buffers(1 if one_tile and out_rows >= n_rows else 2)
+    need = (vmem_bytes((b_rows, col_tile), b_dtype, bb)
+            + vmem_bytes((out_rows, col_tile), out_dtype, ob)
             + vmem_bytes((1, nnz_tile), vals_dtype, 2)
             + vmem_bytes((nnz_tile, col_tile), jnp.float32))
-    if segment_window(strategy, n_rows, nnz_tile) is not None:
+    if segment_window(strategy, out_rows, nnz_tile) is not None:
         need += vmem_bytes((1, nnz_tile), jnp.int32, 2)
     if epilogue.bias:
-        need += vmem_bytes((1, col_tile), jnp.float32, cb)
+        need += vmem_bytes((1, col_tile), jnp.float32,
+                           block_buffers(1 if one_tile else 2))
     if epilogue.residual:
-        need += vmem_bytes((n_rows, col_tile), jnp.float32, cb)
+        need += vmem_bytes((out_rows, col_tile), jnp.float32, ob)
     if out_dtype != jnp.float32:
-        need += vmem_bytes((n_rows, col_tile), jnp.float32)
+        need += vmem_bytes((out_rows, col_tile), jnp.float32)
     return need
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_rows", "nnz_tile", "col_tile", "group_size",
-                     "strategy", "heavy_tiles", "epilogue", "interpret"),
-)
+def eb_windows(k: int, n_rows: int, *, budget: int, **need):
+    """``(row_window, col_window)`` of the ``spmm_eb_stream`` launch over
+    a ``k``-row B and ``n_rows`` output rows, or None where the resident
+    launch's blocks (``vmem_need_eb(k, n_rows, **need)``) fit ``budget``
+    bytes.
+
+    B is fetched once a block a tile visits, so fewer row windows move
+    fewer bytes: the output window is the largest that holds at most
+    half the budget (rows split evenly, 8-aligned), then the column
+    window the largest that fits what is left.  A budget that not even
+    8-row windows fit raises ``ValueError``."""
+    if vmem_need_eb(k, n_rows, **need) <= budget:
+        return None
+
+    def split(extent, parts):
+        return round_up(-(-extent // parts), 8)
+
+    def fits(rw, cw, limit):
+        return vmem_need_eb(k, n_rows, windows=(rw, cw), **need) <= limit
+
+    # the output's share alone: with 8-row B windows, under half the budget
+    parts = 1
+    while split(n_rows, parts) > 8 and not fits(split(n_rows, parts), 8,
+                                                budget // 2):
+        parts += 1
+    rw = split(n_rows, parts)
+    parts = 1
+    while split(k, parts) > 8 and not fits(rw, split(k, parts), budget):
+        parts += 1
+    cw = split(k, parts)
+    if not fits(rw, cw, budget):
+        raise ValueError(f"no eb layout of {k} B rows and {n_rows} output "
+                         f"rows fits {budget} bytes of VMEM")
+    return rw, cw
+
+
+def _lanes(x, nnz_tile: int):
+    """Lanes as (tiles, 1, nnz_tile): one (1, nnz_tile) block a step."""
+    return x.reshape(-1, 1, nnz_tile)
+
+
+def _eb_call(rows, cols, vals, b, *, n_rows, nnz_tile, col_tile, group_size,
+             strategy, heavy_tiles, epilogue, scales, bias, residual,
+             interpret, stream=None):
+    """The ``pallas_call`` of both eb launches over shape-strict operands.
+    ``stream`` is None for the resident launch, else ``(tile_rows,
+    tile_cols, row_window, col_window)`` of the windowed one."""
+    nnz_pad = vals.shape[0]
+    k, n = b.shape
+    assert nnz_pad % nnz_tile == 0 and n % col_tile == 0, (nnz_pad, n)
+    grid = (n // col_tile, nnz_pad // nnz_tile)
+    if stream is None:
+        out_rows, b_rows = n_rows, k
+        out_block = lambda j, i, *_: (0, j)  # noqa: E731
+        b_block = out_block
+        prefetch = []
+    else:
+        tile_rows, tile_cols, out_rows, b_rows = stream
+        assert tile_rows.shape == tile_cols.shape == (grid[1],), grid
+        out_block = lambda j, i, tr, tc: (tr[i], j)  # noqa: E731
+        b_block = lambda j, i, tr, tc: (tc[i], j)  # noqa: E731
+        prefetch = [tile_rows, tile_cols]
+
+    def lane_spec(space=None):
+        return pl.BlockSpec((None, 1, nnz_tile), lambda j, i, *_: (i, 0, 0),
+                            memory_space=space)
+
+    # indices to SMEM (scalar reads), values to VMEM
+    operands = [_lanes(rows, nnz_tile), _lanes(cols, nnz_tile),
+                _lanes(vals, nnz_tile), b]
+    in_specs = [lane_spec(pltpu.SMEM), lane_spec(pltpu.SMEM), lane_spec(),
+                pl.BlockSpec((b_rows, col_tile), b_block)]
+    window = segment_window(strategy, out_rows, nnz_tile) is not None
+    if window:
+        # the row ids once more, as a VMEM lane block: the window's 0/1
+        # row-match matrix and its bounds are computed from them as vectors
+        operands.append(_lanes(rows, nnz_tile))
+        in_specs.append(lane_spec())
+    quantized = scales is not None
+    if quantized:
+        assert stream is None, "int8 codes run the resident launch only"
+        assert scales.shape == (n_rows,), (scales.shape, n_rows)
+        operands.append(scales.reshape(1, n_rows))
+        in_specs.append(pl.BlockSpec((1, n_rows), lambda j, i: (0, 0),
+                                     memory_space=pltpu.SMEM))
+    if epilogue.bias:
+        assert bias is not None and bias.shape == (1, n), (n, bias)
+        operands.append(bias)
+        in_specs.append(pl.BlockSpec((1, col_tile), lambda j, i, *_: (0, j)))
+    if epilogue.residual:
+        assert residual is not None and residual.shape == (n_rows, n)
+        operands.append(residual)
+        in_specs.append(pl.BlockSpec((out_rows, col_tile), out_block))
+    out_dtype = jnp.dtype(epilogue.out_dtype or jnp.float32)
+    narrowed = out_dtype != jnp.float32
+    scratch = ([pltpu.VMEM((out_rows, col_tile), jnp.float32)]
+               if narrowed else [])
+    scratch.append(pltpu.VMEM((nnz_tile, col_tile), jnp.float32))
+
+    kernel = functools.partial(
+        _spmm_eb_kernel, group_size=group_size, strategy=strategy,
+        heavy_tiles=heavy_tiles, epilogue=epilogue, narrowed=narrowed,
+        quantized=quantized, window=window)
+    grid_spec = dict(
+        grid=grid, in_specs=in_specs,
+        out_specs=pl.BlockSpec((out_rows, col_tile), out_block),
+        scratch_shapes=scratch)
+    if stream is not None:
+        kernel = functools.partial(_stream_kernel, kernel)
+        grid_spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), **grid_spec))
+    return pallas_call(
+        kernel,
+        name="spmm_eb" if stream is None else "spmm_eb_stream",
+        out_shape=jax.ShapeDtypeStruct((n_rows, n), out_dtype),
+        vmem_need=vmem_need_eb(
+            k, n_rows, nnz_tile=nnz_tile, col_tile=col_tile, n=n,
+            b_dtype=b.dtype, vals_dtype=vals.dtype, epilogue=epilogue,
+            strategy=strategy,
+            windows=None if stream is None else (out_rows, b_rows)),
+        interpret=interpret,
+        **grid_spec,
+    )(*prefetch, *operands)
+
+
+def _stream_kernel(kernel, tile_rows_ref, tile_cols_ref, *refs):
+    """The windowed launch's grid step: the eb kernel body, its output
+    block zeroed on the first tile of its row window and finished on the
+    last (a row window's tiles are consecutive)."""
+    del tile_cols_ref  # read by the block specs alone
+    i, n = pl.program_id(1), pl.num_programs(1)
+    win = tile_rows_ref[i]
+    first = jnp.logical_or(i == 0, tile_rows_ref[jnp.maximum(i - 1, 0)] != win)
+    last = jnp.logical_or(i == n - 1,
+                          tile_rows_ref[jnp.minimum(i + 1, n - 1)] != win)
+    kernel(*refs, first=first, last=last)
+
+
+_STATIC = ("n_rows", "nnz_tile", "col_tile", "group_size", "strategy",
+           "epilogue", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("heavy_tiles",))
 def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
             col_tile: int = 128, group_size: int = 32,
             strategy: str = "segment", heavy_tiles: int = 0,
@@ -204,68 +368,32 @@ def spmm_eb(rows, cols, vals, b, *, n_rows: int, nnz_tile: int = 256,
     ``interpret`` defaults to the backend's answer (``common.pallas_call``);
     a test compiles for a described TPU by passing ``False``.
     """
-    nnz_pad = vals.shape[0]
-    k, n = b.shape
-    assert nnz_pad % nnz_tile == 0 and n % col_tile == 0, (nnz_pad, n)
-    grid = (n // col_tile, nnz_pad // nnz_tile)
+    return _eb_call(rows, cols, vals, b, n_rows=n_rows, nnz_tile=nnz_tile,
+                    col_tile=col_tile, group_size=group_size,
+                    strategy=strategy, heavy_tiles=heavy_tiles,
+                    epilogue=epilogue, scales=scales, bias=bias,
+                    residual=residual, interpret=interpret)
 
-    # lanes travel as (tiles, 1, nnz_tile): one (1, nnz_tile) block per
-    # nnz step — indices to SMEM (scalar reads), values to VMEM
-    def lanes(x):
-        return x.reshape(-1, 1, nnz_tile)
 
-    def lane_spec(space=None):
-        return pl.BlockSpec((None, 1, nnz_tile), lambda j, i: (i, 0, 0),
-                            memory_space=space)
-
-    operands = [lanes(rows), lanes(cols), lanes(vals), b]
-    in_specs = [
-        lane_spec(pltpu.SMEM),
-        lane_spec(pltpu.SMEM),
-        lane_spec(),
-        pl.BlockSpec((k, col_tile), lambda j, i: (0, j)),
-    ]
-    window = segment_window(strategy, n_rows, nnz_tile) is not None
-    if window:
-        # the row ids once more, as a VMEM lane block: the window's 0/1
-        # row-match matrix and its bounds are computed from them as vectors
-        operands.append(lanes(rows))
-        in_specs.append(lane_spec())
-    quantized = scales is not None
-    if quantized:
-        assert scales.shape == (n_rows,), (scales.shape, n_rows)
-        operands.append(scales.reshape(1, n_rows))
-        in_specs.append(pl.BlockSpec((1, n_rows), lambda j, i: (0, 0),
-                                     memory_space=pltpu.SMEM))
-    if epilogue.bias:
-        assert bias is not None and bias.shape == (1, n), (n, bias)
-        operands.append(bias)
-        in_specs.append(pl.BlockSpec((1, col_tile), lambda j, i: (0, j)))
-    if epilogue.residual:
-        assert residual is not None and residual.shape == (n_rows, n)
-        operands.append(residual)
-        in_specs.append(
-            pl.BlockSpec((n_rows, col_tile), lambda j, i: (0, j)))
-    out_dtype = jnp.dtype(epilogue.out_dtype or jnp.float32)
-    narrowed = out_dtype != jnp.float32
-    scratch = [pltpu.VMEM((n_rows, col_tile), jnp.float32)] if narrowed else []
-    scratch.append(pltpu.VMEM((nnz_tile, col_tile), jnp.float32))
-
-    kernel = functools.partial(
-        _spmm_eb_kernel, group_size=group_size, strategy=strategy,
-        heavy_tiles=heavy_tiles, epilogue=epilogue, narrowed=narrowed,
-        quantized=quantized, window=window)
-    return pallas_call(
-        kernel,
-        name="spmm_eb",
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((n_rows, col_tile), lambda j, i: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((n_rows, n), out_dtype),
-        scratch_shapes=scratch,
-        vmem_need=vmem_need_eb(k, n_rows, nnz_tile=nnz_tile,
-                               col_tile=col_tile, n=n, b_dtype=b.dtype,
-                               vals_dtype=vals.dtype, epilogue=epilogue,
-                               strategy=strategy),
-        interpret=interpret,
-    )(*operands)
+@functools.partial(jax.jit, static_argnames=_STATIC + ("row_window",
+                                                       "col_window"))
+def spmm_eb_stream(rows, cols, vals, tile_rows, tile_cols, b, *, n_rows: int,
+                   row_window: int, col_window: int, nnz_tile: int = 256,
+                   col_tile: int = 128, group_size: int = 32,
+                   strategy: str = "segment", epilogue: Epilogue = _NOOP,
+                   bias=None, residual=None, interpret: bool | None = None):
+    """:func:`spmm_eb` over operands windowed to fit VMEM: the lanes of a
+    ``formats.BlockedCOO`` (``rows`` within their row window, ``cols``
+    within their column window, each tile's windows ``tile_rows`` and
+    ``tile_cols``, a row window's tiles consecutive and at least one a
+    row window), B fetched ``col_window`` rows at a time, the output
+    kept ``row_window`` rows at a time.  The last window of either may
+    run past the array: its rows past the end are never gathered, and
+    never written back.  Same epilogue operands as :func:`spmm_eb`; no
+    int8 codes, no skew layout."""
+    return _eb_call(rows, cols, vals, b, n_rows=n_rows, nnz_tile=nnz_tile,
+                    col_tile=col_tile, group_size=group_size,
+                    strategy=strategy, heavy_tiles=0, epilogue=epilogue,
+                    scales=None, bias=bias, residual=residual,
+                    interpret=interpret,
+                    stream=(tile_rows, tile_cols, row_window, col_window))

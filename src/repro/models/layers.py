@@ -82,11 +82,73 @@ def gcn_layer(adj, x, w, b=None, *, activation="relu", residual=None,
                 epilogue=ep)
 
 
+def gcn(adj, x, params, *, norm=None, stats=None, keeps=None,
+        activation="relu", final_activation=None, schedule=None, plan=None):
+    """An L-layer GCN, ``L`` the number of weights ``w0 .. w{L-1}`` in
+    ``params``: layer ``i`` is ``Ã (H Wᵢ) + bᵢ`` (``b<i>`` optional),
+    with ``activation`` between layers and ``final_activation`` after the
+    last.  Built as a ``repro.fuse`` chain (``gcn_chain``) and executed by
+    the fusion planner: each bias (and, without a norm, each activation)
+    folds into its SpMM's epilogue, so the model runs in L Pallas
+    launches.
+
+    ``norm``, a :class:`repro.fuse.BatchNorm`, puts a batch norm after
+    each hidden layer, then the activation, then the dropout mask
+    ``keeps["keep<i>"]`` (0 or 1/(1-p), optional): OGB's GCN (conv →
+    BatchNorm → ReLU → dropout).  The norm runs in XLA between the
+    launches, under ``gcn.layer<i>`` with its children ``norm`` and
+    ``act``.  It takes ``params["gamma<i>"]`` and ``["beta<i>"]`` and
+    the running ``stats["mean<i>"]`` and ``["var<i>"]`` (in training,
+    zeros and ones where ``stats`` is None).
+
+    Returns ``(logits, stats)``: with a norm, the running statistics it
+    leaves (moved by the batch in training, as given in evaluation);
+    ``{}`` without.  ``plan`` overrides the greedy plan; ``schedule``
+    rides on every SpMM anchor (``None`` → per-matrix auto selection).
+    Differentiable in ``x`` and the parameters through the planned
+    launches' custom VJPs."""
+    from ..fuse import gcn_chain
+    from ..fuse import plan as plan_chain
+    from ..fuse import run_plan
+
+    n_layers = sum(1 for k in params if k.startswith("w"))
+    weights = tuple(params[f"w{i}"] for i in range(n_layers))
+    biases = tuple(params.get(f"b{i}") for i in range(n_layers))
+    norms = None
+    if norm is not None:
+        norms = []
+        for i in range(n_layers - 1):
+            width = weights[i].shape[1]
+            norms.append({
+                "gamma": params[f"gamma{i}"], "beta": params[f"beta{i}"],
+                "mean": (jnp.zeros((width,), jnp.float32) if stats is None
+                         else stats[f"mean{i}"]),
+                "var": (jnp.ones((width,), jnp.float32) if stats is None
+                        else stats[f"var{i}"])})
+        keeps = [None if keeps is None else keeps.get(f"keep{i}")
+                 for i in range(n_layers - 1)]
+    elif keeps:
+        raise ValueError("the dropout between layers runs in the norm "
+                         "launch: keeps need a norm")
+    chain, chain_params = gcn_chain(
+        adj, weights, biases, activation=activation,
+        final_activation=final_activation, schedule=schedule, norm=norm,
+        norms=norms, keeps=keeps)
+    p = plan_chain(chain) if plan is None else plan
+    running = {}
+    logits = run_plan(p, x, chain_params, stats=running)
+    out = {}
+    for i in range(n_layers - 1):
+        if f"gcn.layer{i}" in running:
+            out[f"mean{i}"], out[f"var{i}"] = running[f"gcn.layer{i}"]
+    return logits, out
+
+
 def gcn_two_layer(adj, x, w0, w1, b0=None, b1=None, *,
                   activation="relu", final_activation=None, schedule=None,
                   plan=None):
-    """Two-layer GCN — ``Ã act(Ã (x @ w0) + b0) @ w1 [+ b1]`` — built as
-    a ``repro.fuse`` chain and executed by the fusion planner: the
+    """Two-layer GCN — ``Ã act(Ã (x @ w0) + b0) @ w1 [+ b1]`` — the
+    L = 2 case of :func:`gcn` with no norm and no dropout: the
     activations/biases fold into their producing SpMM's epilogue, so the
     whole model is **2 Pallas launches** (DESIGN.md §10).
 
@@ -95,16 +157,10 @@ def gcn_two_layer(adj, x, w0, w1, b0=None, b1=None, *,
     timing); ``schedule`` rides on both SpMM anchors (``None`` →
     per-matrix auto selection).  Differentiable in ``x``/weights/biases
     through the planned launches' custom VJPs."""
-    from ..fuse import gcn_chain
-    from ..fuse import plan as plan_chain
-    from ..fuse import run_plan
-
-    chain, params = gcn_chain(adj, (w0, w1), (b0, b1),
-                              activation=activation,
-                              final_activation=final_activation,
-                              schedule=schedule)
-    p = plan_chain(chain) if plan is None else plan
-    return run_plan(p, x, params)
+    params = {"w0": w0, "w1": w1, "b0": b0, "b1": b1}
+    return gcn(adj, x, params, activation=activation,
+               final_activation=final_activation, schedule=schedule,
+               plan=plan)[0]
 
 
 def gat_layer(pattern, x, w, a_l, a_r, b, *, concat: bool = True,

@@ -1,6 +1,6 @@
 """repro.sparse — the single public sparse API.
 
-Formats (`CSR`, `COO`, `GroupedCOO`, `ELL`), generators (`random_csr`,
+Formats (`CSR`, `COO`, `GroupedCOO`, `BlockedCOO`, `ELL`), generators (`random_csr`,
 `power_law_csr`, `graph_pattern_csr`),
 the unified ops (`spmm`, `sddmm`, `segment_reduce`, `sparse_attention`,
 all taking ``schedule=``), and the scheduling surface re-exported from
@@ -24,6 +24,7 @@ from .distributed import (  # noqa: F401
 )
 from .formats import (  # noqa: F401
     COO,
+    BlockedCOO,
     CSR,
     ELL,
     GroupedCOO,

@@ -11,6 +11,9 @@ GroupedCOO       row-sorted COO padded to a multiple of ``nnz_tile`` — the
                  vector/MXU datapath and contribute nothing.
 ELL              per-row padded (blocked-ELL when viewed in row tiles) —
                  the feed format of the row-split (RB) kernel.
+BlockedCOO       GroupedCOO's lanes partitioned into 2-D blocks of (row
+                 window, column window) — the feed format of the windowed
+                 EB launch, for operands larger than VMEM.
 
 All formats carry their dense ``shape`` and padding parameters as static
 metadata so they can cross ``jit`` boundaries.
@@ -39,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["COO", "CSR", "GroupedCOO", "ELL", "QuantizedCSR",
+__all__ = ["COO", "CSR", "GroupedCOO", "BlockedCOO", "ELL", "QuantizedCSR",
            "quantize_csr", "dequantize", "round_up"]
 
 
@@ -280,6 +283,18 @@ class CSR:
                 self, nnz_tile, group_size=group_size,
                 split_threshold=split_threshold,
                 merge_threshold=merge_threshold))
+
+    def blocked(self, nnz_tile: int, windows: tuple) -> "BlockedCOO":
+        """Windowed EB-kernel feed format, memoized per ``(nnz_tile,
+        windows)``; a host pass over concrete indices."""
+        def _build():
+            indptr = _concrete_np(self.indptr, "the blocked layout")
+            rows = np.repeat(np.arange(self.shape[0]), np.diff(indptr))
+            return BlockedCOO.fromcoo(
+                rows, _concrete_np(self.indices, "the blocked layout"),
+                self.vals, self.shape, nnz_tile, windows)
+
+        return self._cached(("blocked", nnz_tile, tuple(windows)), _build)
 
     def ell(self, row_tile: int = 8, width: int | None = None) -> "ELL":
         """RB-kernel feed format, memoized per (row_tile, width)."""
@@ -543,11 +558,140 @@ class GroupedCOO:
                          ("regrouped", nnz_tile, group_size,
                           split_threshold, merge_threshold), _build)
 
+    def blocked(self, windows: tuple) -> "BlockedCOO":
+        """These lanes as a :class:`BlockedCOO` of the same tile,
+        memoized per ``windows``; a host pass over concrete indices."""
+        def _build():
+            rows, cols, vals = self._compact()
+            return BlockedCOO.fromcoo(
+                _concrete_np(rows, "the blocked layout"),
+                _concrete_np(cols, "the blocked layout"), vals, self.shape,
+                self.nnz_tile, windows)
+
+        return _memoized(self, (self.rows, self.cols, self.vals),
+                         ("blocked", tuple(windows)), _build)
+
     def todense(self) -> jax.Array:
         """Scatter-add the (padded) triplets into a dense array — padded
         lanes contribute zero by the zero-extension rule."""
         out = jnp.zeros(self.shape, self.vals.dtype)
         return out.at[self.rows, self.cols].add(self.vals)
+
+
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["rows", "cols", "vals", "tile_rows", "tile_cols"],
+    meta_fields=["shape", "nnz", "nnz_tile", "windows"],
+)
+@dataclasses.dataclass(frozen=True)
+class BlockedCOO:
+    """COO lanes in 2-D blocks — the feed format of the windowed EB
+    launch (``kernels.spmm_eb.spmm_eb_stream``), for a B or an output
+    larger than VMEM.
+
+    ``windows = (row_window, col_window)`` cut the output rows and the B
+    rows (the matrix's columns) into windows; block ``(r, c)`` holds the
+    entries whose row lies in row window ``r`` and column in column
+    window ``c``, row-sorted, padded to whole ``nnz_tile`` tiles.  Blocks
+    run row window by row window, so a row window's tiles are
+    consecutive, and a row window with no entry still gets one tile of
+    padding (its output rows are then zeroed and take the epilogue).
+    ``rows`` and ``cols`` are each lane's row and column *within* its
+    windows, and ``tile_rows`` / ``tile_cols`` (``(num_tiles,)``) each
+    tile's windows.  Padded lanes have ``val == 0`` and the row of their
+    block's last entry (row 0 in an empty row window), so a window
+    product's span does not grow; their column is 0.
+    """
+
+    rows: jax.Array  # (nnz_padded,) int32, within the row window
+    cols: jax.Array  # (nnz_padded,) int32, within the column window
+    vals: jax.Array  # (nnz_padded,)
+    tile_rows: jax.Array  # (num_tiles,) int32 row window of each tile
+    tile_cols: jax.Array  # (num_tiles,) int32 column window of each tile
+    shape: tuple
+    nnz: int
+    nnz_tile: int
+    windows: tuple
+
+    @property
+    def nnz_padded(self) -> int:
+        """Total lane count including padding (a ``nnz_tile`` multiple)."""
+        return self.vals.shape[0]
+
+    @property
+    def num_tiles(self) -> int:
+        """Grid extent along the nnz axis."""
+        return self.nnz_padded // self.nnz_tile
+
+    def positions(self) -> jax.Array:
+        """(nnz,) int32: the lane of each entry of the source triplets, in
+        their order, so that autodiff can place a fresh value stream
+        (``zeros.at[positions].set(vals)``).  Lost on pytree-transformed
+        copies."""
+        return self.__dict__["_positions"]
+
+    def with_vals(self, vals) -> "BlockedCOO":
+        """This layout with ``vals`` (nnz_padded,) as its value stream."""
+        out = dataclasses.replace(self, vals=vals)
+        object.__setattr__(out, "_positions", self.positions())
+        return out
+
+    def global_lanes(self):
+        """(rows, cols) of every lane in the matrix's own indices."""
+        rw, cw = self.windows
+        tile = lambda t: jnp.repeat(t, self.nnz_tile)  # noqa: E731
+        return (tile(self.tile_rows) * rw + self.rows,
+                tile(self.tile_cols) * cw + self.cols)
+
+    @staticmethod
+    def fromcoo(rows, cols, vals, shape, nnz_tile: int,
+                windows: tuple) -> "BlockedCOO":
+        """The blocked layout of triplets ``rows``/``cols`` (NumPy) and
+        ``vals``; ``rows`` need not be sorted."""
+        rw, cw = (int(w) for w in windows)
+        n_rw, n_cw = -(-shape[0] // rw), -(-max(shape[1], 1) // cw)
+        rows = np.asarray(rows, np.int64)
+        cols = np.asarray(cols, np.int64)
+        block = (rows // rw) * n_cw + cols // cw
+        order = np.lexsort((cols, rows, block))
+        counts = np.bincount(block, minlength=n_rw * n_cw)
+        tiles = -(-counts // nnz_tile)
+        empty = tiles.reshape(n_rw, n_cw).sum(axis=1) == 0
+        tiles.reshape(n_rw, n_cw)[empty, 0] = 1  # one tile a row window
+        starts = np.concatenate([[0], np.cumsum(tiles * nnz_tile)])
+        firsts = np.concatenate([[0], np.cumsum(counts)])
+        sorted_block = block[order]
+        slot = starts[sorted_block] + np.arange(order.size) - firsts[sorted_block]
+        total = int(starts[-1])
+        # each block's padding takes the row of its last entry
+        last_row = np.zeros(n_rw * n_cw, np.int64)
+        has = counts > 0
+        last_row[has] = rows[order[firsts[1:][has] - 1]] % rw
+        lane_rows = np.repeat(last_row, tiles * nnz_tile)
+        lane_cols = np.zeros(total, np.int64)
+        lane_rows[slot] = rows[order] % rw
+        lane_cols[slot] = cols[order] % cw
+        positions = np.empty(order.size, np.int64)
+        positions[order] = slot
+        ids = np.repeat(np.arange(n_rw * n_cw), tiles)
+        pos_j = jnp.asarray(positions, jnp.int32)
+        if isinstance(vals, jax.core.Tracer):
+            lane_vals = jnp.zeros((total,), vals.dtype).at[pos_j].set(vals)
+        else:  # placed on the host: a memo never holds a traced value
+            host = np.asarray(vals)
+            lane_vals = np.zeros((total,), host.dtype)
+            lane_vals[positions] = host
+            lane_vals = jnp.asarray(lane_vals)
+        out = BlockedCOO(
+            rows=jnp.asarray(lane_rows, jnp.int32),
+            cols=jnp.asarray(lane_cols, jnp.int32),
+            vals=lane_vals,
+            tile_rows=jnp.asarray(ids // n_cw, jnp.int32),
+            tile_cols=jnp.asarray(ids % n_cw, jnp.int32),
+            shape=tuple(shape), nnz=int(order.size), nnz_tile=nnz_tile,
+            windows=(rw, cw))
+        object.__setattr__(out, "_positions", pos_j)
+        return out
 
 
 @partial(

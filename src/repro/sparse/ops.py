@@ -158,12 +158,31 @@ def _spmm_csr_diff(a: CSR, b, sched: Schedule,
     float32 ReLU with no residual, over float32 value storage, ``y > 0``
     is ``act'`` itself and the backward takes it from there; every
     other epilogue recomputes ``A@B + bias`` (see :func:`_spmm_bwd`).
+
+    Where the eb launch's blocks exceed VMEM
+    (``kernels.ops.eb_stream_windows``), the forward runs the windowed
+    launch over ``a.blocked(...)``, its values placed by the layout's
+    ``positions`` (once, where ``a.vals`` is concrete); the backward is
+    the same.
     """
     ep = sched.epilogue
     coo = a.tocoo()  # cached on the CSR instance
     rows, cols = coo.rows, coo.cols
 
-    if sched.kernel == "eb":
+    windows = (kops.eb_stream_windows(a.shape, int(b.shape[1]), sched)
+               if sched.kernel == "eb" else None)
+    if windows is not None:
+        blk0 = a.blocked(sched.nnz_tile, windows)
+        pos = blk0.positions()
+        # concrete values are no operand anyone differentiates: the
+        # memoized layout's own copy stands for them, placed once
+        fixed = not isinstance(a.vals, jax.core.Tracer)
+
+        def run(vals, bb, bias_x, res_x):
+            blk = blk0 if fixed else blk0.with_vals(
+                jnp.zeros((blk0.nnz_padded,), vals.dtype).at[pos].set(vals))
+            return kops.spmm(blk, bb, sched, bias=bias_x, residual=res_x)
+    elif sched.kernel == "eb":
         g0 = a.grouped(sched.nnz_tile, group_size=sched.group_size,
                        split_threshold=sched.split_threshold,
                        merge_threshold=sched.merge_threshold)

@@ -70,11 +70,19 @@ DIST_VALUE_DTYPES = ("bfloat16", "float16")
 
 
 def _feasible(cands: List[Schedule], stats: dict) -> List[Schedule]:
+    """The candidates whose launch fits VMEM (an eb one in the layout it
+    runs, windowed where the resident one does not fit, so eb candidates
+    always stay); ``ValueError`` where none does, rather than a schedule
+    the compiler must refuse."""
     kept = [s for s in cands
             if schedule_fits_vmem(s, n_rows=stats["n_rows"],
                                   n_cols=stats["n_cols"],
                                   row_max=stats["row_max"])]
-    return kept or cands  # never let pruning empty the pool
+    if not kept:
+        raise ValueError(
+            f"no candidate schedule fits VMEM for a {stats['n_rows']} x "
+            f"{stats['n_cols']} matrix: {[str(s) for s in cands]}")
+    return kept
 
 
 def _dtype_parity_error(csr, n_dense_cols: int, vd: str) -> float:

@@ -1,5 +1,6 @@
 """Compile the GCN and GAT paths' Pallas kernels for a described TPU v5e
-at the widths of Planetoid PubMed — no chip needed.
+at the widths of Planetoid PubMed, and the windowed eb launch at those of
+ogbn-arxiv — no chip needed.
 
 Mosaic refuses patterns the interpreter accepts (gathers from VMEM
 values, dynamic slices of values, 1-D blocks that disagree with XLA's
@@ -22,7 +23,7 @@ from repro.core.schedule import Epilogue, Schedule
 from repro.kernels import common
 from repro.kernels.ops import vmem_footprint_eb
 from repro.kernels.segment_reduce import segment_reduce
-from repro.kernels.spmm_eb import spmm_eb, vmem_need_eb
+from repro.kernels.spmm_eb import spmm_eb, spmm_eb_stream, vmem_need_eb
 from repro.kernels.spmm_rb import spmm_rb
 from repro.sparse.formats import round_up
 from repro.sparse.random import PUBMED, gcn_graph_csr
@@ -174,6 +175,101 @@ def test_vmem_footprint_matches_compiler(one_chip, pubmed, monkeypatch,
     assert _compiles_within(fn, shapes, monkeypatch, scoped)
     assert not _compiles_within(fn, shapes, monkeypatch, scoped - 512)
     jax.clear_caches()
+
+
+#: ogbn-arxiv's adjacency (169,343 rows, 2,484,941 entries with the
+#: self-loops) in 128-lane tiles, as ``Schedule.auto`` feeds OGB's GCN,
+#: with room for each block's padding
+ARXIV_ROWS, ARXIV_TILES = 169343, 19500
+
+
+@pytest.mark.parametrize("width", [256, 40])
+def test_stream_vmem_matches_compiler(one_chip, monkeypatch, width):
+    """The windowed launch ``spmm_eb_stream`` at ogbn-arxiv's size, in the
+    windows ``eb_stream_windows`` picks for OGB's GCN widths (two column
+    tiles of 256, or one of 40, with the bias epilogue), compiles, and its
+    ``vmem_need_eb`` count is the compiler's scoped allocation to within
+    the launch's small blocks: it compiles under exactly that many bytes,
+    and is refused under that less the value and row-id lane blocks, the
+    bias block and 512.  (At PubMed's size the count is exact for this
+    launch too; at ogbn-arxiv's the compiler leaves those few KiB out of
+    its scoped allocation.)"""
+    from repro.kernels.ops import _col_tile, eb_stream_windows
+
+    sched = Schedule("eb", nnz_tile=128, col_tile=128, group_size=16,
+                     epilogue=Epilogue(bias=True))
+    windows = eb_stream_windows((ARXIV_ROWS, ARXIV_ROWS), width, sched)
+    assert windows is not None
+    col_tile = _col_tile(sched, width)
+    n = round_up(width, col_tile)
+
+    def fn(rows, cols, vals, tile_rows, tile_cols, b, bias):
+        return spmm_eb_stream(rows, cols, vals, tile_rows, tile_cols, b,
+                              n_rows=ARXIV_ROWS, row_window=windows[0],
+                              col_window=windows[1], nnz_tile=128,
+                              col_tile=col_tile, group_size=16,
+                              epilogue=sched.epilogue, bias=bias,
+                              interpret=False)
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lanes = ARXIV_TILES * 128
+    shapes = (s((lanes,), jnp.int32), s((lanes,), jnp.int32),
+              s((lanes,), jnp.float32), s((ARXIV_TILES,), jnp.int32),
+              s((ARXIV_TILES,), jnp.int32), s((ARXIV_ROWS, n), jnp.float32),
+              s((1, n), jnp.float32))
+    compiled = _compile(fn, *shapes)
+    _assert_kernel(compiled)
+    assert "spmm_eb_stream" in compiled.as_text()
+    scoped = vmem_need_eb(ARXIV_ROWS, ARXIV_ROWS, nnz_tile=128,
+                          col_tile=col_tile, n=n, epilogue=sched.epilogue,
+                          windows=windows)
+    small = (2 * common.vmem_bytes((1, 128), jnp.float32, 2)
+             + common.vmem_bytes((1, col_tile), jnp.float32, 2 if n > col_tile else 1))
+    assert _compiles_within(fn, shapes, monkeypatch, scoped)
+    assert not _compiles_within(fn, shapes, monkeypatch, scoped - small - 512)
+    jax.clear_caches()
+
+
+def test_deep_gcn_step_runs_windowed_launches(one_chip, monkeypatch):
+    """The deep GCN training cell's step (``bench/modes/train_gcn_deep.py``)
+    at a cut size whose launches run windowed (a VMEM budget lowered for
+    the test), compiled for the described chip: one forward launch a
+    layer, each ``spmm_eb_stream``, and no resident launch."""
+    import json
+
+    from bench import trace
+    from bench.modes import train as loop
+    from bench.modes import train_gcn_deep as mode
+    from bench.run import ROOT
+    from bench.traffic import gcn as graphs
+    from bench.traffic import gcn_deep as traffic
+    from repro.kernels import ops as kops
+
+    cfg = json.loads((ROOT / "bench" / "configs" / "gcn-arxiv.json").read_text())
+    cfg.update(n_nodes=300, n_edges=700, n_entries=1700, n_features=40,
+               hidden=32, n_classes=5, train_nodes=150)
+    monkeypatch.setattr(kops, "_VMEM_BUDGET", 250_000)
+    graph = graphs.config_graph(cfg)
+    with jax.default_matmul_precision(cfg["matmul_precision"]):
+        inputs = traffic.make_inputs(cfg, graph, 7)
+        program = mode.build_program(cfg, graph, inputs)
+        on_chip = lambda tree: jax.tree.map(  # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+        args = (inputs["params"],
+                mode.initial_state(program, inputs, inputs["params"]),
+                *loop.feed(inputs))
+        monkeypatch.setattr(common, "pallas_interpret_default", lambda: False)
+        jax.clear_caches()
+        try:
+            hlo = _compile(program["step"], *on_chip(args)).as_text()
+        finally:
+            monkeypatch.undo()
+            jax.clear_caches()
+    launches = trace.pallas_launches(hlo)
+    assert [(lc["kernel"], lc["backward"]) for lc in launches] == [("spmm_eb", False)] * 3
+    assert all(lc["name"].startswith("spmm_eb_stream") for lc in launches)
 
 
 def test_gcn_step_launches_are_named(one_chip, monkeypatch):
